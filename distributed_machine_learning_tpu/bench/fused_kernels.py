@@ -191,6 +191,11 @@ def bench_update_ab(world: int = 4, iters: int = 40,
 
 
 def main(argv=None) -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--world", default=8, type=int,
                         help="codec A/B world (the update A/B runs at "

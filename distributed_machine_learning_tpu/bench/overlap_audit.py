@@ -524,6 +524,11 @@ def wire_bytes_main(topology_name: str = "v5e:2x4",
 
 
 def main(argv=None) -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     import argparse
     import sys
 

@@ -19,10 +19,8 @@ measurement protocol), and a configurable host-side data wait (a
 
 Schemes: zero1 and fsdp (CNN steps, fixed-seed synthetic batches,
 loss parity asserted bit-identical), the GPipe pipeline with the
-pipe-sharded boundary update, and — on jax versions with
-partial-manual shard_map — zero1×3-D (annotated-dependency grad
-constraint vs its compile only; this host's jax lacks manual_axes, in
-which case the row records the skip reason instead of numbers).
+pipe-sharded boundary update, and zero1×3-D (the annotated-dependency
+grad constraint, over a partial-manual shard_map).
 
 Run:  python -m distributed_machine_learning_tpu.bench.overlap_bench \
           [--iters 24] [--data-wait-ms 10] [--json out.json]
@@ -295,68 +293,57 @@ def _bench_pipeline(iters: int, data_wait_s: float) -> list[dict]:
 
 
 def _bench_3d_zero1(iters: int, data_wait_s: float) -> list[dict]:
-    """zero1×3-D with the annotated-dependency grad constraint —
-    requires partial-manual shard_map; records the skip reason on jax
-    versions without it (this CI host)."""
+    """zero1×3-D with the annotated-dependency grad constraint (a
+    partial-manual shard_map over the pipe axis)."""
+    import jax
     import numpy as np
 
     from distributed_machine_learning_tpu.models.transformer import (
         TransformerLM,
     )
+    from distributed_machine_learning_tpu.parallel.parallel3d import (
+        init_pipeline_state,
+        make_3d_lm_train_step,
+        make_3d_mesh,
+        microbatch,
+        shard_3d_batch,
+        shard_3d_state,
+    )
     from distributed_machine_learning_tpu.train.adamw import AdamWConfig
 
-    try:
-        import jax
-
-        from distributed_machine_learning_tpu.parallel.parallel3d import (
-            init_pipeline_state,
-            make_3d_lm_train_step,
-            make_3d_mesh,
-            microbatch,
-            shard_3d_batch,
-            shard_3d_state,
-        )
-
-        model = TransformerLM(vocab_size=64, d_model=32, n_layers=4,
-                              n_heads=4)
-        mesh = make_3d_mesh(2, 2, 2)
-        rng = np.random.default_rng(7)
-        rows = []
-        for build, z1 in (("plain", False), ("zero1_dp", True)):
-            state = shard_3d_state(
-                init_pipeline_state(model, config=AdamWConfig()), mesh,
-                zero1_dp=z1)
-            step = make_3d_lm_train_step(model, mesh, 2, zero1_dp=z1)
-            it = []
-            loss = None
-            for i in range(iters):
-                t = rng.integers(0, 64, (8, 17))
-                mx, my = shard_3d_batch(
-                    mesh, *microbatch(t[:, :-1].astype(np.int32),
-                                      t[:, 1:].astype(np.int32), 2))
-                if data_wait_s:
-                    time.sleep(data_wait_s)
-                t0 = time.perf_counter()
-                state, loss = step(state, mx, my)
-                loss = jax.block_until_ready(loss)
-                it.append(time.perf_counter() - t0)
-            rows.append(_row("3d_zero1", build, it[1:], [], float(loss)))
-        return rows
-    except RuntimeError as e:
-        if "manual_axes" not in str(e) and "check_rep" not in str(e):
-            raise
-        return [{
-            "scheme": "3d_zero1", "build": "skipped",
-            "reason": (
-                "partial-manual shard_map unavailable on this jax "
-                f"({e}); the annotated-dependency constraint is "
-                "compile-covered by tests/test_parallel3d.py on capable "
-                "versions"
-            ),
-        }]
+    model = TransformerLM(vocab_size=64, d_model=32, n_layers=4,
+                          n_heads=4)
+    mesh = make_3d_mesh(2, 2, 2)
+    rng = np.random.default_rng(7)
+    rows = []
+    for build, z1 in (("plain", False), ("zero1_dp", True)):
+        state = shard_3d_state(
+            init_pipeline_state(model, config=AdamWConfig()), mesh,
+            zero1_dp=z1)
+        step = make_3d_lm_train_step(model, mesh, 2, zero1_dp=z1)
+        it = []
+        loss = None
+        for i in range(iters):
+            t = rng.integers(0, 64, (8, 17))
+            mx, my = shard_3d_batch(
+                mesh, *microbatch(t[:, :-1].astype(np.int32),
+                                  t[:, 1:].astype(np.int32), 2))
+            if data_wait_s:
+                time.sleep(data_wait_s)
+            t0 = time.perf_counter()
+            state, loss = step(state, mx, my)
+            loss = jax.block_until_ready(loss)
+            it.append(time.perf_counter() - t0)
+        rows.append(_row("3d_zero1", build, it[1:], [], float(loss)))
+    return rows
 
 
 def main(argv=None) -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     from distributed_machine_learning_tpu.runtime.mesh import (
         ensure_host_devices,
     )

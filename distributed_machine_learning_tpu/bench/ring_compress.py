@@ -314,6 +314,11 @@ def bench_selector_ab(world: int = 8, topology: str = "2x4",
 
 
 def main(argv=None) -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--worlds", default="2,4,8")
     parser.add_argument("--iters", default=24, type=int)

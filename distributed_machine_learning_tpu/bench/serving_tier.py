@@ -295,6 +295,11 @@ def acceptance(rows) -> dict:
 
 
 def main() -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--rates", default="6,16,48",
                    help="offered loads, requests/sec (ascending)")

@@ -175,6 +175,11 @@ def weak_scaling_sweep(
 
 
 def main() -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     from distributed_machine_learning_tpu.models.registry import list_models
 
     parser = argparse.ArgumentParser(description=__doc__)

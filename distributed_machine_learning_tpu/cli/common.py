@@ -11,7 +11,9 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import os
+from typing import Any, Callable
 
 import jax
 
@@ -26,7 +28,7 @@ from distributed_machine_learning_tpu.runtime.distributed import (
     DEFAULT_MASTER_IP,
     initialize_from_flags,
 )
-from distributed_machine_learning_tpu.runtime.mesh import make_mesh
+from distributed_machine_learning_tpu.runtime.mesh import make_mesh, replicate
 from distributed_machine_learning_tpu.train.loop import evaluate, train_epoch
 from distributed_machine_learning_tpu.train.sgd import SGDConfig
 from distributed_machine_learning_tpu.train.state import TrainState
@@ -40,6 +42,35 @@ from distributed_machine_learning_tpu.utils.profiling import MetricsLogger, trac
 
 SEED = 69143  # part1/main.py:17
 EVAL_BATCH = 256
+
+
+@dataclasses.dataclass(frozen=True)
+class RunResult:
+    """What a finished run hands back to a caller driving ``main(argv)``
+    in-process (``chip_smoke.py``): the final state, the compiled step
+    that produced it and the batch placement, so the caller can check
+    where things live and lower the very step that ran."""
+
+    state: Any
+    train_step: Callable
+    place_batch: Callable | None
+
+
+def device_banner(pallas: bool) -> str:
+    """``platform=… device_kind=…`` for the run banners, plus
+    ``pallas=compiled|interpreted`` when the run may dispatch a Pallas
+    kernel — so a TPU that failed to initialise (JAX then picks the CPU
+    with a warning, and every kernel silently interprets) is visible in
+    the first line of the log."""
+    dev = jax.devices()[0]
+    out = f"platform={dev.platform} device_kind={dev.device_kind!r}"
+    if pallas:
+        from distributed_machine_learning_tpu.ops.pallas.common import (
+            interpret,
+        )
+
+        out += f" pallas={'interpreted' if interpret() else 'compiled'}"
+    return out
 
 
 def add_node_flags(parser: argparse.ArgumentParser) -> None:
@@ -458,13 +489,17 @@ def run_part(
     use_bn: bool,
     args,
     strategy_kwargs: dict | None = None,
-) -> None:
+) -> RunResult:
     """Train `args.model` (default VGG-11) on CIFAR-10 for `args.epochs`
     under one sync strategy."""
     import jax.numpy as jnp
 
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
     from distributed_machine_learning_tpu.runtime.faults import FaultEvents
 
+    configure_compile_cache()
     # Streaming mode: rows hit the disk as they land (rank-0 gated,
     # periodic fsync) instead of only at exit — a crash keeps history.
     # Append only when this run CONTINUES prior work (--resume): a
@@ -512,10 +547,20 @@ def run_part(
         distributed = strategy_name != "none"
         mesh = make_mesh() if distributed else None
         world = mesh.shape["batch"] if mesh is not None else 1
-        # Reference banner (part2/2a/main.py:200-203).
+        # The two Pallas paths a part can select (int8 is the only
+        # codec with kernels, AdamW the only fused update).
+        fused_codec = (
+            strategy_name == "ring"
+            and getattr(args, "ring_codec_impl", "xla") == "pallas"
+            and getattr(args, "ring_compress", "none") == "int8"
+        )
+        fused_update = (getattr(args, "fused_update", False)
+                        and args.optimizer == "adamw")
+        # Reference banner (part2/2a/main.py:200-203) + the device.
         rank0_print(
             f"strategy={strategy_name} world_size={world} "
-            f"devices={jax.device_count()} processes={jax.process_count()}"
+            f"devices={jax.device_count()} processes={jax.process_count()} "
+            + device_banner(fused_codec or fused_update)
         )
 
         compute_dtype = jnp.bfloat16 if args.compute_dtype == "bfloat16" else jnp.float32
@@ -561,7 +606,10 @@ def run_part(
         def _maybe_stack(st):
             return broadcast_bn_stats(st, world) if unsync_bn else st
 
-        state = _maybe_stack(state)
+        def _replicate(st):
+            return replicate(st, mesh) if mesh is not None else st
+
+        state = _replicate(_maybe_stack(state))
 
         def restore_latest(fresh_state):
             """State from the newest complete checkpoint in --ckpt-dir
@@ -682,17 +730,7 @@ def run_part(
                         # buffer tree to AdamW's {"mu","nu"} update.
                         momentum=init_for_config(want)(state.params),
                     )
-                if mesh is not None:
-                    # Restored arrays come back committed to the default
-                    # device; the distributed step needs them replicated
-                    # over the mesh (the shard_map's in_specs say P()) —
-                    # mixing a device-0-committed state with mesh-sharded
-                    # batches is a hard error, not just slow.
-                    from jax.sharding import NamedSharding, PartitionSpec
-
-                    state = jax.device_put(
-                        state, NamedSharding(mesh, PartitionSpec())
-                    )
+                state = _replicate(state)
             return state
 
         if args.resume:
@@ -794,14 +832,14 @@ def run_part(
                 strategy.compression_ratio(n_elems, world)
             )
         if telemetry is not None:
-            # Which implementation actually ran, visible per step in the
-            # registry/trace (round 13): a bench or gang row claiming
-            # "fused" must show a nonzero counter, and a silent fallback
-            # to the XLA path shows as its absence.
-            if (getattr(strategy, "codec_impl", "xla") == "pallas"
-                    and getattr(strategy, "compress", "none") == "int8"):
+            # Which implementation was REQUESTED, per step in the
+            # registry/trace (round 13).  The counters follow the flags,
+            # not the lowered program: whether the requested kernels
+            # were compiled or interpreted is the banner's ``pallas=``
+            # word (``device_banner``).
+            if fused_codec:
                 telemetry.step_counters["fused_codec_steps"] = 1
-            if getattr(opt_config, "fused", False):
+            if fused_update:
                 telemetry.step_counters["fused_update_steps"] = 1
         train_step = make_train_step(
             model, strategy, mesh=mesh,
@@ -850,8 +888,10 @@ def run_part(
                 fn = dist_eval if len(labels) % world == 0 else single_eval
                 return fn(params, stats, images, labels)
 
-        train_set = load_cifar10(args.data_root, train=True)
-        test_set = load_cifar10(args.data_root, train=False)
+        # Never the network: the dataset is on disk under --data-root or
+        # it is the seeded stand-in, so a run reads nothing from outside.
+        train_set = load_cifar10(args.data_root, train=True, download=False)
+        test_set = load_cifar10(args.data_root, train=False, download=False)
         if train_set.synthetic:
             rank0_print("WARNING: CIFAR-10 not found on disk — using the "
                         "deterministic synthetic stand-in dataset.")
@@ -1275,3 +1315,4 @@ def run_part(
             telemetry.close()
             rank0_print(f"Telemetry written to {args.telemetry_dir}")
         ctx.shutdown()  # dist.destroy_process_group parity (part2/2a/main.py:207)
+    return RunResult(state=state, train_step=train_step, place_batch=place)

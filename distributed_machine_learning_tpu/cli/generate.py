@@ -142,6 +142,11 @@ def _restore_lm_params(ckpt_dir: str, n_layers: int):
 
 
 def main(argv=None) -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     args = make_parser().parse_args(argv)
     if not args.ckpt_dir and not args.random_init:
         raise ValueError("pass --ckpt-dir (a cli.lm checkpoint) or "

@@ -45,12 +45,16 @@ from __future__ import annotations
 import numpy as np
 import jax
 
-from distributed_machine_learning_tpu.cli.common import SEED
+from distributed_machine_learning_tpu.cli.common import (
+    SEED,
+    RunResult,
+    device_banner,
+)
 from distributed_machine_learning_tpu.models.transformer import TransformerLM
 from distributed_machine_learning_tpu.runtime.distributed import (
     initialize_from_flags,
 )
-from distributed_machine_learning_tpu.runtime.mesh import make_mesh
+from distributed_machine_learning_tpu.runtime.mesh import make_mesh, replicate
 from distributed_machine_learning_tpu.train.loop import train_epoch
 from distributed_machine_learning_tpu.utils.logging import rank0_print
 
@@ -418,7 +422,11 @@ def build(args):
                         "falling back to the einsum ring"
                     )
             model = TransformerLM(**{**common, "attn_impl": impl})
-        state = init_lm_state(model, seed=SEED, config=opt_config)
+        # Placed up front: left on the default device, the whole step
+        # compiles a second time at step 1 (38 s at d2048/8L on a v5e).
+        state = replicate(
+            init_lm_state(model, seed=SEED, config=opt_config), mesh
+        )
         step = make_lm_train_step(model, mesh=mesh,
                                   fused_ce_chunks=args.fused_ce_chunks,
                                   guard_nonfinite=guard,
@@ -738,7 +746,12 @@ def build(args):
     return step, state, place, model, lambda st: st.params
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> RunResult:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     parser = make_parser()
     args = parser.parse_args(argv)
     if args.telemetry_flush_every < 1:
@@ -761,7 +774,9 @@ def main(argv=None) -> None:
         rank0_print(
             f"lm parallel={args.parallel} devices={jax.device_count()} "
             f"d_model={args.d_model} layers={args.n_layers} "
-            f"seq_len={args.seq_len} batch={args.batch_size}"
+            f"seq_len={args.seq_len} batch={args.batch_size} "
+            # --attn auto/flash may dispatch the Pallas flash kernels.
+            + device_banner(args.fused_update or args.attn != "dense")
         )
         # Eval runs for EVERY scheme and process count: params are
         # materialized to host numpy first (a cross-process all-gather
@@ -930,9 +945,9 @@ def main(argv=None) -> None:
                         "cli/common.py)"
                     )
                 # The placed state doubles as the abstract template, so
-                # sharded leaves (fsdp_pl/tp/pp) restore straight into
-                # their shardings.  Leaves the scheme keeps UNCOMMITTED
-                # (dp/ring's replicated state under shard_map) must stay
+                # placed leaves (dp's replicated state, fsdp_pl/tp/pp's
+                # sharded ones) restore straight into their shardings.
+                # Leaves a scheme keeps UNCOMMITTED must stay
                 # uncommitted — a restore pins them to one device, which
                 # then conflicts with the mesh-sharded batch at dispatch
                 # — so those take a host round-trip back to plain
@@ -1097,6 +1112,7 @@ def main(argv=None) -> None:
             telemetry.close()
             rank0_print(f"Telemetry written to {args.telemetry_dir}")
         ctx.shutdown()
+    return RunResult(state=state, train_step=step, place_batch=place)
 
 
 if __name__ == "__main__":

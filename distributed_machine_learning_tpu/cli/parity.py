@@ -362,6 +362,11 @@ def make_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> None:
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     args = make_parser().parse_args(argv)
     if args.equivalence:
         result = run_equivalence(args)

@@ -17,18 +17,23 @@ is the deprecated spelling of ``--ring-compress bf16``.
 
 from __future__ import annotations
 
-from distributed_machine_learning_tpu.cli.common import make_flag_parser, parse_flags, run_part
+from distributed_machine_learning_tpu.cli.common import (
+    RunResult,
+    make_flag_parser,
+    parse_flags,
+    run_part,
+)
 from distributed_machine_learning_tpu.ops.ring import DEFAULT_BUCKET_BYTES
 
 BATCH_SIZE = 64  # per worker — part3/main.py:31
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> RunResult:
     parser = make_flag_parser(__doc__)
     parser.add_argument("--bucket-mb", default=25, type=int,
                         help="ring all-reduce bucket size (part3/main.py:137)")
     args = parse_flags(parser, argv)
-    run_part(
+    return run_part(
         "ring",
         per_rank_batch=BATCH_SIZE,
         use_bn=True,

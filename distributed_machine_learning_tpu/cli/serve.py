@@ -92,10 +92,14 @@ def _make_engine(micro_batch: int):
     from distributed_machine_learning_tpu.models.transformer import (
         TransformerLM,
     )
+    from distributed_machine_learning_tpu.runtime.compile_cache import (
+        configure_compile_cache,
+    )
     from distributed_machine_learning_tpu.train.lm_step import (
         init_lm_state,
     )
 
+    configure_compile_cache()
     model = TransformerLM(vocab_size=32, d_model=16, n_layers=2,
                           n_heads=4, n_kv_heads=2)
     engine = ContinuousEngine(

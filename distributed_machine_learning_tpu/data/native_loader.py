@@ -6,13 +6,18 @@ counterpart — batch assembly and prefetch run in a C++ worker thread
 behind a bounded queue, so host gather overlaps device compute without
 the GIL in the way.  The shared library is compiled from source on first
 use with the system ``g++`` (no pip deps); when no toolchain is
-available, callers fall back to the pure-Python loaders (same batch
-stream — ``tests/test_native_loader.py`` asserts byte equality).
+available, ``--loader auto`` callers fall back to the pure-Python
+loaders (same batch stream — ``tests/test_native_loader.py`` asserts
+byte equality).  The artifact's file name carries a hash of the source
+and the compile command, so only a binary built from the source as it
+stands is ever loaded — never a stale or foreign one left in the
+git-ignored build directory.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,22 +31,27 @@ from distributed_machine_learning_tpu.data.sharding import shard_indices
 
 _SRC = Path(__file__).resolve().parent.parent / "native" / "dataloader.cc"
 _BUILD_DIR = _SRC.parent / "_build"
-_LIB_PATH = _BUILD_DIR / "libdml_loader.so"
+_CXX = ("g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread")
 
 _lib = None
 _lib_error: str | None = None
 _lib_lock = threading.Lock()
 
 
-def _compile() -> None:
+def _lib_path() -> Path:
+    """Where the artifact of the CURRENT source + compile command lives."""
+    digest = hashlib.sha256(
+        _SRC.read_bytes() + b"\0" + " ".join(_CXX).encode()
+    ).hexdigest()[:16]
+    return _BUILD_DIR / f"libdml_loader.{digest}.so"
+
+
+def _compile(lib_path: Path) -> None:
     _BUILD_DIR.mkdir(exist_ok=True)
-    tmp = _LIB_PATH.with_suffix(f".{os.getpid()}.tmp")
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        str(_SRC), "-o", str(tmp),
-    ]
+    tmp = lib_path.with_suffix(f".{os.getpid()}.tmp")
+    cmd = [*_CXX, str(_SRC), "-o", str(tmp)]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
-    os.replace(tmp, _LIB_PATH)  # atomic: parallel builders race benignly
+    os.replace(tmp, lib_path)  # atomic: parallel builders race benignly
 
 
 def _load():
@@ -51,11 +61,10 @@ def _load():
         if _lib is not None or _lib_error is not None:
             return _lib
         try:
-            if not _LIB_PATH.exists() or (
-                _SRC.stat().st_mtime > _LIB_PATH.stat().st_mtime
-            ):
-                _compile()
-            lib = ctypes.CDLL(str(_LIB_PATH))
+            lib_path = _lib_path()
+            if not lib_path.exists():
+                _compile(lib_path)
+            lib = ctypes.CDLL(str(lib_path))
             lib.dl_create.restype = ctypes.c_void_p
             lib.dl_create.argtypes = [
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,

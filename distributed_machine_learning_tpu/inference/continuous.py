@@ -23,9 +23,11 @@ batched-frontier cached attention (``models/transformer.py``
 and scatters the one newly written (Hkv, D) row per lane back into
 the pool.  The gather formulation is numerically identical to
 ``ops/pallas/decode_attention.paged_attention_reference`` (asserted
-in tests); on TPU hardware the same pool + tables feed
-``paged_flash_attention``, whose scalar-prefetched table walk makes
-each lane's reads O(position) without materializing the gather.
+in tests) and is what the engine runs on EVERY backend, TPU included.
+``paged_flash_attention`` — the Pallas kernel whose scalar-prefetched
+table walk makes each lane's reads O(position) without materializing
+the gather — takes the same pool + tables but is not dispatched from
+here yet.
 
 The **regime lever** (``runtime/scheduler.py``): per step the engine
 asks its :class:`~..runtime.scheduler.RegimeScheduler` (or honors the

@@ -5,9 +5,9 @@ classification eval — ``part1/main.py:62-77``); this module is the LM
 serving half this framework adds: prefill the prompt once, then decode
 one token per step against per-layer K/V caches
 (``models/transformer.py`` ``decode=True``), the whole loop a single
-jitted program (`lax.scan`) — no per-token Python dispatch, which on a
-remote/tunneled TPU would cost more than the step itself (same argument
-as bench.py's scanned epoch).
+jitted program (`lax.scan`) — no per-token Python dispatch, which
+would cost more than a µs-scale decode step (same argument as
+bench.py's scanned epoch).
 
 TPU notes: the decode step is memory-bound (matvec against the cache),
 so the cache stays in the model's compute dtype (bf16 halves HBM

@@ -1,8 +1,8 @@
 """Shared Pallas plumbing: one interpret-mode knob for every kernel.
 
 Every kernel module (``flash_attention``, ``quant_matmul``,
-``ring_codec``, ``fused_adamw``, ``decode_attention``, ...) needs the
-same two decisions made the same way:
+``ring_codec``, ``fused_adamw``, ``decode_attention``, ...) makes the
+same decision the same way:
 
 - ``interpret()`` — whether ``pl.pallas_call`` should run the kernel
   under the Pallas interpreter instead of Mosaic.  Mosaic only compiles
@@ -12,9 +12,8 @@ same two decisions made the same way:
   ``flash_attention._interpret`` and was imported sideways by
   ``quant_matmul`` — it is hoisted here so interpret-mode selection is
   ONE knob for all kernels (the old import path is kept as an alias).
-- ``HAS_PLTPU`` / ``pltpu`` — the ``jax.experimental.pallas.tpu``
-  import, which only resolves fully on TPU-capable installs; kernels
-  gate their ``CompilerParams``/memory-space usage on it.
+  The CLI run banners print the same decision (``pallas=compiled`` /
+  ``pallas=interpreted``, ``cli/common.py::device_banner``).
 
 ``pick_block`` is the shared tiling helper (grown in ``quant_matmul``):
 the largest multiple-of-``quantum`` divisor of a dimension under a VMEM
@@ -24,14 +23,7 @@ target.
 from __future__ import annotations
 
 import jax
-
-try:  # pltpu imports only resolve fully on TPU-capable installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 def interpret() -> bool:
@@ -58,7 +50,6 @@ def interpret() -> bool:
 # predicate; quant_matmul imported it from there) so both spellings
 # resolve to the one definition above.
 _interpret = interpret
-_HAS_PLTPU = HAS_PLTPU
 
 
 #: VMEM lane width — the last dim of every kernel tile.
@@ -90,7 +81,7 @@ def tile_compiler_params(semantics) -> dict:
     will compile the kernel, ``{}`` under the interpreter (which
     rejects TPU compiler params) — the gate every kernel call spells
     around its ``dimension_semantics``."""
-    if HAS_PLTPU and not interpret():
+    if not interpret():
         return {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=tuple(semantics))}
     return {}

@@ -56,6 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
     _LANES,
@@ -64,13 +65,6 @@ from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
     _interpret,
     _online_update,
 )
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
 
 
 def pick_block_s(S: int, target: int = 512) -> int | None:
@@ -216,8 +210,6 @@ def cached_flash_attention(
         raise ValueError(
             f"cache length {S} does not tile; check decode_flash_qualifies"
         )
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable")
     if not quant:
         # Dummy scale operands keep ONE kernel signature; block index 0
         # never moves, so only 128 lanes per head are ever DMA'd.
@@ -360,10 +352,9 @@ def paged_attention_reference(
 ) -> jax.Array:
     """XLA reference for :func:`paged_flash_attention` — the gather
     formulation (pool rows indexed by the block table, then the same
-    masked fp32-softmax attention as ``_cached_attention``).  This is
-    also the CPU serving path: on hosts without a Pallas TPU backend
-    the engine dispatches here, and the kernel's interpret-mode parity
-    test pins the two together.
+    masked fp32-softmax attention as ``_cached_attention``).  The
+    continuous-batching engine runs this formulation on every backend;
+    the kernel's parity tests pin the two together.
 
     ``q``: [W, 1, H, D] — one in-flight decode token per lane;
     ``k_pool``/``v_pool``: [num_blocks, Hkv, block_s, D];
@@ -425,8 +416,6 @@ def paged_flash_attention(
     Hkv, block_s = k_pool.shape[1], k_pool.shape[2]
     n_rep = H // Hkv
     mb = block_tables.shape[1]
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable")
     if not paged_flash_qualifies(block_s):
         raise ValueError(
             f"pool block_s={block_s} is not a 128 multiple; dispatch "

@@ -54,12 +54,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Interpret-mode selection and the pltpu import are the shared knobs of
-# ops/pallas/common.py (one decision for every kernel); ``_interpret``
-# stays importable from here — quant_matmul historically imported it
-# from this module, and that path keeps working as an alias.
+# Interpret-mode selection is the shared knob of ops/pallas/common.py
+# (one decision for every kernel); ``_interpret`` stays importable from
+# here — quant_matmul historically imported it from this module, and
+# that path keeps working as an alias.
 from distributed_machine_learning_tpu.ops.pallas.common import (
-    _HAS_PLTPU,
     _interpret,
     pltpu,
 )
@@ -362,9 +361,6 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, scale=scale
     )
-    if not _HAS_PLTPU:  # pragma: no cover — pltpu ships with jax[tpu]/cpu alike
-        raise RuntimeError("pallas TPU support (jax.experimental.pallas.tpu) "
-                           "is unavailable; use attn_impl='dense'")
     q_spec = pl.BlockSpec(
         (1, block_q, D), lambda bh, qi, kb: (bh, qi, 0), memory_space=pltpu.VMEM
     )

@@ -36,7 +36,7 @@ pads are exact: they never raise the amax, quantize to 0, decode to 0,
 and contribute 0 residual — sliced off before anything reaches the
 wire).  The encode needs the global amax before any block can quantize,
 so its grid is (2, blocks): a max pass, then a quantize pass over the
-same tiles, the running amax carried in SMEM scratch.  Decode kernels
+same tiles, the running per-lane amax carried in VMEM scratch.  Decode kernels
 are single-pass with parallel grids, and the accumulator/decode output
 aliases its input buffer (``input_output_aliases``) so the in-place add
 stays in place.
@@ -154,10 +154,15 @@ def _block_rows(rows: int) -> int:
 
 
 def _encode_kernel(v_ref, q_ref, s_ref, *out_refs, with_residual):
-    """Grid (2, blocks): phase 0 folds each tile's |max| into the SMEM
-    running amax; phase 1 quantizes every tile against the final scale
-    (and, with_residual, emits ``v − q·scale`` from the registers —
-    the decode the XLA path materializes to HBM for the EF residual)."""
+    """Grid (2, blocks): phase 0 folds each tile's per-lane |max| into
+    the VMEM running amax row; phase 1 quantizes every tile against the
+    final scale (and, with_residual, emits ``v − q·scale`` from the
+    registers — the decode the XLA path materializes to HBM for the EF
+    residual).
+
+    The scale stays a ``[1, 128]`` lane vector until its one scalar
+    store: Mosaic bitcasts vectors only (``truncate_scale``), and the
+    vector divide broadcasts over sublanes for free."""
     if with_residual:
         err_ref, amax_ref = out_refs
     else:
@@ -167,19 +172,24 @@ def _encode_kernel(v_ref, q_ref, s_ref, *out_refs, with_residual):
 
     @pl.when((phase == 0) & (blk == 0))
     def _init():
-        amax_ref[0] = 0.0
+        amax_ref[...] = jnp.zeros_like(amax_ref)
 
     @pl.when(phase == 0)
     def _max_pass():
-        amax_ref[0] = jnp.maximum(amax_ref[0], jnp.max(jnp.abs(v_ref[...])))
+        amax_ref[...] = jnp.maximum(
+            amax_ref[...],
+            jnp.max(jnp.abs(v_ref[...]), axis=0, keepdims=True),
+        )
 
     @pl.when(phase == 1)
     def _quantize_pass():
-        scale = chunk_scale(amax_ref[0])
+        scale = chunk_scale(
+            jnp.full((1, _LANES), jnp.max(amax_ref[...]), jnp.float32)
+        )
         v = v_ref[...]
         q = jnp.clip(jnp.round(v / scale), -127, 127).astype(jnp.int8)
         q_ref[...] = q
-        s_ref[0, 0] = scale
+        s_ref[0, 0] = jnp.max(scale)  # every lane holds the same value
         if with_residual:
             # q·scale is EXACT (truncate_scale), so this subtraction is
             # FMA-contraction-immune and lands bit-identically to the
@@ -193,22 +203,26 @@ def _encode_call(v: jax.Array, with_residual: bool):
     tiles = _as_tiles(v.astype(jnp.float32), rows)
     br = _block_rows(rows)
     blocks = rows // br
-    tile_spec = pl.BlockSpec((br, _LANES), lambda p, b: (b, 0))
+    in_spec = pl.BlockSpec((br, _LANES), lambda p, b: (b, 0))
+    # Phase 0 assigns no output tile: park its output window on block 0
+    # (b·0) so nothing is written back until phase 1 has filled it.
+    out_tile = pl.BlockSpec((br, _LANES), lambda p, b: (b * p, 0))
     out_shapes = [
         jax.ShapeDtypeStruct((rows, _LANES), jnp.int8),
         jax.ShapeDtypeStruct((1, 1), jnp.float32),
     ]
-    out_specs = [tile_spec, pl.BlockSpec((1, 1), lambda p, b: (0, 0))]
+    # The scale is a scalar: Mosaic stores scalars to SMEM only.
+    out_specs = [out_tile, pl.BlockSpec(memory_space=pltpu.SMEM)]
     if with_residual:
         out_shapes.append(jax.ShapeDtypeStruct((rows, _LANES), jnp.float32))
-        out_specs.append(tile_spec)
+        out_specs.append(out_tile)
     outs = pl.pallas_call(
         functools.partial(_encode_kernel, with_residual=with_residual),
         grid=(2, blocks),
-        in_specs=[tile_spec],
+        in_specs=[in_spec],
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, _LANES), jnp.float32)],
         interpret=_interpret(),
         # Both axes sequential: phase 1 must see phase 0's amax, and the
         # amax fold itself carries across blocks.

@@ -53,9 +53,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
-    _HAS_PLTPU,
     _LANES,
     LOG2E,
     NEG_INF,
@@ -74,17 +74,6 @@ from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
     _tile_scores,
     _unfold,
 )
-
-if _HAS_PLTPU:
-    from jax.experimental.pallas import tpu as pltpu
-
-
-def _require_pltpu():
-    if not _HAS_PLTPU:  # pragma: no cover — pltpu ships with jax cpu/tpu
-        raise RuntimeError(
-            "pallas TPU support (jax.experimental.pallas.tpu) is "
-            "unavailable; use attn_impl='ring'"
-        )
 
 
 # ---------------------------------------------------------------------------
@@ -142,7 +131,6 @@ def _chunk_fwd(q, k, v, carry, *, causal: bool, kv_groups: int = 1):
     """One ring step over folded chunks (q [BHq, Lc, D], k/v
     [BHq // kv_groups, Lc, D]); carry = (m, l, acc) with m/l
     [BHq, 1, Lc] f32 (exact rows) and acc [BHq, Lc, D] f32."""
-    _require_pltpu()
     m, l, acc = carry
     BH, Lc, D = q.shape
     scale = 1.0 / (D**0.5)
@@ -271,7 +259,6 @@ def _chunk_dkv_kernel(
 
 def _chunk_dq(q, k, v, do, lse, delta, dq, *, causal: bool,
               kv_groups: int = 1):
-    _require_pltpu()
     BH, Lc, D = q.shape
     scale = 1.0 / (D**0.5)
     block_q, block_k = _fwd_blocks(Lc)
@@ -323,7 +310,6 @@ def _chunk_dkv(q, k, v, do, lse, delta, dk, dv, *, causal: bool,
     HEAD zero buffers [BHq, Lc, D]; the caller group-sums them down to
     the narrow heads before merging into the traveling accumulators.
     """
-    _require_pltpu()
     BH, Lc, D = q.shape
     scale = 1.0 / (D**0.5)
     block_q, block_k = _dkv_blocks(Lc)

@@ -16,12 +16,7 @@ import dataclasses
 
 import numpy as np
 import jax
-from jax.sharding import Mesh
-
-try:  # jax>=0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 BATCH_AXIS = "batch"
 
@@ -118,8 +113,7 @@ def repad_flat(flat: np.ndarray, n_elems: int, world: int) -> np.ndarray:
 
 
 def shard_map_no_check(f, *, mesh, in_specs, out_specs, manual_axes=None):
-    """shard_map with replication checking off, across the API rename
-    (new jax: check_vma; the experimental API this falls back to: check_rep).
+    """``jax.shard_map`` with replication checking off (``check_vma=False``).
 
     ``manual_axes``: restrict manual sharding to a subset of mesh axes
     (jax's ``axis_names``); the rest stay under automatic GSPMD
@@ -128,21 +122,21 @@ def shard_map_no_check(f, *, mesh, in_specs, out_specs, manual_axes=None):
     (``parallel/parallel3d.py``).  None (default) = fully manual.
     """
     kwargs = {} if manual_axes is None else {"axis_names": frozenset(manual_axes)}
-    try:
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False, **kwargs,
-        )
-    except TypeError as e:  # pragma: no cover
-        if manual_axes is not None:
-            raise RuntimeError(
-                "partial-manual shard_map (manual_axes=...) needs a jax "
-                "version whose shard_map accepts the axis_names parameter; "
-                "this jax only has the legacy check_rep API"
-            ) from e
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False, **kwargs,
+    )
+
+
+def replicate(tree, mesh: Mesh):
+    """Commit every leaf of ``tree`` to all of ``mesh``'s devices (the
+    placement a ``shard_map`` in_spec of ``P()`` expects).  A fresh or
+    restored state otherwise sits on the default device until the first
+    donated step moves it — after which the second step sees
+    differently-placed inputs and compiles the whole program again —
+    and a device-0-COMMITTED state next to mesh-sharded batches is a
+    hard error."""
+    return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
 
 
 def ensure_host_devices(n: int = 8) -> None:

@@ -204,7 +204,6 @@ class Telemetry:
         # Optional cost model for MFU: the CLI sets whichever it knows.
         self.flops_per_example: float | None = None
         self.flops_per_token: float | None = None
-        self.peak_tflops: float | None = None
         # Static per-step counter increments the train loop applies on
         # every completed step (e.g. ``ring_wire_bytes``: the compressed
         # ring's bytes-on-the-wire are a compile-time constant of the
@@ -245,19 +244,14 @@ class Telemetry:
             self.attempt = attempt
             self.flush()  # the prior attempt's rows are now history
 
-    def mfu_of(self, examples_per_s: float, tokens_per_s: float | None
-               ) -> float | None:
-        """MFU from whichever cost model the CLI installed, or None."""
-        from distributed_machine_learning_tpu.utils.flops import (
-            DEFAULT_PEAK_TFLOPS,
-            mfu,
-        )
-
-        peak = self.peak_tflops or DEFAULT_PEAK_TFLOPS
+    def model_flops_per_s(self, examples_per_s: float,
+                          tokens_per_s: float | None) -> float | None:
+        """Achieved model FLOP/s from whichever cost model the CLI
+        installed, or None without one."""
         if self.flops_per_token is not None and tokens_per_s is not None:
-            return mfu(tokens_per_s * self.flops_per_token, peak)
+            return tokens_per_s * self.flops_per_token
         if self.flops_per_example is not None:
-            return mfu(examples_per_s * self.flops_per_example, peak)
+            return examples_per_s * self.flops_per_example
         return None
 
     # -- lifecycle -------------------------------------------------------

@@ -326,11 +326,12 @@ def shard_lm_batch(
     seq_axis: str = SEQ_AXIS,
 ):
     """Place [B, L] token/target arrays: batch over data axis, sequence
-    over the ring axis."""
+    over the ring axis — straight from host memory to each device's
+    shard."""
     sharding = NamedSharding(mesh, P(data_axis, seq_axis))
     return (
-        jax.device_put(jnp.asarray(tokens), sharding),
-        jax.device_put(jnp.asarray(targets), sharding),
+        jax.device_put(tokens, sharding),
+        jax.device_put(targets, sharding),
     )
 
 

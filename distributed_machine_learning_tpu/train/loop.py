@@ -19,6 +19,7 @@ import numpy as np
 
 from distributed_machine_learning_tpu.telemetry import get_telemetry
 from distributed_machine_learning_tpu.train.state import TrainState
+from distributed_machine_learning_tpu.utils.flops import mfu
 from distributed_machine_learning_tpu.utils.logging import rank0_print
 from distributed_machine_learning_tpu.utils.timing import IterationTimer
 
@@ -98,6 +99,7 @@ def train_epoch(
     """
     timer = timer or IterationTimer(skip_first=1)
     tel = telemetry if telemetry is not None else get_telemetry()
+    device_kind = jax.devices()[0].device_kind if tel is not None else None
     if watchdog is not None:
         watchdog.beat()
     batches = iter(batches)
@@ -237,10 +239,14 @@ def train_epoch(
             else:
                 tokens_per_s = None
             reg.gauge("examples_per_s").set(examples_per_s)
-            mfu_val = tel.mfu_of(examples_per_s, tokens_per_s)
-            if mfu_val is not None:
-                row["mfu"] = mfu_val
-                reg.gauge("mfu").set(mfu_val)
+            flops_per_s = tel.model_flops_per_s(examples_per_s, tokens_per_s)
+            if flops_per_s is not None:
+                # None on a device kind the peak table does not list
+                # (every CPU run): the row says "no MFU", never a number
+                # against another device's peak.
+                row["mfu"] = mfu(flops_per_s, device_kind)
+                if row["mfu"] is not None:
+                    reg.gauge("mfu").set(row["mfu"])
             tel.log_step(step_no, **row)
         if metrics is not None:
             metrics.log(

@@ -251,8 +251,8 @@ def make_train_step(
 
     ``jit=False`` returns the un-jitted step function (no donation) — for
     callers that embed the step in a larger compiled program, e.g. the
-    benchmark's ``lax.scan``-ed epoch (bench.py) where per-step dispatch
-    would dominate on a remote/tunneled device.
+    benchmark's ``lax.scan``-ed epoch (bench.py), which keeps per-step
+    Python dispatch out of the measurement.
 
     Stateful strategies (``strategy.stateful``, e.g. the error-feedback
     compressed ring — ``RingAllReduce(compress="int8")``): the compiled
@@ -530,9 +530,11 @@ def make_eval_step(model, mesh: Mesh | None = None, axis_name: str = BATCH_AXIS,
 
 
 def shard_batch(mesh: Mesh, images_u8, labels, axis_name: str = BATCH_AXIS):
-    """Place a host batch onto the mesh, sharded along the batch axis."""
+    """Place a host batch onto the mesh, sharded along the batch axis —
+    straight from host memory to each device's shard (no staging copy of
+    the whole batch on the default device)."""
     sharding = NamedSharding(mesh, P(axis_name))
     return (
-        jax.device_put(jnp.asarray(images_u8), sharding),
-        jax.device_put(jnp.asarray(labels), sharding),
+        jax.device_put(images_u8, sharding),
+        jax.device_put(labels, sharding),
     )

@@ -10,9 +10,24 @@ count multiply and add separately (factor 2).
 
 from __future__ import annotations
 
-# bf16 peak of the attached chip class (TPU v5 lite — docs/PERF.md).
-# Overridable per call: MFU against the wrong peak is worse than no MFU.
-DEFAULT_PEAK_TFLOPS = 197.0
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeak:
+    """Published per-chip peaks: dense bf16 TFLOP/s and HBM GB/s."""
+
+    bf16_tflops: float
+    hbm_gb_per_s: float
+
+
+# Keyed by the ``device_kind`` string JAX reports.  A kind that is not
+# listed has no peak: MFU against the wrong peak is worse than no MFU.
+DEVICE_PEAKS = {
+    # TPU v5e — Google Cloud documentation, "TPU v5e" (system
+    # architecture): 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip.
+    "TPU v5 lite": DevicePeak(bf16_tflops=197.0, hbm_gb_per_s=819.0),
+}
 
 
 def vgg_forward_flops_per_image(
@@ -60,7 +75,10 @@ def transformer_train_flops_per_token(
     return 6.0 * n_params + attn
 
 
-def mfu(
-    achieved_flops_per_sec: float, peak_tflops: float = DEFAULT_PEAK_TFLOPS
-) -> float:
-    return achieved_flops_per_sec / (peak_tflops * 1e12)
+def mfu(achieved_flops_per_sec: float, device_kind: str) -> float | None:
+    """Model-FLOPs utilization against ``device_kind``'s bf16 peak, or
+    None for a kind :data:`DEVICE_PEAKS` does not list."""
+    peak = DEVICE_PEAKS.get(device_kind)
+    if peak is None:
+        return None
+    return achieved_flops_per_sec / (peak.bf16_tflops * 1e12)

@@ -35,18 +35,14 @@ def _initialized_process_count() -> int:
     XLA backend — called from a log record emitted before
     ``jax.distributed.initialize``, that would both break the later
     init and pin the count at 1 forever.  Multi-host is only knowable
-    after distributed init anyway, so consult its global state: not
-    initialized ⇒ treat as single process, touch nothing.
+    after distributed init anyway, so ask it: not initialized ⇒ treat
+    as single process, touch nothing.
     """
-    try:
-        import jax
-        from jax._src import distributed
+    import jax
 
-        if getattr(distributed.global_state, "client", None) is None:
-            return 1  # distributed runtime not up: single-process
-        return jax.process_count()  # safe: backend already initialized
-    except Exception:
-        return 1
+    if not jax.distributed.is_initialized():
+        return 1  # distributed runtime not up: single-process
+    return jax.process_count()  # safe: backend already initialized
 
 
 class _RankTaggedFormatter(logging.Formatter):

@@ -3,12 +3,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 from distributed_machine_learning_tpu.ops.collectives import (
     all_reduce_mean,

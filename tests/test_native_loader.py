@@ -82,3 +82,26 @@ def test_native_rejects_bad_batch(dataset):
         NativeBatchLoader(dataset, 0)
     with pytest.raises(ValueError):
         NativeDistributedBatchLoader(dataset, -1, 4)
+
+
+def test_only_the_hash_named_artifact_is_loaded(monkeypatch):
+    """A stale or foreign binary under the old fixed name is never
+    loaded: the artifact's name carries a hash of the source and the
+    compile command, and that is the only path ``_load`` opens."""
+    import hashlib
+
+    from distributed_machine_learning_tpu.data import native_loader as nl
+
+    stale = nl._BUILD_DIR / "libdml_loader.so"
+    stale.write_bytes(b"not a shared library")
+    monkeypatch.setattr(nl, "_lib", None)  # force a fresh load decision
+    monkeypatch.setattr(nl, "_lib_error", None)
+    try:
+        digest = hashlib.sha256(
+            nl._SRC.read_bytes() + b"\0" + " ".join(nl._CXX).encode()
+        ).hexdigest()[:16]
+        assert nl._lib_path().name == f"libdml_loader.{digest}.so"
+        assert nl._load()._name == str(nl._lib_path())
+        assert nl._lib_path() != stale
+    finally:
+        stale.unlink()

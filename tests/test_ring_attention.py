@@ -7,10 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:  # jax>=0.6 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from distributed_machine_learning_tpu.ops.ring_attention import (
