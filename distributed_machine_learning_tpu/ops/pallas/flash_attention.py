@@ -398,6 +398,7 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -506,6 +507,7 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dK/dV: K blocks own the accumulators, Q innermost.  Below-diagonal
@@ -555,6 +557,7 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -200,7 +200,8 @@ class Telemetry:
         self.metrics = JsonlSink(metrics_path, flush_every=flush_every,
                                  fsync=fsync, enabled=enabled)
         self.tracer = SpanTracer(self._artifact(TRACE_FILE),
-                                 flush_every=flush_every, enabled=enabled)
+                                 flush_every=flush_every, enabled=enabled,
+                                 fsync=fsync)
         # Optional cost model for MFU: the CLI sets whichever it knows.
         self.flops_per_example: float | None = None
         self.flops_per_token: float | None = None

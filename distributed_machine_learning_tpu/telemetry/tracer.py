@@ -42,11 +42,15 @@ class SpanTracer:
 
     def __init__(self, path: str | os.PathLike, flush_every: int = 20,
                  enabled: bool | None = None,
-                 max_events: int = DEFAULT_MAX_EVENTS):
+                 max_events: int = DEFAULT_MAX_EVENTS, fsync: bool = True):
         if flush_every < 1:
             raise ValueError(f"flush_every must be >= 1, got {flush_every}")
         self.path = os.fspath(path)
         self.flush_every = flush_every
+        # As ``JsonlSink.fsync``: False keeps the periodic flush and drops
+        # the fsync (a caller that measures the loop, not one that must
+        # survive a power cut).
+        self.fsync = fsync
         # None = rank-0 gate, resolved lazily at the first event (see
         # JsonlSink.enabled: construction predates distributed init).
         self._enabled = enabled
@@ -105,7 +109,8 @@ class SpanTracer:
             self._pending += 1
             if self._pending >= self.flush_every:
                 self._file.flush()
-                os.fsync(self._file.fileno())
+                if self.fsync:
+                    os.fsync(self._file.fileno())
                 self._pending = 0
 
     def complete(self, name: str, start_s: float, end_s: float,

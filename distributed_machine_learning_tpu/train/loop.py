@@ -19,6 +19,7 @@ import numpy as np
 
 from distributed_machine_learning_tpu.telemetry import get_telemetry
 from distributed_machine_learning_tpu.train.state import TrainState
+from distributed_machine_learning_tpu.utils import profiling
 from distributed_machine_learning_tpu.utils.flops import mfu
 from distributed_machine_learning_tpu.utils.logging import rank0_print
 from distributed_machine_learning_tpu.utils.timing import IterationTimer
@@ -48,6 +49,202 @@ def _host_local_losses(loss) -> list[tuple[int, float]]:
         for j, v in enumerate(np.asarray(sh.data).ravel()):
             out.append((start + j, float(v)))
     return sorted(out)
+
+
+def _print_loss(batch_no: int, loss) -> None:
+    if getattr(loss, "ndim", 0):
+        # local-loss mode (make_train_step(local_loss=True)): one line
+        # per THIS-HOST device — the reference's every-rank-prints-its-
+        # own-loss surface (part2/2a/main.py:58-61); printed
+        # unconditionally (not rank-0-gated) for the same reason.
+        for d, lv in _host_local_losses(loss):
+            print(f"Loss at {batch_no}th batch is {lv} (device {d})")
+    else:
+        rank0_print(f"Loss at {batch_no}th batch is {float(loss)}")
+
+
+#: One iteration's phases, each declared once: the name on the profiler's
+#: clock (``utils/profiling.annotate``) -> the ``SpanTracer`` span and the
+#: step-row field.  The last two exist only with a ``Telemetry`` and are
+#: made from the same two ``perf_counter`` reads.
+_PHASES = {
+    "train.data_wait": ("data_wait", "data_wait_s"),
+    "train.place_batch": ("place_batch", "place_s"),
+    "train.step_dispatch": ("step_dispatch", "dispatch_s"),
+    "train.device_block": ("device_block", "block_s"),
+}
+
+
+class _Timed:
+    """A phase bracket with telemetry on: the profiler annotation, and
+    inside it the two clock reads kept in ``times[name]``."""
+
+    __slots__ = ("_ann", "_name", "_times", "_t0")
+
+    def __init__(self, ann, name: str, times: dict):
+        self._ann, self._name, self._times = ann, name, times
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._times[self._name] = (self._t0, time.perf_counter())
+        return self._ann.__exit__(*exc)
+
+
+def _phase(name: str, rec: "_LoopTelemetry | None"):
+    """The one bracket of a phase.  Always a profiler annotation (a flag
+    test without a profiler session); with telemetry also the host-clock
+    reads its tracer span and row field come from."""
+    ann = profiling.annotate(name)
+    return ann if rec is None else _Timed(ann, name, rec.times)
+
+
+class _LoopTelemetry:
+    """``train_epoch``'s telemetry side, one per epoch: from the host
+    clock of each iteration's phases to tracer spans, registry series and
+    the step row."""
+
+    def __init__(self, tel, state, train_step, timer):
+        self.tel = tel
+        self.times: dict[str, tuple[float, float]] = {}
+        self._train_step = train_step
+        self._timer = timer
+        self._device_kind = jax.devices()[0].device_kind
+        # The optimizer's count, read once: rows count on from it on the
+        # host (a read-back every step is a host sync on telemetry's
+        # account).  Only a guard-skipped step makes the two differ, and
+        # whoever cares about those passes ``events`` or ``until_step``,
+        # whose own read the row then uses.
+        self._step_base = int(jax.device_get(state.step))
+        self._t_ready = None
+        # (fetch start, children's seconds, batch) of the iteration
+        # whose period is still open: it ends at the next fetch.
+        self._open = None
+
+    def host_batch(self, images, labels) -> None:
+        """Batch geometry BEFORE placement (sharding may hide it), and
+        the bytes about to cross to the device: no sync, host arrays
+        only."""
+        shape = getattr(images, "shape", None)
+        self._n_examples = int(shape[0]) if shape else 0
+        self._n_tokens = (
+            int(shape[0]) * int(shape[1])
+            if shape is not None and len(shape) == 2
+            else None
+        )
+        self._h2d_bytes = sum(
+            a.nbytes for a in (images, labels) if isinstance(a, np.ndarray)
+        )
+
+    def batch_ready(self) -> None:
+        """The placed batch is resident on every shard: now."""
+        self._t_ready = time.perf_counter()
+
+    def log_step(self, batch_idx: int, iter_time: float,
+                 step_after: int | None) -> None:
+        tel, times = self.tel, self.times
+        tr, reg = tel.tracer, tel.registry
+        # Mirror the timer's warm-up protocol: an iteration the
+        # timer excluded (XLA compile lands there) must not skew
+        # the histogram quantiles either — registry p99 and the
+        # printed summary percentiles describe the same population.
+        # The span and the (warmup-tagged) row still record it: the
+        # compile step belongs on the timeline, not in the tail.
+        warmup = self._timer._iter <= self._timer.skip_first
+        # ``place_s`` is 0 where the loop was given no placement call
+        # (``jit`` moves the batch inside ``dispatch_s``/``block_s``).
+        row = {"batch": batch_idx, "iter_s": iter_time,
+               **({"warmup": True} if warmup else {}), "place_s": 0.0}
+        children = 0.0
+        for name, (span, field) in _PHASES.items():
+            if name in times:
+                t0, t1 = times[name]
+                tr.complete(span, t0, t1, step=batch_idx)
+                row[field] = t1 - t0
+                children += t1 - t0
+        if self._t_ready is not None:
+            # Placement call -> batch resident.  Overlaps place_batch,
+            # step_dispatch and the head of device_block: transfers the
+            # placement call returned before the end of.
+            t_place = times["train.place_batch"][0]
+            tr.complete("batch_ready", t_place, self._t_ready,
+                        step=batch_idx)
+            row["batch_ready_s"] = self._t_ready - t_place
+            self._t_ready = None
+        t_fetch = times["train.data_wait"][0]
+        times.clear()
+        if self._open is not None:
+            # The period of an iteration ends at the next fetch, after
+            # its own row was written: row k carries iteration k-1's
+            # self time (as ``param_gather_s`` below does its gather).
+            t_prev, children_prev, batch_prev = self._open
+            tr.complete("train_step", t_prev, t_fetch, step=batch_prev)
+            row["loop_self_s"] = (t_fetch - t_prev) - children_prev
+        self._open = (t_fetch, children, batch_idx)
+        data_wait_s = row["data_wait_s"]
+        reg.counter("steps_total").inc()
+        reg.counter("h2d_bytes_total").inc(self._h2d_bytes)
+        row["h2d_bytes"] = self._h2d_bytes
+        for _cname, _cval in (getattr(tel, "step_counters", None)
+                              or {}).items():
+            # Static per-step increments the CLI registered (e.g.
+            # ring_wire_bytes — the compressed ring's per-step wire
+            # bytes, a compile-time constant of the program).  A
+            # list value is labeled sub-counters:
+            # [({"axis": "outer"}, bytes), ...] increments one
+            # counter per label set under the shared name.
+            if isinstance(_cval, (list, tuple)):
+                for _clabels, _v in _cval:
+                    reg.counter(_cname, **_clabels).inc(_v)
+            else:
+                reg.counter(_cname).inc(_cval)
+        if not warmup:
+            reg.histogram("step_seconds").observe(iter_time)
+            reg.histogram("data_wait_seconds").observe(data_wait_s)
+        wall = iter_time + data_wait_s
+        examples_per_s = self._n_examples / wall if wall > 0 else 0.0
+        row["examples_per_s"] = examples_per_s
+        reg.gauge("examples_per_s").set(examples_per_s)
+        # Overlap-aware sharded updates (zero1/fsdp overlap=True)
+        # expose the consume-phase gather span: dispatch → observed
+        # ready, closed at the NEXT step's consume, so row k
+        # reports step k−1's gather.  On the trace timeline the
+        # param_gather span overlaps data_wait — the 2004.13336
+        # proof that the weight-update gather left the critical
+        # path (device_block shrinks by what param_gather hides).
+        pop_gather = getattr(self._train_step, "pop_gather_seconds", None)
+        if pop_gather is not None:
+            gather_s = pop_gather()
+            if gather_s is not None:
+                row["param_gather_s"] = gather_s
+        if self._n_tokens is not None:
+            tokens_per_s = self._n_tokens / wall if wall > 0 else 0.0
+            row["tokens_per_s"] = tokens_per_s
+        else:
+            tokens_per_s = None
+        flops_per_s = tel.model_flops_per_s(examples_per_s, tokens_per_s)
+        if flops_per_s is not None:
+            # None on a device kind the peak table does not list
+            # (every CPU run): the row says "no MFU", never a number
+            # against another device's peak.
+            row["mfu"] = mfu(flops_per_s, self._device_kind)
+        tel.log_step(
+            step_after if step_after is not None
+            else self._step_base + batch_idx + 1,
+            **row,
+        )
+
+    def close(self) -> None:
+        """The last iteration's step span ends where the loop did: at
+        the fetch that ended the epoch, if there was one."""
+        if self._open is not None:
+            last_fetch = self.times.get("train.data_wait")
+            self.tel.tracer.complete(
+                "train_step", self._open[0],
+                last_fetch[0] if last_fetch else time.perf_counter(),
+                step=self._open[2])
 
 
 def train_epoch(
@@ -87,194 +284,127 @@ def train_epoch(
     cap) this counts *applied* updates, so guard-skipped steps are
     retried with further batches — the supervisor's contract that a
     faulted run still lands on the same final step count.
+
+    One iteration is a closed set of spans, each bracketed once
+    (``_PHASES``): ``train.step`` (a ``StepTraceAnnotation`` numbered by
+    the batch) holds ``train.data_wait`` / ``train.place_batch`` /
+    ``train.step_dispatch`` / ``train.device_block`` /
+    ``train.bookkeeping`` on the profiler's clock, always — without a
+    profiler session each is a flag test, and with one (``--trace-dir``)
+    they lie against the device's operations in the trace.
+
     ``telemetry``: optional ``telemetry.Telemetry``; defaults to the
     process-wide install (``get_telemetry()``, None unless a CLI set
-    ``--telemetry-dir``).  When active, the old single timing bracket is
-    split into per-phase spans — ``data_wait`` / ``place_batch`` /
-    ``step_dispatch`` / ``device_block`` — streamed to the Chrome trace,
-    and each step logs an attempt-tagged metrics row (examples/s,
-    tokens/s, MFU when the CLI installed a FLOPs model).  When None
-    (the default) every telemetry branch is a single pointer test: no
-    allocations, no clock reads, no syscalls beyond today's loop.
+    ``--telemetry-dir``).  When active, the same brackets also read the
+    host clock: spans ``data_wait`` / ``place_batch`` /
+    ``step_dispatch`` / ``device_block`` under a ``train_step`` parent go
+    to the Chrome trace, and each step logs an attempt-tagged metrics
+    row (the phases' seconds, examples/s, tokens/s, MFU when the CLI
+    installed a FLOPs model) with three fields only this loop can know:
+    ``batch_ready_s`` (placement call -> the placed batch resident on
+    every shard: the loop waits for it inside ``device_block``, after
+    the dispatch, so nothing the step needs is delayed and the reading
+    is never below ``place_s + dispatch_s``; span ``batch_ready``;
+    absent without a ``place_batch``), ``h2d_bytes``
+    (the host batch's bytes; counter ``h2d_bytes_total``) and
+    ``loop_self_s`` (the iteration's period, fetch to next fetch, minus
+    the four phases: this loop's own bookkeeping, telemetry included —
+    what the instrumentation costs when it is on.  A row is written
+    before its iteration ends, so row k carries iteration k-1's).  The
+    row's ``step`` is counted on the host from one read of
+    ``state.step`` before the loop.  When None (the default) every
+    telemetry branch is a single pointer test: no clock reads, no
+    device reads or waits, no syscalls beyond today's loop.
     """
     timer = timer or IterationTimer(skip_first=1)
     tel = telemetry if telemetry is not None else get_telemetry()
-    device_kind = jax.devices()[0].device_kind if tel is not None else None
+    rec = (_LoopTelemetry(tel, state, train_step, timer)
+           if tel is not None else None)
     if watchdog is not None:
         watchdog.beat()
     batches = iter(batches)
     batch_idx = 0
     while True:
-        t_fetch = time.perf_counter() if tel is not None else 0.0
-        try:
-            images, labels = next(batches)
-        except StopIteration:
-            break
-        t_got = time.perf_counter() if tel is not None else 0.0
-        if batch_idx == max_iters:  # part1/main.py:32-33
-            break
-        if stop is not None and stop():
-            rank0_print(
-                f"stop requested; ending epoch after {batch_idx} iterations"
-            )
-            break
-        if events is not None:
-            step_before = int(jax.device_get(state.step))
-            # Read the value NOW: the jitted step donates its input
-            # state, so this buffer is dead after the call.
-            scale_before = getattr(state, "loss_scale", None)
-            if scale_before is not None:
-                scale_before = float(scale_before)
-        if tel is not None:
-            # Batch geometry BEFORE placement (sharding may hide it).
-            shape = getattr(images, "shape", None)
-            n_examples = int(shape[0]) if shape else 0
-            n_tokens = (
-                int(shape[0]) * int(shape[1])
-                if shape is not None and len(shape) == 2
-                else None
-            )
-        timer.start()
-        t_place = time.perf_counter() if tel is not None else 0.0
-        if place_batch is not None:
-            images, labels = place_batch(images, labels)
-        t_dispatch = time.perf_counter() if tel is not None else 0.0
-        state, loss = train_step(state, images, labels)
-        t_block = time.perf_counter() if tel is not None else 0.0
-        loss = jax.block_until_ready(loss)
-        t_end = time.perf_counter() if tel is not None else 0.0
-        iter_time = timer.stop()
-        # One host sync serves both the skip accounting and the
-        # until_step check below — these reads serialize dispatch, so
-        # pay for them only when a consumer asked.
-        step_after = (
-            int(jax.device_get(state.step))
-            if events is not None or until_step is not None
-            else None
-        )
-        if events is not None:
-            # Account BEFORE the watchdog beat: a RaisingWatchdog beat
-            # escalates a declared stall into an exception, and a skip
-            # that landed on the same step must already be counted.
-            if step_after == step_before:
-                events.skipped_steps += 1
-            if scale_before is not None:
-                before, after = scale_before, float(state.loss_scale)
-                if after < before:
-                    events.scaler_backoffs += 1
-                elif after > before:
-                    events.scaler_growths += 1
-        if watchdog is not None:
-            watchdog.beat()
-        if tel is not None:
-            step_no = (
-                step_after if step_after is not None
-                else int(jax.device_get(state.step))
-            )
-            tr = tel.tracer
-            tr.complete("data_wait", t_fetch, t_got, step=batch_idx)
-            if place_batch is not None:
-                tr.complete("place_batch", t_place, t_dispatch,
-                            step=batch_idx)
-            tr.complete("step_dispatch", t_dispatch, t_block,
-                        step=batch_idx)
-            tr.complete("device_block", t_block, t_end, step=batch_idx)
-            data_wait_s = t_got - t_fetch
-            # Mirror the timer's warm-up protocol: an iteration the
-            # timer excluded (XLA compile lands there) must not skew
-            # the histogram quantiles either — registry p99 and the
-            # printed summary percentiles describe the same population.
-            # The span and the (warmup-tagged) row still record it: the
-            # compile step belongs on the timeline, not in the tail.
-            warmup = timer._iter <= timer.skip_first
-            reg = tel.registry
-            reg.counter("steps_total").inc()
-            for _cname, _cval in (getattr(tel, "step_counters", None)
-                                  or {}).items():
-                # Static per-step increments the CLI registered (e.g.
-                # ring_wire_bytes — the compressed ring's per-step wire
-                # bytes, a compile-time constant of the program).  A
-                # list value is labeled sub-counters:
-                # [({"axis": "outer"}, bytes), ...] increments one
-                # counter per label set under the shared name.
-                if isinstance(_cval, (list, tuple)):
-                    for _clabels, _v in _cval:
-                        reg.counter(_cname, **_clabels).inc(_v)
-                else:
-                    reg.counter(_cname).inc(_cval)
-            if not warmup:
-                reg.histogram("step_seconds").observe(iter_time)
-                reg.histogram("data_wait_seconds").observe(data_wait_s)
-            wall = iter_time + data_wait_s
-            examples_per_s = n_examples / wall if wall > 0 else 0.0
-            row = {
-                "batch": batch_idx,
-                "iter_s": iter_time,
-                "data_wait_s": data_wait_s,
-                **({"warmup": True} if warmup else {}),
-                "place_s": t_dispatch - t_place,
-                "dispatch_s": t_block - t_dispatch,
-                "block_s": t_end - t_block,
-                "examples_per_s": examples_per_s,
-            }
-            # Overlap-aware sharded updates (zero1/fsdp overlap=True)
-            # expose the consume-phase gather span: dispatch → observed
-            # ready, closed at the NEXT step's consume, so row k
-            # reports step k−1's gather.  On the trace timeline the
-            # param_gather span overlaps data_wait — the 2004.13336
-            # proof that the weight-update gather left the critical
-            # path (device_block shrinks by what param_gather hides).
-            pop_gather = getattr(train_step, "pop_gather_seconds", None)
-            if pop_gather is not None:
-                gather_s = pop_gather()
-                if gather_s is not None:
-                    row["param_gather_s"] = gather_s
-                    if not warmup:
-                        reg.histogram("param_gather_seconds").observe(
-                            gather_s)
-            if n_tokens is not None:
-                tokens_per_s = n_tokens / wall if wall > 0 else 0.0
-                row["tokens_per_s"] = tokens_per_s
-                reg.gauge("tokens_per_s").set(tokens_per_s)
-            else:
-                tokens_per_s = None
-            reg.gauge("examples_per_s").set(examples_per_s)
-            flops_per_s = tel.model_flops_per_s(examples_per_s, tokens_per_s)
-            if flops_per_s is not None:
-                # None on a device kind the peak table does not list
-                # (every CPU run): the row says "no MFU", never a number
-                # against another device's peak.
-                row["mfu"] = mfu(flops_per_s, device_kind)
-                if row["mfu"] is not None:
-                    reg.gauge("mfu").set(row["mfu"])
-            tel.log_step(step_no, **row)
-        if metrics is not None:
-            metrics.log(
-                step=int(state.step),
-                loss=float(np.mean(
-                    [lv for _, lv in _host_local_losses(loss)]
-                )),
-                iter_seconds=iter_time,
-            )
-        if (batch_idx + 1) % loss_print_every == 0:  # part1/main.py:49-50
-            if getattr(loss, "ndim", 0):
-                # local-loss mode (make_train_step(local_loss=True)): one
-                # line per THIS-HOST device — the reference's every-rank-
-                # prints-its-own-loss surface (part2/2a/main.py:58-61);
-                # printed unconditionally (not rank-0-gated) for the same
-                # reason.
-                for d, lv in _host_local_losses(loss):
-                    print(
-                        f"Loss at {batch_idx + 1}th batch is {lv} "
-                        f"(device {d})"
-                    )
-            else:
+        with profiling.annotate("train.step", step_num=batch_idx):
+            with _phase("train.data_wait", rec):
+                try:
+                    images, labels = next(batches)
+                except StopIteration:
+                    break
+            if batch_idx == max_iters:  # part1/main.py:32-33
+                break
+            if stop is not None and stop():
                 rank0_print(
-                    f"Loss at {batch_idx + 1}th batch is {float(loss)}"
+                    f"stop requested; ending epoch after {batch_idx} "
+                    "iterations"
                 )
-        if until_step is not None and step_after >= until_step:
-            break
+                break
+            if events is not None:
+                step_before = int(jax.device_get(state.step))
+                # Read the value NOW: the jitted step donates its input
+                # state, so this buffer is dead after the call.
+                scale_before = getattr(state, "loss_scale", None)
+                if scale_before is not None:
+                    scale_before = float(scale_before)
+            if tel is not None:
+                rec.host_batch(images, labels)
+            timer.start()
+            if place_batch is not None:
+                with _phase("train.place_batch", rec):
+                    images, labels = place_batch(images, labels)
+            with _phase("train.step_dispatch", rec):
+                state, loss = train_step(state, images, labels)
+            with _phase("train.device_block", rec):
+                if tel is not None and place_batch is not None:
+                    # When did the placed batch arrive?  The step is
+                    # already dispatched and the loop blocks on its loss
+                    # next anyway, so this wait delays nothing.  (No
+                    # step donates its batch.)
+                    jax.block_until_ready((images, labels))
+                    rec.batch_ready()
+                loss = jax.block_until_ready(loss)
+            iter_time = timer.stop()
+            with profiling.annotate("train.bookkeeping"):
+                # One host sync serves both the skip accounting and the
+                # until_step check below — these reads serialize
+                # dispatch, so pay for them only when a consumer asked.
+                step_after = (
+                    int(jax.device_get(state.step))
+                    if events is not None or until_step is not None
+                    else None
+                )
+                if events is not None:
+                    # Account BEFORE the watchdog beat: a RaisingWatchdog
+                    # beat escalates a declared stall into an exception,
+                    # and a skip that landed on the same step must
+                    # already be counted.
+                    if step_after == step_before:
+                        events.skipped_steps += 1
+                    if scale_before is not None:
+                        before, after = scale_before, float(state.loss_scale)
+                        if after < before:
+                            events.scaler_backoffs += 1
+                        elif after > before:
+                            events.scaler_growths += 1
+                if watchdog is not None:
+                    watchdog.beat()
+                if tel is not None:
+                    rec.log_step(batch_idx, iter_time, step_after)
+                if metrics is not None:
+                    metrics.log(
+                        step=int(state.step),
+                        loss=float(np.mean(
+                            [lv for _, lv in _host_local_losses(loss)]
+                        )),
+                        iter_seconds=iter_time,
+                    )
+                if (batch_idx + 1) % loss_print_every == 0:
+                    _print_loss(batch_idx + 1, loss)  # part1/main.py:49-50
+            if until_step is not None and step_after >= until_step:
+                break
         batch_idx += 1
+    if tel is not None:
+        rec.close()
     rank0_print(timer.summary())  # part1/main.py:57-58
     return state, timer
 

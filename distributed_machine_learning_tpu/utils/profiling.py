@@ -11,8 +11,10 @@ The reference's observability is a hand-rolled wall-clock harness
 - :class:`MetricsLogger` — per-step structured metrics (step, loss,
   wall-clock) accumulated in memory and flushed to CSV and/or JSONL,
   rank-0 gated; feeds the scaling-sweep harness.
-- :func:`annotate` — ``jax.profiler.TraceAnnotation`` wrapper so driver
-  phases (train/eval/checkpoint) show up as named spans in the trace.
+- :func:`annotate` — ``jax.profiler.TraceAnnotation`` wrapper:
+  ``train_epoch`` brackets each iteration (``train.step``) and its phases
+  (``train.data_wait`` … ``train.bookkeeping``) with it, so they show up
+  as named host spans beside the device's operations in the trace.
 """
 
 from __future__ import annotations
@@ -47,8 +49,17 @@ def trace(log_dir: str | os.PathLike | None):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named span in the profiler timeline (host side)."""
+def annotate(name: str, step_num: int | None = None):
+    """Named host span on the profiler's clock — the device trace's own,
+    so the span lies against the device's ``XLA Ops`` line.  With no
+    profiler session the span costs a flag test.  ``step_num`` makes it a
+    ``StepTraceAnnotation``: the span carries the step's number, which
+    viewers that group by step (XProf) give to the spans and device
+    operations inside it (``train/loop.py::train_epoch`` brackets each
+    iteration so).  The device plane's own ``Steps`` line in the raw
+    ``.xplane.pb`` keeps the runtime's count from 0 (PERF.md, PR 25)."""
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
     return jax.profiler.TraceAnnotation(name)
 
 

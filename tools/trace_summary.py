@@ -5,7 +5,8 @@ Reads the artifacts a run's ``--telemetry-dir`` produced
 (``distributed_machine_learning_tpu/telemetry/``) and prints:
 
 - per-phase time shares from the Chrome trace's complete events
-  (data_wait / place_batch / step_dispatch / device_block /
+  (data_wait / place_batch / step_dispatch / device_block, the loop's
+  own self time where the trace has ``train_step`` parents /
   checkpoint_save / eval / ...), the first diagnosis dimension for
   stragglers and sync overhead — trace *instants* (fault markers,
   gang_shrink, restarts) are counted in the same table: a fault that
@@ -53,12 +54,20 @@ REGISTRY_FILE = "registry.json"
 # The per-step driver phases, in pipeline order (other spans —
 # checkpoint_save, eval, restart_attempt — are reported after these).
 STEP_PHASES = ("data_wait", "place_batch", "step_dispatch", "device_block")
-# Spans that run CONCURRENTLY with the pipeline phases (the
-# overlap-aware sharded update's consume-phase gather runs behind
-# data_wait): shown in the phase table for visibility, but excluded
-# from the pipeline total — counting an overlapped span into the
-# denominator would misstate every share.
-OVERLAY_PHASES = ("param_gather",)
+# The parent of one iteration's phases (fetch start to next fetch start).
+# What of it the phases do not cover is the loop's self time: telemetry
+# rows, tracer writes, watchdog, prints — the row field ``loop_self_s``.
+STEP_PARENT = "train_step"
+# Spans that run CONCURRENTLY with the pipeline phases, and under which:
+# shown in the phase table for visibility, but excluded from the pipeline
+# total — counting an overlapped span into the denominator would misstate
+# every share.  ``param_gather``: the overlap-aware sharded update's
+# consume-phase gather.  ``batch_ready``: placement call to the placed
+# batch resident on every shard, waited for inside device_block.
+OVERLAY_PHASES = {
+    "param_gather": "data_wait/dispatch",
+    "batch_ready": "place_batch/dispatch/device_block",
+}
 
 
 def summarize(telemetry_dir: str, top: int = 5) -> str:
@@ -89,6 +98,14 @@ def summarize(telemetry_dir: str, top: int = 5) -> str:
         phase_total = sum(
             by_name.get(p, {"dur": 0.0})["dur"] for p in STEP_PHASES
         )
+        # With the phases' parents in the trace the shares are of the
+        # loop's whole wall-clock, self time included (clamped: a killed
+        # run can leave one step's phases without their parent).
+        parent = by_name.get(STEP_PARENT)
+        loop_self = (max(parent["dur"] - phase_total, 0.0)
+                     if parent is not None else None)
+        if loop_self is not None:
+            phase_total += loop_self
         lines.append(f"== Phase time shares ({trace_path}) ==")
         if phase_total > 0:
             for p in STEP_PHASES:
@@ -100,7 +117,14 @@ def summarize(telemetry_dir: str, top: int = 5) -> str:
                     f"  {p:<14} {share:5.1f}%  "
                     f"({d['dur'] / 1e6:.3f}s over {d['count']} spans)"
                 )
-            for p in OVERLAY_PHASES:
+            if loop_self is not None:
+                lines.append(
+                    f"  {'loop_self':<14} "
+                    f"{100.0 * loop_self / phase_total:5.1f}%  "
+                    f"({loop_self / 1e6:.3f}s of {parent['count']} "
+                    f"{STEP_PARENT} spans outside the phases above)"
+                )
+            for p, under in OVERLAY_PHASES.items():
                 d = by_name.get(p)
                 if d is None:
                     continue
@@ -112,11 +136,12 @@ def summarize(telemetry_dir: str, top: int = 5) -> str:
                 lines.append(
                     f"  {p:<14} {share:5.1f}%  "
                     f"({d['dur'] / 1e6:.3f}s over {d['count']} spans, "
-                    "overlapped — runs under data_wait/dispatch)"
+                    f"overlapped — runs under {under})"
                 )
         other = sorted(
             (n for n in by_name
-             if n not in STEP_PHASES and n not in OVERLAY_PHASES),
+             if n not in STEP_PHASES and n not in OVERLAY_PHASES
+             and n != STEP_PARENT),
             key=lambda n: -by_name[n]["dur"],
         )
         for n in other:
@@ -154,13 +179,25 @@ def summarize(telemetry_dir: str, top: int = 5) -> str:
                 f"p99 {percentile(iters, 0.99):.6f}  "
                 f"max {max(iters):.6f}"
             )
+            selfs = [float(r["loop_self_s"]) for r in rows
+                     if "loop_self_s" in r]
+            if selfs:
+                # Row k carries iteration k-1's: the period's remainder
+                # after the four phases, telemetry's own cost included.
+                lines.append(
+                    f"  loop_self_s (the loop's own time a step) "
+                    f"p50 {percentile(selfs, 0.5):.6f}  "
+                    f"p95 {percentile(selfs, 0.95):.6f}  "
+                    f"max {max(selfs):.6f}"
+                )
             lines.append(f"  top-{top} slowest steps:")
             slowest = sorted(rows, key=lambda r: -float(r["iter_s"]))[:top]
             for r in slowest:
                 phases = "  ".join(
                     f"{k}={float(r[k]):.6f}"
                     for k in ("data_wait_s", "place_s", "dispatch_s",
-                              "block_s", "param_gather_s")
+                              "block_s", "batch_ready_s", "loop_self_s",
+                              "param_gather_s")
                     if k in r
                 )
                 lines.append(
