@@ -19,8 +19,10 @@ fresh parameters) splits every flat-shard scheme's step into:
   a bucketed :func:`~distributed_machine_learning_tpu.ops.ring.ring_all_gather_flat`
   ppermute chain (bucket k's DMA hides bucket k±1's assembly; verified
   in the v5e AOT schedule).  Dispatch is async, so the gather executes
-  behind the host's ``data_wait``/``place_batch`` for the next batch
-  and its result is consumed by the next step's forward.
+  behind the host's bookkeeping and next dispatch (the next batch's
+  ``data_wait``/``place_batch`` already ran under the update program:
+  ``train_epoch`` holds one batch ahead) and its result is consumed by
+  the next step's forward.
 
 Both phases are pure data-movement refactorings of the sync step —
 the overlapped trajectory is BIT-IDENTICAL to the sync one (tested for
@@ -31,8 +33,8 @@ protocols cannot drift apart: the jitted ring-gather program builder
 and the ``param_gather`` telemetry bookkeeping (span from gather
 dispatch to observed readiness, closed at the next step's consume;
 ``pop_gather_seconds()`` feeds the train loop's ``param_gather_s`` row
-column — the span that should overlap ``data_wait`` on the trace
-timeline while ``device_block`` shrinks).
+column — the span that should outlast ``device_block`` on the trace
+timeline: the loss is back before the gather is).
 """
 
 from __future__ import annotations

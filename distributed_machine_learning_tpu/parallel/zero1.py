@@ -140,8 +140,9 @@ def make_zero1_train_step(
     program (a chunked :func:`~distributed_machine_learning_tpu.ops.ring.ring_all_gather_flat`
     ppermute chain, each hop an async window the scheduler fills with
     the per-chunk assembly), so it executes behind the host's
-    ``data_wait`` for the next batch and is consumed by the next step's
-    forward.  Dispatch is async, so the returned state's ``param_flat``
+    bookkeeping and next dispatch (``train_epoch`` holds one batch ahead:
+    the next batch was fetched and placed under the update program) and
+    is consumed by the next step's forward.  Dispatch is async, so the returned state's ``param_flat``
     is simply the in-flight gather result — checkpoint/eval callers
     block on it transparently and see the identical replicated vector.
     The two builds are BIT-IDENTICAL in trajectory (the gather is pure
@@ -151,8 +152,8 @@ def make_zero1_train_step(
     span from gather dispatch to observed readiness (closed at the next
     step's consume), and exposes ``step.pop_gather_seconds()`` so the
     train loop can add a ``param_gather_s`` column — the span that
-    should overlap ``data_wait`` on the trace timeline while
-    ``device_block`` shrinks.  ``step.update_for(cfg)`` /
+    should outlast ``device_block`` on the trace timeline (the loss is
+    back before the gather is).  ``step.update_for(cfg)`` /
     ``step.gather_inner`` expose the two jitted programs for AOT
     lowering and the HLO overlap audit (``bench/overlap_audit.py``).
     """

@@ -11,6 +11,7 @@ enqueue latency, not the step.
 
 from __future__ import annotations
 
+import collections
 import time
 from typing import Iterable
 
@@ -108,6 +109,7 @@ class _LoopTelemetry:
 
     def __init__(self, tel, state, train_step, timer):
         self.tel = tel
+        #: The phases bracketed since the last row: one iteration's.
         self.times: dict[str, tuple[float, float]] = {}
         self._train_step = train_step
         self._timer = timer
@@ -118,9 +120,14 @@ class _LoopTelemetry:
         # whoever cares about those passes ``events`` or ``until_step``,
         # whose own read the row then uses.
         self._step_base = int(jax.device_get(state.step))
-        self._t_ready = None
-        # (fetch start, children's seconds, batch) of the iteration
-        # whose period is still open: it ends at the next fetch.
+        # (examples, tokens, host bytes) of each batch fetched and not
+        # yet in a row: the loop runs one batch ahead of its rows.
+        self._geometry: collections.deque = collections.deque()
+        # (resident at, was the loss still pending then) of the batch
+        # this iteration waited for inside its block.
+        self._arrival = None
+        # (start, phases' seconds, batch) of the iteration whose period
+        # is still open: it ends where the next one starts.
         self._open = None
 
     def host_batch(self, images, labels) -> None:
@@ -128,19 +135,30 @@ class _LoopTelemetry:
         the bytes about to cross to the device: no sync, host arrays
         only."""
         shape = getattr(images, "shape", None)
-        self._n_examples = int(shape[0]) if shape else 0
-        self._n_tokens = (
+        self._geometry.append((
+            int(shape[0]) if shape else 0,
             int(shape[0]) * int(shape[1])
-            if shape is not None and len(shape) == 2
-            else None
-        )
-        self._h2d_bytes = sum(
-            a.nbytes for a in (images, labels) if isinstance(a, np.ndarray)
-        )
+            if shape is not None and len(shape) == 2 else None,
+            sum(a.nbytes for a in (images, labels)
+                if isinstance(a, np.ndarray)),
+        ))
 
-    def batch_ready(self) -> None:
-        """The placed batch is resident on every shard: now."""
-        self._t_ready = time.perf_counter()
+    def primed(self) -> None:
+        """Batch 0 was fetched and placed before the first iteration and
+        belongs to no row: its two spans go to the trace as they are."""
+        for name, (t0, t1) in self.times.items():
+            self.tel.tracer.complete(_PHASES[name][0], t0, t1, primed=True)
+        self.times.clear()
+
+    def batch_ready(self, loss=None) -> None:
+        """The newest placed batch is resident on every shard: now.
+        ``loss``: the running step's, when that batch is the NEXT step's
+        — asked, without a wait, whether it is still pending."""
+        t_ready, pending = time.perf_counter(), None
+        if loss is not None:
+            is_ready = getattr(loss, "is_ready", None)  # a host scalar has none
+            pending = is_ready is not None and not is_ready()
+        self._arrival = (t_ready, pending)
 
     def log_step(self, batch_idx: int, iter_time: float,
                  step_after: int | None) -> None:
@@ -153,8 +171,9 @@ class _LoopTelemetry:
         # The span and the (warmup-tagged) row still record it: the
         # compile step belongs on the timeline, not in the tail.
         warmup = self._timer._iter <= self._timer.skip_first
-        # ``place_s`` is 0 where the loop was given no placement call
-        # (``jit`` moves the batch inside ``dispatch_s``/``block_s``).
+        # ``place_s`` is 0 where the iteration placed nothing: the loop
+        # was given no placement call (``jit`` moves the batch inside
+        # ``dispatch_s``/``block_s``), or its fetch ended the epoch.
         row = {"batch": batch_idx, "iter_s": iter_time,
                **({"warmup": True} if warmup else {}), "place_s": 0.0}
         children = 0.0
@@ -164,29 +183,43 @@ class _LoopTelemetry:
                 tr.complete(span, t0, t1, step=batch_idx)
                 row[field] = t1 - t0
                 children += t1 - t0
-        if self._t_ready is not None:
-            # Placement call -> batch resident.  Overlaps place_batch,
-            # step_dispatch and the head of device_block: transfers the
-            # placement call returned before the end of.
+        if self._arrival is not None:
+            # Placement call -> batch resident, waited for at the head
+            # of device_block: it overlaps place_batch and, one batch
+            # ahead, the running step.
+            t_ready, loss_pending = self._arrival
             t_place = times["train.place_batch"][0]
-            tr.complete("batch_ready", t_place, self._t_ready,
-                        step=batch_idx)
-            row["batch_ready_s"] = self._t_ready - t_place
-            self._t_ready = None
-        t_fetch = times["train.data_wait"][0]
+            tr.complete("batch_ready", t_place, t_ready, step=batch_idx)
+            row["batch_ready_s"] = t_ready - t_place
+            if loss_pending is not None:
+                # One batch ahead: by how much the input beat the step
+                # (the loss came back that long after the batch was
+                # resident), or, negative, how long the block waited for
+                # the batch with the step already done and the device
+                # empty.
+                t_block, t_loss = times["train.device_block"]
+                lead = t_loss - t_ready if loss_pending \
+                    else t_block - t_ready
+                row["batch_lead_s"] = lead
+                if lead > 0:
+                    reg.counter("batches_ahead_total").inc()
+            self._arrival = None
+        t_start = min(t0 for t0, _ in times.values())
         times.clear()
         if self._open is not None:
-            # The period of an iteration ends at the next fetch, after
-            # its own row was written: row k carries iteration k-1's
-            # self time (as ``param_gather_s`` below does its gather).
+            # The period of an iteration ends where the next one starts,
+            # after its own row was written: row k carries iteration
+            # k-1's self time (as ``param_gather_s`` below does its
+            # gather).
             t_prev, children_prev, batch_prev = self._open
-            tr.complete("train_step", t_prev, t_fetch, step=batch_prev)
-            row["loop_self_s"] = (t_fetch - t_prev) - children_prev
-        self._open = (t_fetch, children, batch_idx)
+            tr.complete("train_step", t_prev, t_start, step=batch_prev)
+            row["loop_self_s"] = (t_start - t_prev) - children_prev
+        self._open = (t_start, children, batch_idx)
+        n_examples, n_tokens, h2d_bytes = self._geometry.popleft()
         data_wait_s = row["data_wait_s"]
         reg.counter("steps_total").inc()
-        reg.counter("h2d_bytes_total").inc(self._h2d_bytes)
-        row["h2d_bytes"] = self._h2d_bytes
+        reg.counter("h2d_bytes_total").inc(h2d_bytes)
+        row["h2d_bytes"] = h2d_bytes
         for _cname, _cval in (getattr(tel, "step_counters", None)
                               or {}).items():
             # Static per-step increments the CLI registered (e.g.
@@ -203,15 +236,17 @@ class _LoopTelemetry:
         if not warmup:
             reg.histogram("step_seconds").observe(iter_time)
             reg.histogram("data_wait_seconds").observe(data_wait_s)
-        wall = iter_time + data_wait_s
-        examples_per_s = self._n_examples / wall if wall > 0 else 0.0
+        # The four phases are the iteration's wall-clock in either order
+        # (``iter_time`` holds the fetch only when it ran under the step).
+        wall = children
+        examples_per_s = n_examples / wall if wall > 0 else 0.0
         row["examples_per_s"] = examples_per_s
         reg.gauge("examples_per_s").set(examples_per_s)
         # Overlap-aware sharded updates (zero1/fsdp overlap=True)
         # expose the consume-phase gather span: dispatch → observed
         # ready, closed at the NEXT step's consume, so row k
         # reports step k−1's gather.  On the trace timeline the
-        # param_gather span overlaps data_wait — the 2004.13336
+        # param_gather span outlasts device_block — the 2004.13336
         # proof that the weight-update gather left the critical
         # path (device_block shrinks by what param_gather hides).
         pop_gather = getattr(self._train_step, "pop_gather_seconds", None)
@@ -219,8 +254,8 @@ class _LoopTelemetry:
             gather_s = pop_gather()
             if gather_s is not None:
                 row["param_gather_s"] = gather_s
-        if self._n_tokens is not None:
-            tokens_per_s = self._n_tokens / wall if wall > 0 else 0.0
+        if n_tokens is not None:
+            tokens_per_s = n_tokens / wall if wall > 0 else 0.0
             row["tokens_per_s"] = tokens_per_s
         else:
             tokens_per_s = None
@@ -238,7 +273,7 @@ class _LoopTelemetry:
 
     def close(self) -> None:
         """The last iteration's step span ends where the loop did: at
-        the fetch that ended the epoch, if there was one."""
+        the fetch that ended the epoch, if that came after its row."""
         if self._open is not None:
             last_fetch = self.times.get("train.data_wait")
             self.tel.tracer.complete(
@@ -268,7 +303,26 @@ def train_epoch(
     (e.g. `shard_batch(mesh, ...)`); defaults to identity (jit handles
     transfer for the single-device path).
 
-    ``stop``: optional zero-arg predicate polled at every step boundary
+    **The loop holds one placed batch ahead of the step.**  It primes
+    itself with batch 0 (fetch, place); iteration k then (1) dispatches
+    step k on the batch already placed, (2) fetches batch k+1, applies
+    the ``max_iters`` / ``stop`` tests to it and places it — the loader's
+    wait, the host's gather and the transfer ``device_put`` started all
+    run while the device runs step k — and (3) blocks on loss k and does
+    step k's bookkeeping.  One *step* is in flight at a time (step k+1 is
+    dispatched only after loss k is back, so a skipped or non-finite step
+    is seen before the next starts); what runs ahead is one *batch*.
+    When the fetch in (2) ends the epoch the loop finishes (3) for step k
+    and returns: every placed batch is trained, and a fetched batch is
+    discarded only by the ``max_iters`` and ``stop`` tests, before
+    placement.  Without a ``place_batch`` the fetch still moves under
+    the step; nothing is placed ahead.  The exception is ``until_step``:
+    whether batch k+1 is wanted then depends on step k's result, so the
+    loop fetches it only after step k's bookkeeping (fetch, place,
+    dispatch, block — nothing overlaps, and the batches consumed are
+    exactly those the target needs).
+
+    ``stop``: optional zero-arg predicate polled once a fetched batch
     (e.g. a ``runtime/resilience.PreemptionHandler``) — True ends the
     epoch cleanly with state consistent, so the caller can checkpoint.
     ``watchdog``: optional ``runtime/resilience.Watchdog``; beaten once
@@ -278,36 +332,51 @@ def train_epoch(
     spent on setup/compile.
     ``events``: optional ``runtime/faults.FaultEvents``; counts steps the
     non-finite-gradient guard skipped (step counter unchanged after a
-    consumed batch) and dynamic loss-scale adjustments.
+    consumed batch) and dynamic loss-scale adjustments.  The counter is
+    read once before the loop and once after each step: step k's reading
+    is step k+1's "before".
     ``until_step``: optional absolute step-counter target — the epoch
     ends once ``state.step`` reaches it.  Unlike ``max_iters`` (a batch
     cap) this counts *applied* updates, so guard-skipped steps are
     retried with further batches — the supervisor's contract that a
     faulted run still lands on the same final step count.
+    ``timer``: an iteration's time runs from the dispatch of step k to
+    its loss (the reference's printed "average time per iteration");
+    one batch ahead, the fetch and placement of batch k+1 run inside it.
 
     One iteration is a closed set of spans, each bracketed once
     (``_PHASES``): ``train.step`` (a ``StepTraceAnnotation`` numbered by
-    the batch) holds ``train.data_wait`` / ``train.place_batch`` /
-    ``train.step_dispatch`` / ``train.device_block`` /
+    the batch) holds ``train.step_dispatch`` / ``train.data_wait`` /
+    ``train.place_batch`` / ``train.device_block`` /
     ``train.bookkeeping`` on the profiler's clock, always — without a
     profiler session each is a flag test, and with one (``--trace-dir``)
-    they lie against the device's operations in the trace.
+    they lie against the device's operations in the trace.  (With
+    ``until_step`` the fetch and placement come last, after the
+    bookkeeping; batch 0's lie before ``train.step`` 0 in either order.)
 
     ``telemetry``: optional ``telemetry.Telemetry``; defaults to the
     process-wide install (``get_telemetry()``, None unless a CLI set
     ``--telemetry-dir``).  When active, the same brackets also read the
-    host clock: spans ``data_wait`` / ``place_batch`` /
-    ``step_dispatch`` / ``device_block`` under a ``train_step`` parent go
-    to the Chrome trace, and each step logs an attempt-tagged metrics
-    row (the phases' seconds, examples/s, tokens/s, MFU when the CLI
-    installed a FLOPs model) with three fields only this loop can know:
-    ``batch_ready_s`` (placement call -> the placed batch resident on
-    every shard: the loop waits for it inside ``device_block``, after
-    the dispatch, so nothing the step needs is delayed and the reading
-    is never below ``place_s + dispatch_s``; span ``batch_ready``;
-    absent without a ``place_batch``), ``h2d_bytes``
-    (the host batch's bytes; counter ``h2d_bytes_total``) and
-    ``loop_self_s`` (the iteration's period, fetch to next fetch, minus
+    host clock: spans ``step_dispatch`` / ``data_wait`` / ``place_batch``
+    / ``device_block`` under a ``train_step`` parent go to the Chrome
+    trace, and each step logs an attempt-tagged metrics row (the phases'
+    seconds, examples/s, tokens/s, MFU when the CLI installed a FLOPs
+    model).  Row k holds the phases of iteration k — so, one batch
+    ahead, the fetch and placement of batch k+1 — and four fields only
+    this loop can know: ``batch_ready_s`` (placement call -> the placed
+    batch resident on every shard; the loop waits for it at the head of
+    ``device_block``, where it blocks anyway, so nothing is delayed;
+    span ``batch_ready``; absent where nothing was placed),
+    ``batch_lead_s`` (at the instant the batch is resident the loop asks
+    ``loss.is_ready()``, no wait: not yet — the arrival was hidden, and
+    the field is loss ready − batch ready, positive, the margin by which
+    the input beat the step; already — the device sat empty for the
+    arrival, and the field is minus the time the block waited for the
+    batch; counter ``batches_ahead_total`` counts the positive ones
+    beside ``steps_total``; absent in an epoch's last iteration and with
+    ``until_step``), ``h2d_bytes`` (the bytes of the host batch step k
+    trained; counter ``h2d_bytes_total``) and ``loop_self_s`` (the
+    iteration's period, its first phase to the next iteration's, minus
     the four phases: this loop's own bookkeeping, telemetry included —
     what the instrumentation costs when it is on.  A row is written
     before its iteration ends, so row k carries iteration k-1's).  The
@@ -323,45 +392,55 @@ def train_epoch(
     if watchdog is not None:
         watchdog.beat()
     batches = iter(batches)
+
+    def next_batch(idx: int):
+        """Batch ``idx`` fetched, tested and placed; None where the
+        fetch ends the epoch."""
+        with _phase("train.data_wait", rec):
+            try:
+                images, labels = next(batches)
+            except StopIteration:
+                return None
+        if idx == max_iters:  # part1/main.py:32-33
+            return None
+        if stop is not None and stop():
+            rank0_print(
+                f"stop requested; ending epoch after {idx} iterations"
+            )
+            return None
+        if rec is not None:
+            rec.host_batch(images, labels)
+        if place_batch is None:
+            return images, labels
+        with _phase("train.place_batch", rec):
+            return place_batch(images, labels)
+
+    ahead = until_step is None
+    batch = next_batch(0)
+    if rec is not None and ahead:
+        rec.primed()
+    if events is not None:
+        step_before = int(jax.device_get(state.step))
+        scale_before = getattr(state, "loss_scale", None)
+        if scale_before is not None:
+            scale_before = float(scale_before)
     batch_idx = 0
-    while True:
+    while batch is not None:
         with profiling.annotate("train.step", step_num=batch_idx):
-            with _phase("train.data_wait", rec):
-                try:
-                    images, labels = next(batches)
-                except StopIteration:
-                    break
-            if batch_idx == max_iters:  # part1/main.py:32-33
-                break
-            if stop is not None and stop():
-                rank0_print(
-                    f"stop requested; ending epoch after {batch_idx} "
-                    "iterations"
-                )
-                break
-            if events is not None:
-                step_before = int(jax.device_get(state.step))
-                # Read the value NOW: the jitted step donates its input
-                # state, so this buffer is dead after the call.
-                scale_before = getattr(state, "loss_scale", None)
-                if scale_before is not None:
-                    scale_before = float(scale_before)
-            if tel is not None:
-                rec.host_batch(images, labels)
             timer.start()
-            if place_batch is not None:
-                with _phase("train.place_batch", rec):
-                    images, labels = place_batch(images, labels)
             with _phase("train.step_dispatch", rec):
-                state, loss = train_step(state, images, labels)
+                state, loss = train_step(state, *batch)
+            if ahead:
+                # Under the running step: nothing here needs its result.
+                batch = next_batch(batch_idx + 1)
             with _phase("train.device_block", rec):
-                if tel is not None and place_batch is not None:
-                    # When did the placed batch arrive?  The step is
-                    # already dispatched and the loop blocks on its loss
-                    # next anyway, so this wait delays nothing.  (No
-                    # step donates its batch.)
-                    jax.block_until_ready((images, labels))
-                    rec.batch_ready()
+                if (tel is not None and place_batch is not None
+                        and batch is not None):
+                    # When did the newest placed batch arrive?  The loop
+                    # blocks on the loss next anyway, so this wait delays
+                    # nothing.  (No step donates its batch.)
+                    jax.block_until_ready(batch)
+                    rec.batch_ready(loss if ahead else None)
                 loss = jax.block_until_ready(loss)
             iter_time = timer.stop()
             with profiling.annotate("train.bookkeeping"):
@@ -380,12 +459,14 @@ def train_epoch(
                     # already be counted.
                     if step_after == step_before:
                         events.skipped_steps += 1
+                    step_before = step_after
                     if scale_before is not None:
-                        before, after = scale_before, float(state.loss_scale)
-                        if after < before:
+                        scale_after = float(state.loss_scale)
+                        if scale_after < scale_before:
                             events.scaler_backoffs += 1
-                        elif after > before:
+                        elif scale_after > scale_before:
                             events.scaler_growths += 1
+                        scale_before = scale_after
                 if watchdog is not None:
                     watchdog.beat()
                 if tel is not None:
@@ -400,8 +481,10 @@ def train_epoch(
                     )
                 if (batch_idx + 1) % loss_print_every == 0:
                     _print_loss(batch_idx + 1, loss)  # part1/main.py:49-50
-            if until_step is not None and step_after >= until_step:
-                break
+            if not ahead:
+                if step_after >= until_step:
+                    break
+                batch = next_batch(batch_idx + 1)
         batch_idx += 1
     if tel is not None:
         rec.close()

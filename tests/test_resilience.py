@@ -240,7 +240,8 @@ def test_loader_hanging_on_first_batch_is_caught_as_a_stall():
     assert wd.stalled and fired
 
 
-def test_train_epoch_until_step_counts_applied_updates():
+@pytest.mark.parametrize("placed", [False, True])
+def test_train_epoch_until_step_counts_applied_updates(placed):
     # until_step is an APPLIED-updates target: a step that leaves the
     # counter unchanged (the guard's skip) consumes a batch but does not
     # count, so the epoch pulls further data to reach the target.
@@ -250,24 +251,103 @@ def test_train_epoch_until_step_counts_applied_updates():
         def __init__(self, step):
             self.step = step
 
-    consumed = []
+    consumed, order = [], []
 
     def batches():
         for i in range(100):
             consumed.append(i)
+            order.append(("fetch", i))
             yield (i, i)
 
+    def place(x, y):
+        order.append(("place", x))
+        return x, y
+
     def step_skipping_batch_1(s, x, y):
+        order.append(("step", x))
         return (S(s.step) if x == 1 else S(s.step + 1)), 0.0
 
     events = FaultEvents()
     out, _ = train_epoch(
         step_skipping_batch_1, S(0), batches(), max_iters=10**9,
-        until_step=3, events=events,
+        until_step=3, events=events, place_batch=place if placed else None,
     )
     assert out.step == 3
     assert consumed == [0, 1, 2, 3]  # four batches for three updates
     assert events.skipped_steps == 1
+    # Whether a further batch is wanted depends on the step's result, so
+    # under until_step nothing is fetched (or placed) ahead of it.
+    per_batch = ["fetch", "place", "step"] if placed else ["fetch", "step"]
+    assert order == [(what, i) for i in range(4) for what in per_batch]
+
+
+def test_train_epoch_events_carry_the_counter_from_step_to_step(monkeypatch):
+    # Without until_step the loop runs one batch ahead; the skip
+    # accounting then reads the counter once before the loop and once a
+    # step: step k's "after" is step k+1's "before".
+    import jax
+
+    from distributed_machine_learning_tpu.train.loop import train_epoch
+
+    class S:
+        def __init__(self, step, loss_scale):
+            self.step, self.loss_scale = step, loss_scale
+
+    def step(s, x, y):  # skips batches 1 and 2, halving the scale
+        if x in (1, 2):
+            return S(s.step, s.loss_scale / 2), 0.0
+        return S(s.step + 1, s.loss_scale * (2 if x == 4 else 1)), 0.0
+
+    reads = []
+    get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: reads.append(x) or get(x))
+    events = FaultEvents()
+    out, _ = train_epoch(step, S(7, 8.0), [(i, i) for i in range(6)],
+                         max_iters=10, events=events,
+                         loss_print_every=10**9)
+    assert out.step == 7 + 4
+    assert events.skipped_steps == 2
+    assert events.scaler_backoffs == 2 and events.scaler_growths == 1
+    assert reads == [7, 8, 8, 8, 9, 10, 11]  # one before, one a step
+
+
+def test_train_epoch_stop_while_a_batch_is_ahead_trains_it():
+    # The stop predicate is polled once a fetched batch, as before; the
+    # fetch now runs under the step in flight, so a stop seen at the fetch
+    # of batch k+1 still finishes step k — the batch already placed — and
+    # discards only the batch just fetched, never a placed one.
+    from distributed_machine_learning_tpu.train.loop import train_epoch
+
+    class S:
+        step = 0
+
+    seen = {"fetched": [], "placed": [], "trained": [], "polls": 0}
+
+    def batches():
+        for i in range(10):
+            seen["fetched"].append(i)
+            yield (i, i)
+
+    def place(x, y):
+        seen["placed"].append(x)
+        return x, y
+
+    def step(s, x, y):
+        seen["trained"].append(x)
+        return s, 0.0
+
+    def stop():
+        seen["polls"] += 1
+        return seen["polls"] > 3  # true at the fourth fetched batch
+
+    wd = Watchdog(timeout_s=60).start()
+    train_epoch(step, S(), batches(), place_batch=place, max_iters=10,
+                stop=stop, watchdog=wd)
+    wd.stop()
+    assert seen["fetched"] == [0, 1, 2, 3]
+    assert seen["placed"] == seen["trained"] == [0, 1, 2]
+    assert seen["polls"] == 4 and not wd.stalled
 
 
 # ---------------------------------------------------------------------------

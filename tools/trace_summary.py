@@ -6,13 +6,15 @@ Reads the artifacts a run's ``--telemetry-dir`` produced
 
 - per-phase time shares from the Chrome trace's complete events
   (data_wait / place_batch / step_dispatch / device_block, the loop's
-  own self time where the trace has ``train_step`` parents /
+  own self time where the trace has ``train_step`` parents, the next
+  batch's arrival as an overlay /
   checkpoint_save / eval / ...), the first diagnosis dimension for
   stragglers and sync overhead — trace *instants* (fault markers,
   gang_shrink, restarts) are counted in the same table: a fault that
   fired during a phase is the context that phase's duration needs;
 - the top-5 slowest steps from the metrics JSONL (attempt-tagged), with
-  their phase breakdown;
+  their phase breakdown, and the share of steps that hid their next
+  batch's arrival (``batch_lead_s`` > 0);
 - attempt/restart structure when the run was supervised.
 
 Tolerates the artifacts of a crash: a torn final JSONL line and an
@@ -63,10 +65,14 @@ STEP_PARENT = "train_step"
 # total — counting an overlapped span into the denominator would misstate
 # every share.  ``param_gather``: the overlap-aware sharded update's
 # consume-phase gather.  ``batch_ready``: placement call to the placed
-# batch resident on every shard, waited for inside device_block.
+# batch resident on every shard, waited for at the head of device_block.
+# The loop holds one batch ahead, so the span tagged step k is batch
+# k+1's arrival: it lies under step k's placement and block, the block of
+# the step BEFORE the one that trains the batch.
 OVERLAY_PHASES = {
-    "param_gather": "data_wait/dispatch",
-    "batch_ready": "place_batch/dispatch/device_block",
+    "param_gather": "the phases from one dispatch to the next",
+    "batch_ready": "place_batch/device_block of the step before "
+                   "(one batch ahead)",
 }
 
 
@@ -91,8 +97,13 @@ def summarize(telemetry_dir: str, top: int = 5) -> str:
                 instants[name] = instants.get(name, 0) + 1
         by_name: dict[str, dict] = {}
         for e in events:
-            d = by_name.setdefault(e.get("name", "?"),
-                                   {"dur": 0.0, "count": 0})
+            name = e.get("name", "?")
+            if (e.get("args") or {}).get("primed"):
+                # Batch 0's fetch and placement, before the first
+                # iteration: under no train_step parent, so not among
+                # the shares of the loop's wall-clock.
+                name += "[primed]"
+            d = by_name.setdefault(name, {"dur": 0.0, "count": 0})
             d["dur"] += float(e.get("dur", 0.0))
             d["count"] += 1
         phase_total = sum(
@@ -190,14 +201,29 @@ def summarize(telemetry_dir: str, top: int = 5) -> str:
                     f"p95 {percentile(selfs, 0.95):.6f}  "
                     f"max {max(selfs):.6f}"
                 )
+            leads = [float(r["batch_lead_s"]) for r in rows
+                     if "batch_lead_s" in r]
+            if leads:
+                # One batch ahead: positive = the next batch was resident
+                # that long before this step's loss came back (its arrival
+                # hidden under the step); negative = the block waited that
+                # long for the batch with the device already empty.
+                hidden = sum(1 for v in leads if v > 0)
+                lines.append(
+                    f"  batch_lead_s (next batch resident before the "
+                    f"loss) hidden in {hidden} of {len(leads)} steps "
+                    f"({100.0 * hidden / len(leads):.1f}%)  "
+                    f"p50 {percentile(leads, 0.5):.6f}  "
+                    f"min {min(leads):.6f}"
+                )
             lines.append(f"  top-{top} slowest steps:")
             slowest = sorted(rows, key=lambda r: -float(r["iter_s"]))[:top]
             for r in slowest:
                 phases = "  ".join(
                     f"{k}={float(r[k]):.6f}"
                     for k in ("data_wait_s", "place_s", "dispatch_s",
-                              "block_s", "batch_ready_s", "loop_self_s",
-                              "param_gather_s")
+                              "block_s", "batch_ready_s", "batch_lead_s",
+                              "loop_self_s", "param_gather_s")
                     if k in r
                 )
                 lines.append(
