@@ -104,6 +104,16 @@ def make_parser():
                         "the sequence over a third mesh axis and runs "
                         "ring attention over it while the MoE dispatch "
                         "all_to_alls over the expert axis")
+    p.add_argument("--model-config", dest="model_config", default=None,
+                   help="an HF-style config.json: its sizes replace the "
+                        "size flags (hidden_size, num_hidden_layers, "
+                        "num_attention_heads, num_key_value_heads, "
+                        "vocab_size) and it carries what no flag does "
+                        "(intermediate_size, rope_theta, norm_epsilon); "
+                        "its model_type picks the module — 'qwen3_next' is "
+                        "the hybrid Gated-DeltaNet / gated-attention MoE "
+                        "(models/hybrid_moe.py, --parallel dp), anything "
+                        "else the dense TransformerLM")
     p.add_argument("--d-model", dest="d_model", default=256, type=int)
     p.add_argument("--n-layers", dest="n_layers", default=4, type=int)
     p.add_argument("--n-heads", dest="n_heads", default=8, type=int)
@@ -261,6 +271,55 @@ def synthetic_tokens(rng: np.random.Generator, batch: int, seq_len: int,
     return rng.integers(0, vocab, (batch, seq_len + 1)).astype(np.int32)
 
 
+#: HF-style configuration key -> the size flag's destination it replaces.
+_CONFIG_SIZES = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "vocab_size": "vocab",
+}
+
+
+def read_model_config(args) -> dict:
+    """``--model-config``'s file ({} without the flag), its sizes written
+    over the size flags' values so that everything downstream of the
+    parser (banner, data, FLOPs model) sees the model that is built."""
+    path = getattr(args, "model_config", None)
+    if path is None:
+        return {}
+    import json
+
+    with open(path, encoding="utf-8") as f:
+        config = json.load(f)
+    for key, dest in _CONFIG_SIZES.items():
+        if key in config:
+            setattr(args, dest, config[key])
+    return config
+
+
+def dp_model(args, config: dict, **common):
+    """The model ``--parallel dp`` trains: the module ``config``'s
+    ``model_type`` names, or ``TransformerLM(**common)``."""
+    if config.get("model_type") != "qwen3_next":
+        return TransformerLM(**common)
+    from distributed_machine_learning_tpu.models.hybrid_moe import (
+        HybridMoELM,
+        HybridMoESizes,
+    )
+    from distributed_machine_learning_tpu.models.transformer import (
+        _flash_wins,
+    )
+
+    attn = common["attn_impl"]
+    if attn == "auto":
+        attn = "flash" if _flash_wins(args.seq_len) else "dense"
+    return HybridMoELM(
+        HybridMoESizes.from_config(config), attn_impl=attn,
+        compute_dtype=common["compute_dtype"], remat=common["remat"],
+        remat_policy=common["remat_policy"])
+
+
 def build(args):
     """(step, state, place, model, params_fn) for the chosen parallelism
     scheme; ``params_fn(state)`` yields the replicated params pytree for
@@ -270,6 +329,21 @@ def build(args):
     n = jax.device_count()
     dtype = jnp.bfloat16 if args.compute_dtype == "bfloat16" else jnp.float32
     attn = getattr(args, "attn", "auto")
+    config = read_model_config(args)
+    if config.get("model_type") == "qwen3_next" and args.parallel != "dp":
+        raise ValueError(
+            "--model-config with model_type 'qwen3_next' trains under "
+            f"--parallel dp only (got --parallel {args.parallel}): the "
+            "hybrid model has no sequence-, tensor- or pipeline-sharded "
+            "step yet")
+    carried = sorted({"intermediate_size", "rope_theta", "norm_epsilon"}
+                     & set(config))
+    if carried and args.parallel in ("pp", "3d", "ep"):
+        # These steps build their blocks themselves (parallel/pipeline.py,
+        # models/moe.py) and would run the defaults in silence.
+        raise ValueError(
+            f"--model-config states {carried}, which --parallel "
+            f"{args.parallel} does not take from a file yet")
     if args.parallel in ("pp", "fsdp") and attn == "auto":
         # These steps resolve "auto" to the dense path they default to
         # (pp accepts an EXPLICIT --attn flash — its pipe-axis shard_map
@@ -284,6 +358,11 @@ def build(args):
         n_kv_heads=args.n_kv_heads,
         # ring/ulysses overwrite this below; all other modes honor it.
         attn_impl=attn,
+        # What only a configuration file states (None/absent: the
+        # defaults the flags-only path has always run).
+        d_ff=config.get("intermediate_size"),
+        rope_base=float(config.get("rope_theta", 10000.0)),
+        ln_eps=config.get("norm_epsilon", 1e-6),
     )
     from distributed_machine_learning_tpu.train.optimizers import get_optimizer
 
@@ -385,7 +464,7 @@ def build(args):
                     f"the {n}-device data axis"
                 )
             mesh = make_mesh(n, ("batch", "seq"), (n, 1))
-            model = TransformerLM(**common)
+            model = dp_model(args, config, **common)
         else:
             if args.seq_len % n:
                 raise ValueError(
@@ -771,6 +850,7 @@ def main(argv=None) -> RunResult:
         prev_telemetry = set_telemetry(telemetry)
     ctx = initialize_from_flags(args.master_ip, args.rank, args.num_nodes)
     try:
+        read_model_config(args)  # the sizes the banner and the data use
         rank0_print(
             f"lm parallel={args.parallel} devices={jax.device_count()} "
             f"d_model={args.d_model} layers={args.n_layers} "

@@ -1,5 +1,12 @@
 """Mixture-of-Experts transformer (Switch-style top-1 routing).
 
+Routing and the dropless grouped compute are ``ops/grouped.py``'s one
+routed front end (``route_topk`` + ``grouped_expert_mlp``: k experts a
+token, GELU or gated SiLU experts, with or without biases, all experts or
+a held range of them); this module is its ``k = 1``, GELU, biased,
+all-experts-held case, and ``models/hybrid_moe.py`` its top-k, gated,
+bias-free, held-range case.
+
 Model family beyond the reference (EP/MoE absent — SURVEY.md §2.3), built
 for expert parallelism the GSPMD way: every expert-owned parameter carries
 a leading ``[n_experts, ...]`` axis, routing is expressed as static-shape
@@ -28,9 +35,10 @@ import jax
 import jax.numpy as jnp
 
 class MoEMLP(nn.Module):
-    """Top-1 routed expert MLP over [B, T, D] activations.
+    """Switch (top-1) routed expert MLP over [B, T, D] activations: the
+    ``k = 1`` case of ``ops/grouped.py::route_topk``.
 
-    Two compute paths behind one routing front-end (``moe_impl``):
+    Two compute paths behind that one routing front end (``moe_impl``):
 
     - ``"einsum"`` (default): Switch-style capacity + overflow drops via
       static one-hot dispatch/combine einsums — the GSPMD-shardable form
@@ -145,8 +153,12 @@ class MoEMLP(nn.Module):
             tokens.astype(jnp.float32)
         )
         probs = jax.nn.softmax(gate, axis=-1)  # [N, E]
-        expert_idx = jnp.argmax(probs, axis=-1)  # [N]
-        expert_prob = jnp.max(probs, axis=-1)  # [N]
+        from distributed_machine_learning_tpu.ops.grouped import route_topk
+
+        # Switch routing is the k = 1 case of the one routed front end.
+        routed_idx, routed_weights = route_topk(probs, 1)  # [N, 1] each
+        expert_idx = routed_idx[:, 0]  # [N]
+        expert_prob = routed_weights[:, 0]  # [N]
         onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # [N, E]
 
         # Switch aux loss: E · Σ_e (token fraction)·(mean router prob).
@@ -229,10 +241,10 @@ class MoEMLP(nn.Module):
             )
 
             y = grouped_expert_mlp(
-                tokens.astype(dt), expert_idx, w_in, b_in, w_out, b_out,
+                tokens.astype(dt), routed_idx, routed_weights, w_in, w_out,
+                b_in=b_in, b_out=b_out,
                 w_in_scale=w_in_scale, w_out_scale=w_out_scale,
             )
-            y = y * expert_prob[:, None].astype(dt)
             if self.tp_axis is not None:
                 # Megatron's second g-collective, per expert: w_out is
                 # row-parallel over the local d_ff slice (b_out and the

@@ -34,11 +34,19 @@ from distributed_machine_learning_tpu.ops.ring_attention import (
 )
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, base: float = 10000.0):
+def apply_rope(x: jax.Array, positions: jax.Array, base: float = 10000.0,
+               rotary_dim: int | None = None):
     """Rotate [B, L, H, D] by per-position angles; fp32 math, dtype
     preserved.  ``positions``: [L] (one stream position per slot) or
     [B, L] (per-ROW absolute positions — the batched-frontier decode
-    path, where each batch row's committed stream has its own length)."""
+    path, where each batch row's committed stream has its own length).
+    ``rotary_dim``: rotate only the first ``rotary_dim`` dimensions of
+    each head (half-split pairs inside that width, frequencies over that
+    width) and pass the rest through — partial rotary embeddings; None
+    rotates the whole head."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        rotated = apply_rope(x[..., :rotary_dim], positions, base)
+        return jnp.concatenate([rotated, x[..., rotary_dim:]], axis=-1)
     d_half = x.shape[-1] // 2
     freqs = base ** (-jnp.arange(d_half, dtype=jnp.float32) / d_half)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [..., L, Dh/2]
@@ -265,6 +273,7 @@ class Attention(nn.Module):
     # the start-0 prefill fast path — speculative decoding's verify
     # pass (inference/speculative.py).  decode=True only.
     decode_continuation: bool = False
+    rope_base: float = 10000.0  # the rotation's base (a config's rope_theta)
 
     @nn.compact
     def __call__(self, x, positions):
@@ -306,8 +315,8 @@ class Attention(nn.Module):
             q = proj((self.n_heads, head_dim), -1, "q")(x)
             kv = proj((2, self.n_kv_heads, head_dim), -1, "kv")(x)
             k, v = kv[:, :, 0], kv[:, :, 1]  # [B, L, Hkv, Dh]
-        q = apply_rope(q, positions)
-        k = apply_rope(k, positions)
+        q = apply_rope(q, positions, self.rope_base)
+        k = apply_rope(k, positions, self.rope_base)
         n_rep = self.n_heads // k.shape[2]
         if self.decode:
             # Cache shape fixes the max sequence length at init time
@@ -619,7 +628,8 @@ def _mlp_sublayer(mdl: "Block", h: jax.Array) -> jax.Array:
     names land in the same scope whether or not the wrap is applied, so
     checkpoints are layout-compatible across remat policies."""
     d_out = h.shape[-1]
-    h = nn.LayerNorm(dtype=mdl.compute_dtype, name="ln2")(h)
+    h = nn.LayerNorm(epsilon=mdl.ln_eps, dtype=mdl.compute_dtype,
+                     name="ln2")(h)
     if mdl.mlp_factory is not None:
         return mdl.mlp_factory()(h)
     if mdl.weight_quant == "int8":
@@ -682,10 +692,13 @@ class Block(nn.Module):
     tp_axis: str | None = None  # manual TP decode (see Attention.tp_axis)
     head_dim: int | None = None  # explicit head width (TP decode clones)
     decode_continuation: bool = False  # verify-pass decode (speculative)
+    rope_base: float = 10000.0
+    ln_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, positions):
-        h = nn.LayerNorm(dtype=self.compute_dtype, name="ln1")(x)
+        h = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.compute_dtype,
+                         name="ln1")(x)
         x = x + Attention(
             n_heads=self.n_heads,
             attn_impl=self.attn_impl,
@@ -702,6 +715,7 @@ class Block(nn.Module):
             tp_axis=self.tp_axis,
             head_dim=self.head_dim,
             decode_continuation=self.decode_continuation,
+            rope_base=self.rope_base,
             name="attn",
         )(h, positions)
         if self.remat_mlp and not self.decode:
@@ -776,6 +790,11 @@ class TransformerLM(nn.Module):
     #   "block" — whole-block jax.checkpoint (the maximal-savings
     #     fallback, ~1·L·E bytes/layer): use when "mlp" does not fit.
     remat_policy: str = "mlp"
+    # What a configuration file states beside the sizes (cli.lm
+    # --model-config: rope_theta, norm_epsilon); the defaults are the
+    # values the flags-only path has always run.
+    rope_base: float = 10000.0
+    ln_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False,
@@ -862,9 +881,12 @@ class TransformerLM(nn.Module):
                 tp_axis=self.tp_axis,
                 head_dim=self.head_dim,
                 decode_continuation=self.decode_continuation,
+                rope_base=self.rope_base,
+                ln_eps=self.ln_eps,
                 name=f"block_{i}",
             )(x, positions)
-        x = nn.LayerNorm(dtype=self.compute_dtype, name="ln_f")(x)
+        x = nn.LayerNorm(epsilon=self.ln_eps, dtype=self.compute_dtype,
+                         name="ln_f")(x)
         if return_hidden:
             return x
         if self.weight_quant == "int8":

@@ -14,18 +14,30 @@ and the expert matmuls run at dense-matmul MFU (measured on this
 repo's chip: 134 TF/s ragged vs 94 TF/s effective for the einsum
 fragment at N=8k, D=2k, F=8k — before counting the combine einsum).
 
-It is also **dropless**: every token reaches its expert, with no
-capacity rounding — group sizes are data-dependent *values*, which
-``ragged_dot`` consumes without shape dynamism (output shape stays
-[N, F]).  Capacity/overflow semantics (Switch's) remain available via
-the einsum path; parity between the two holds whenever capacity is
-ample enough that nothing drops (tested).
+It is **dropless**: every token reaches its expert, with no capacity
+rounding — group sizes are data-dependent *values*, which ``ragged_dot``
+consumes without shape dynamism (the row buffer holds every assignment
+there is).  Capacity/overflow semantics (Switch's) remain available via
+the einsum path; parity between the two holds whenever capacity is ample
+enough that nothing drops (tested).
+
+One routed front end serves every model: :func:`route_topk` picks ``k``
+experts a token from the router's probabilities (``k = 1``: Switch; with
+``renormalize`` the chosen weights sum to one) and
+:func:`grouped_expert_mlp` computes, for GELU or gated-SiLU experts with
+or without biases, the part of the weighted sum that the experts HELD
+here give.  The router keeps its whole width whatever is held: a chip
+that holds experts ``[first_held, first_held + E_local)`` of a wider
+layer (one member of an expert-parallel group, run without the exchange)
+sorts assignments to absent experts into a tail group that no matmul
+touches, and may bound its row buffer near the share it expects, every
+row past the bound counted.
 
 Scope: single-device, shard_map-style data parallelism (each device
 runs this on its local tokens), and — via
 :func:`grouped_expert_mlp_ep` — real expert parallelism under a
-fully-manual shard_map: token rows travel to their expert's owner
-device through an explicit ``lax.all_to_all`` along the expert mesh
+fully-manual shard_map (top-1 only): token rows travel to their expert's
+owner device through an explicit ``lax.all_to_all`` along the expert mesh
 axis, ``ragged_dot`` runs over the received groups locally, and the
 outputs ride the inverse all-to-all home.  ``ragged_dot`` has no GSPMD
 partitioning rule, so the automatic-partitioner EP step keeps the
@@ -102,26 +114,141 @@ def _permute_rows_bwd(res, ct):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+def route_topk(probs: jax.Array, k: int, renormalize: bool = False):
+    """The ``k`` experts of every token and their weights.
+
+    ``probs``: [N, E] router probabilities over ALL experts, held here or
+    not.  Returns ``(expert_idx [N, k] int32, weights [N, k])`` in
+    descending order of probability; ``renormalize`` makes each token's
+    ``k`` weights sum to one (over the chosen ``k``, wherever they live).
+    ``k = 1`` is Switch routing: the argmax and its probability."""
+    weights, expert_idx = lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return expert_idx, weights
+
+
+def _gather_sum(rows, slot_of_assignment, weights=None):
+    """``out[n] = Σ_j weights[n, j] · rows[slot_of_assignment[n, j]]`` in
+    float32, with a zero row behind the buffer for assignments that have no
+    slot (``slot == len(rows)``).  One ``[N, D]`` gather an assignment
+    column, summed as they come: the ``[N, k, D]`` stack is never built —
+    at ``k = 10`` of which a sixteenth is held it would be 320 MB a layer
+    at 8192 tokens, nearly all of it the zero row."""
+    ext = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+    out = 0.0
+    for j in range(slot_of_assignment.shape[1]):
+        term = jnp.take(ext, slot_of_assignment[:, j], axis=0).astype(
+            jnp.float32)
+        if weights is not None:
+            term = term * weights[:, j, None]
+        out = out + term
+    return out
+
+
+@jax.custom_vjp
+def _dispatch_rows(tokens, token_of_slot, slot_of_assignment):
+    """``tokens[token_of_slot]``: one row a buffer slot.  A token routed to
+    several held experts fills several slots, so the generic transpose is a
+    scatter-add with repeated rows (row-at-a-time on a TPU, see
+    :func:`_permute_rows`).  ``slot_of_assignment`` [N, k] is the inverse
+    map — the slot of token n's j-th expert, or the buffer's length for an
+    assignment that has none — so the cotangent is a gather: each token sums
+    the rows of its own slots."""
+    return jnp.take(tokens, token_of_slot, axis=0)
+
+
+def _dispatch_rows_fwd(tokens, token_of_slot, slot_of_assignment):
+    return jnp.take(tokens, token_of_slot, axis=0), slot_of_assignment
+
+
+def _dispatch_rows_bwd(slot_of_assignment, ct):
+    return (_gather_sum(ct, slot_of_assignment).astype(ct.dtype), None, None)
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ys, weights, slot_of_assignment, token_of_slot,
+                  weight_of_slot):
+    """``y[n] = Σ_j weights[n, j] · ys[slot_of_assignment[n, j]]`` with a
+    zero row behind the buffer for assignments that have no slot.  The
+    cotangents are gathers over the buffer's slots (slot s reads its
+    token's row: times its weight for ``ys``, dotted with its own row for
+    the weight) instead of the scatter-add autodiff would emit.  The
+    weights round to the rows' dtype first (for ``k = 1`` the product is
+    then Switch's ``y · prob`` to the bit); the sum over ``k`` is
+    float32."""
+    rounded = weights.astype(ys.dtype).astype(jnp.float32)
+    return _gather_sum(ys, slot_of_assignment, rounded).astype(ys.dtype)
+
+
+def _combine_rows_fwd(ys, weights, slot_of_assignment, token_of_slot,
+                      weight_of_slot):
+    out = _combine_rows(ys, weights, slot_of_assignment, token_of_slot,
+                        weight_of_slot)
+    return out, (ys, weights, slot_of_assignment, token_of_slot,
+                 weight_of_slot)
+
+
+def _combine_rows_bwd(res, ct):
+    ys, weights, slot_of_assignment, token_of_slot, weight_of_slot = res
+    ct_of_slot = jnp.take(ct, token_of_slot, axis=0)         # [rows, D]
+    d_ys = ct_of_slot * weight_of_slot[:, None].astype(ct.dtype)
+    # <ys[s], ct[token of s]> a slot, handed to the slot's assignment; an
+    # assignment without a slot reads the zero behind the buffer.
+    per_slot = jnp.einsum("sd,sd->s", ys, ct_of_slot,
+                          preferred_element_type=jnp.float32)
+    d_weights = jnp.take(jnp.concatenate([per_slot, jnp.zeros((1,))]),
+                         slot_of_assignment, axis=0)
+    return d_ys, d_weights.astype(weights.dtype), None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def grouped_expert_mlp(
     tokens: jax.Array,
     expert_idx: jax.Array,
+    weights: jax.Array,
     w_in: jax.Array,
-    b_in: jax.Array,
     w_out: jax.Array,
-    b_out: jax.Array,
     *,
+    b_in: jax.Array | None = None,
+    b_out: jax.Array | None = None,
+    w_gate: jax.Array | None = None,
     activation=jax.nn.gelu,
+    first_held: int = 0,
+    capacity: int | None = None,
     w_in_scale: jax.Array | None = None,
     w_out_scale: jax.Array | None = None,
-) -> jax.Array:
-    """Dropless routed expert MLP over ``[N, D]`` token rows.
+    return_counts: bool = False,
+):
+    """Routed expert MLP over ``[N, D]`` token rows: the part of
+    ``Σ_j weights[n, j] · Expert_{expert_idx[n, j]}(tokens[n])`` that the
+    experts HELD here give.
 
     ``tokens``: [N, D] (already cast to the compute dtype);
-    ``expert_idx``: [N] int routed expert per token; weights carry the
-    leading [E, ...] expert axis.  Returns [N, D] in ``tokens.dtype`` —
-    the caller applies router-prob scaling.  Gradients flow to tokens
-    and all four weight leaves through ``ragged_dot``'s VJP; the integer
-    routing path is non-differentiable exactly as the one-hot path is.
+    ``expert_idx``, ``weights``: [N, k] from :func:`route_topk` — global
+    expert ids over the router's whole width.  The expert weights carry a
+    leading LOCAL expert axis ``[E_local, ...]`` and stand for the global
+    experts ``[first_held, first_held + E_local)``; an assignment to any
+    other expert sorts to a tail group that no matmul touches and adds
+    nothing (what absent experts would add is another chip's).  An expert
+    is ``act(x·w_in + b_in)·w_out + b_out``, or, with ``w_gate``,
+    ``(act(x·w_gate) ⊙ x·w_in)·w_out`` (a gated MLP; biases optional in
+    both).  Returns [N, D] in ``tokens.dtype``.  Gradients flow to
+    tokens, weights and every expert leaf; the integer routing is
+    non-differentiable.
+
+    ``capacity``: rows of the dispatch buffer.  ``None`` = ``N·k``, every
+    assignment there is — dropless as a static property, and exactly what
+    is needed when all experts are held.  A chip that holds a share of
+    the experts expects ``N·k·E_local/E`` rows and may bound the buffer
+    near that; assignments past the bound are dropped from the last
+    groups and COUNTED: ``return_counts=True`` also returns
+    ``(group_sizes [E_local] int32 as computed, n_dropped int32)``.
 
     ``w_in_scale``/``w_out_scale`` ([E, F] / [E, D] f32): weight-only
     int8 expert serving — ``w_in``/``w_out`` are then int8 and the
@@ -133,20 +260,48 @@ def grouped_expert_mlp(
     compute-dtype convert fuses into ``ragged_dot``'s operand read, so
     HBM only ever reads the int8 expert bytes.
     """
-    n_experts = w_in.shape[0]
-    order, inv_order, group_sizes = sort_by_expert(expert_idx, n_experts)
-    xs = _permute_rows(tokens, order, inv_order)
-    eids = jnp.take(expert_idx, order, axis=0)
+    n, k = expert_idx.shape
+    e_local = w_in.shape[0]
+    rows = n * k if capacity is None else min(capacity, n * k)
+    local = expert_idx.reshape(-1) - first_held
+    local = jnp.where((local >= 0) & (local < e_local), local, e_local)
+    order, dest, group_sizes = sort_by_expert(local, e_local + 1)
+    # Held groups sort first, so the buffer is the head of the order; the
+    # bound cuts whole rows off the last groups.
+    ends = jnp.minimum(jnp.cumsum(group_sizes[:e_local]), rows)
+    sizes = jnp.diff(ends, prepend=0)
+    n_dropped = jnp.sum(group_sizes[:e_local]) - ends[-1]
+    slots = order[:rows]
+    token_of_slot = slots // k
+    in_buffer = (local < e_local) & (dest < rows)
+    slot_of_assignment = jnp.where(in_buffer, dest, rows).reshape(n, k)
+    weight_of_slot = jnp.where(
+        jnp.arange(rows) < ends[-1],
+        jnp.take(weights.reshape(-1), slots, axis=0), 0)
+    # Sorted expert of each row; a row past the held groups (no matmul
+    # touches it, nothing reads it) borrows the last expert's bias and scale.
+    eids = jnp.minimum(jnp.take(local, slots, axis=0), e_local - 1)
+
     dt = tokens.dtype
-    h = lax.ragged_dot(xs, w_in.astype(dt), group_sizes)
-    if w_in_scale is not None:
-        h = h * jnp.take(w_in_scale, eids, axis=0).astype(dt)
-    h = activation(h + jnp.take(b_in.astype(dt), eids, axis=0))
-    ys = lax.ragged_dot(h, w_out.astype(dt), group_sizes)
-    if w_out_scale is not None:
-        ys = ys * jnp.take(w_out_scale, eids, axis=0).astype(dt)
-    ys = ys + jnp.take(b_out.astype(dt), eids, axis=0)
-    return _permute_rows(ys, inv_order, order)
+    xs = _dispatch_rows(tokens, token_of_slot, slot_of_assignment)
+
+    def project(x, w, scale, bias):
+        y = lax.ragged_dot(x, w.astype(dt), sizes)
+        if scale is not None:
+            y = y * jnp.take(scale, eids, axis=0).astype(dt)
+        if bias is not None:
+            y = y + jnp.take(bias.astype(dt), eids, axis=0)
+        return y
+
+    h = project(xs, w_in, w_in_scale, b_in)
+    if w_gate is None:
+        h = activation(h)
+    else:
+        h = activation(project(xs, w_gate, None, None)) * h
+    ys = project(h, w_out, w_out_scale, b_out)
+    y = _combine_rows(ys, weights, slot_of_assignment, token_of_slot,
+                      weight_of_slot)
+    return (y, (sizes, n_dropped)) if return_counts else y
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -232,8 +387,10 @@ def grouped_expert_mlp_ep(
        with zero gradients — verified semantics).
     4. **Return**: un-sort, all_to_all back, gather by the slot map.
 
-    Returns [N_local, D] in ``tokens.dtype`` (router-prob scaling is
-    the caller's, as in :func:`grouped_expert_mlp`).  The ICI cost is
+    Returns [N_local, D] in ``tokens.dtype``; one expert a token, and
+    the router-prob scaling is the caller's (:func:`grouped_expert_mlp`
+    takes ``k`` experts a token and applies the weights itself).  The
+    ICI cost is
     2 all_to_alls of ep·S rows; the matmul padding is bounded by the
     receive buffer (ep·S rows vs ~N_local useful on a balanced
     router).  Reference: the all-to-all pattern is Switch/GShard

@@ -104,40 +104,58 @@ def unwrap_dynamic_scale(state):
 
 
 def lm_loss(model, params, tokens, targets,
-            fused_ce_chunks: int | None = None):
+            fused_ce_chunks: int | None = None, stats: bool = False):
     """The LM training loss — one definition shared by the replicated
     step below and the ZeRO-3 LM step (``parallel/fsdp.py``).
 
     With ``fused_ce_chunks`` the head+loss are fused: the [B, L, vocab]
     logits are never materialized — the model returns post-ln_f hidden
-    states and ``ops/fused_ce.py`` scans the vocab in chunks.
+    states and ``ops/fused_ce.py`` scans the vocab in chunks.  The head
+    may have no bias.
+
+    ``stats=True`` (a model with a ``stats_collection``, e.g. routing
+    counts — ``models/hybrid_moe.py``) returns ``(loss, what the model
+    sowed there)``.
     """
+    kwargs = {"mutable": [model.stats_collection]} if stats else {}
+
+    def apply(**more):
+        out = model.apply({"params": params}, tokens, train=True,
+                          **kwargs, **more)
+        return (out[0], out[1][model.stats_collection]) if stats \
+            else (out, None)
+
     if fused_ce_chunks:
         from distributed_machine_learning_tpu.ops.fused_ce import (
             fused_linear_cross_entropy,
         )
 
-        hidden = model.apply(
-            {"params": params}, tokens, train=True, return_hidden=True
-        )
+        hidden, sown = apply(return_hidden=True)
         E = hidden.shape[-1]
-        return fused_linear_cross_entropy(
-            hidden.reshape(-1, E),
-            params["lm_head"]["kernel"],
-            params["lm_head"]["bias"],
-            targets.reshape(-1),
-            fused_ce_chunks,
-        )
-    logits = model.apply({"params": params}, tokens, train=True)
-    return lm_cross_entropy(logits, targets)
+        head = params["lm_head"]
+        bias = head["bias"] if "bias" in head else jnp.zeros(
+            (head["kernel"].shape[1],), jnp.float32)
+        with jax.named_scope("head"):
+            loss = fused_linear_cross_entropy(
+                hidden.reshape(-1, E), head["kernel"], bias,
+                targets.reshape(-1), fused_ce_chunks,
+            )
+    else:
+        logits, sown = apply()
+        loss = lm_cross_entropy(logits, targets)
+    return (loss, sown) if stats else loss
 
 
 def _lm_step_impl(model, state: TrainState, tokens, targets, *, axis_names,
                   fused_ce_chunks: int | None = None, guard: bool = False):
-    def loss_fn(params):
-        return lm_loss(model, params, tokens, targets, fused_ce_chunks)
+    stats = getattr(model, "stats_collection", None) is not None
 
-    loss, grads = jax.value_and_grad(loss_fn)(state.params)
+    def loss_fn(params):
+        return lm_loss(model, params, tokens, targets, fused_ce_chunks,
+                       stats=stats)
+
+    out, grads = jax.value_and_grad(loss_fn, has_aux=stats)(state.params)
+    loss, sown = out if stats else (out, None)
     if axis_names:
         grads = lax.pmean(grads, axis_names)
         loss = lax.pmean(loss, axis_names)
@@ -152,7 +170,46 @@ def _lm_step_impl(model, state: TrainState, tokens, targets, *, axis_names,
         # included); the non-finite loss still returns so the host can
         # count the skip.  Post-pmean grads ⇒ replicated decision.
         new_state = guard_update(tree_all_finite(grads), new_state, state)
-    return new_state, loss
+    if not stats:
+        return new_state, loss
+    # A chip's counts: the mean over the chips of the mesh.
+    counts = model.step_stats(sown)
+    if axis_names:
+        counts = lax.pmean(counts, axis_names)
+    return new_state, loss, counts
+
+
+class _StepWithStats:
+    """``step(state, tokens, targets) -> (state, loss)`` around a compiled
+    program that also returns a model's counts of the step (a dict of
+    scalars).  The counts stay on the device; their copy to the host is
+    started at once and nobody waits for it.  ``pop_step_stats()`` hands
+    ``train_epoch`` the counts of the step BEFORE the newest one — whose
+    loss the loop has long waited for, so reading them is no sync on
+    telemetry's account (row k carries step k−1's counts, as
+    ``param_gather_s`` carries its gather)."""
+
+    def __init__(self, jitted, counters: tuple):
+        self._jitted = jitted
+        self._newest = self._previous = None
+        #: The counts that are also registry counters (``<name>_total``).
+        self.step_stats_counters = counters
+
+    def __call__(self, state, tokens, targets):
+        state, loss, counts = self._jitted(state, tokens, targets)
+        for leaf in jax.tree_util.tree_leaves(counts):
+            leaf.copy_to_host_async()
+        self._previous, self._newest = self._newest, counts
+        return state, loss
+
+    def pop_step_stats(self) -> dict | None:
+        counts, self._previous = self._previous, None
+        if counts is None:
+            return None
+        return {name: float(value) for name, value in counts.items()}
+
+    def lower(self, *args, **kwargs):
+        return self._jitted.lower(*args, **kwargs)
 
 
 def _lm_scaled_step_impl(model, sstate: DynamicScaleState, tokens, targets,
@@ -252,9 +309,19 @@ def make_lm_train_step(
         base_impl = partial(_lm_step_impl, model,
                             fused_ce_chunks=fused_ce_chunks,
                             guard=guard_nonfinite)
+    # A model that sows counts (and a step that returns them: not the
+    # loss-scaled one) gets a third, replicated output.
+    with_stats = (not dynamic_scale
+                  and getattr(model, "stats_collection", None) is not None)
+
+    def finish(jitted):
+        if not with_stats:
+            return jitted
+        return _StepWithStats(jitted, tuple(model.stats_counters))
+
     if mesh is None:
         impl = partial(base_impl, axis_names=())
-        return jax.jit(impl, donate_argnums=(0,))
+        return finish(jax.jit(impl, donate_argnums=(0,)))
 
     missing = [a for a in (data_axis, seq_axis) if a not in mesh.axis_names]
     if missing:
@@ -289,9 +356,9 @@ def make_lm_train_step(
         impl,
         mesh=mesh,
         in_specs=(P(), batch_spec, batch_spec),
-        out_specs=(P(), P()),
+        out_specs=(P(), P(), P()) if with_stats else (P(), P()),
     )
-    return jax.jit(sharded, donate_argnums=(0,))
+    return finish(jax.jit(sharded, donate_argnums=(0,)))
 
 
 def make_lm_eval_step(model):

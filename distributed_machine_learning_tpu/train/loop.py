@@ -254,6 +254,16 @@ class _LoopTelemetry:
             gather_s = pop_gather()
             if gather_s is not None:
                 row["param_gather_s"] = gather_s
+        # A step whose model counts (routing counts of a sparse MoE,
+        # ``train/lm_step.py::_StepWithStats``) hands over the counts of
+        # the step before this one, already on the host: row fields, and
+        # running totals for those the step names.
+        pop_stats = getattr(self._train_step, "pop_step_stats", None)
+        stats = pop_stats() if pop_stats is not None else None
+        if stats:
+            row.update(stats)
+            for name in self._train_step.step_stats_counters:
+                reg.counter(name + "_total").inc(stats[name])
         if n_tokens is not None:
             tokens_per_s = n_tokens / wall if wall > 0 else 0.0
             row["tokens_per_s"] = tokens_per_s
