@@ -661,6 +661,98 @@ def audit_fsdp_perlayer_step(mesh, batch: int = 8, seq: int = 16
     return findings
 
 
+def audit_dp_lm_step(
+    topology_name: str = "v5e:2x2", *, d_model: int = 3072,
+    n_layers: int = 4, n_heads: int = 24, n_kv_heads: int = 2,
+    vocab_size: int = 49152, seq_len: int = 4096, seqs_per_chip: int = 2,
+    fused_ce_chunks: int = 8, sync_limit_bytes: int = 64 * 2**20,
+) -> tuple[list[Finding], dict]:
+    """DML102's twin for the replicated LM step: AOT-compile
+    ``cli.lm --parallel dp`` for a DESCRIBED multi-chip TPU topology (no
+    chip; needs libtpu) and report every all-reduce of the schedule —
+    bytes, synchronous or asynchronous, position
+    (``bench.overlap_audit.all_reduces_from_hlo``).  The defaults are the
+    benchmark cell ``sc2_3b_dp_w4``'s sizes (``benchmark/configs/
+    starcoder2_3b.json``, ``benchmark/traffic/dp_2x4096_w4.json``; bf16,
+    flash attention, AdamW).  A synchronous all-reduce of more than
+    ``sync_limit_bytes`` is an ERROR: it holds the core for its whole
+    duration where ``train/lm_step.py``'s compiler options were to run it
+    beside the backward pass or the optimizer.
+
+    By hand (``tools/dmlcheck.py --dp-lm-step``, about a minute), not part
+    of ``--layer2``: a process that describes a TPU topology holds
+    libtpu, which the test suite allows one file only (``tests/
+    benchmark_checks/test_benchmark_aot_fit.py``).  A schedule is not a
+    timeline: what an asynchronous reduction really hides is a chip's
+    trace to say."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_machine_learning_tpu.bench.overlap_audit import (
+        _tpu_topology_mesh,
+        all_reduces_from_hlo,
+        grad_sync_bytes,
+    )
+    from distributed_machine_learning_tpu.models.transformer import (
+        TransformerLM,
+    )
+    from distributed_machine_learning_tpu.train.adamw import AdamWConfig
+    from distributed_machine_learning_tpu.train.lm_step import (
+        init_lm_state,
+        make_lm_train_step,
+    )
+
+    devices = _tpu_topology_mesh(topology_name).devices
+    mesh = Mesh(devices.reshape(devices.size, 1), ("batch", "seq"))
+    model = TransformerLM(
+        vocab_size=vocab_size, d_model=d_model, n_layers=n_layers,
+        n_heads=n_heads, n_kv_heads=n_kv_heads, compute_dtype=jnp.bfloat16,
+        attn_impl="flash")
+    replicated = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=replicated),
+        jax.eval_shape(lambda: init_lm_state(model, config=AdamWConfig())))
+    tokens = jax.ShapeDtypeStruct(
+        (seqs_per_chip * devices.size, seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P("batch", "seq")))
+    step = make_lm_train_step(model, mesh=mesh,
+                              fused_ce_chunks=fused_ce_chunks)
+    # A described device as the default makes ``ops/pallas`` compile its
+    # kernels with Mosaic, as on the chip, instead of interpreting them.
+    with jax.default_device(devices.flat[0]):
+        compiled = step.lower(state, tokens, tokens).compile()
+    rows = all_reduces_from_hlo(compiled.as_text())
+    memory = compiled.memory_analysis()
+    label = f"dp_lm_step_{topology_name.replace(':', '_')}"
+    findings = [
+        Finding(
+            rule=RULE_CRITICAL_PATH, file=label, line=0,
+            message=(
+                f"synchronous all-reduce {row['name']} of "
+                f"{row['bytes'] / 2**20:.0f} MiB at position "
+                f"{row['position']} of {row['schedule_length']}: it holds "
+                "the core for its whole duration; the replicated LM step "
+                "is compiled so that its gradient reductions run beside "
+                "the backward pass and the optimizer (train/lm_step.py)"
+            ),
+            snippet=f"{row['name']} = all-reduce(...)",
+            severity="error", layer=2,
+        )
+        for row in rows
+        if not row["async"] and row["bytes"] > sync_limit_bytes
+    ]
+    report = {
+        "metric": label,
+        "all_reduces": rows,
+        **grad_sync_bytes(rows),
+        "argument_gib": memory.argument_size_in_bytes / 2**30,
+        "temp_gib": memory.temp_size_in_bytes / 2**30,
+    }
+    return findings, report
+
+
 def run_layer2(mesh=None) -> list[Finding]:
     """The full Layer-2 sweep ``tools/dmlcheck.py --layer2`` runs:
     ring-step donation/collective/jaxpr audits (flat, the round-11

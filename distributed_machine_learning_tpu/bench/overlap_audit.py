@@ -261,6 +261,94 @@ def wire_bytes_from_hlo(hlo_text: str, inner: int | None = None) -> dict:
     return out
 
 
+# A computation header (``%name (params) -> result {`` / ``ENTRY %name ...``)
+# and an all-reduce definition in any of its three spellings.
+_COMPUTATION_RE = re.compile(r"^(ENTRY )?%?([\w\.\-]+) \(.*\{\s*$")
+_ALL_REDUCE_DEF_RE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w\.\-]+) = (.*?)\ball-reduce(-start|-done)?\(")
+_HLO_SHAPE_RE = re.compile(r"[a-z]+\d*\[[\d,]*\]")
+_CALLS_RE = re.compile(r"calls=%?([\w\.\-]+)")
+_INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT )?%?([\w\.\-]+) = ")
+# XLA:TPU's async-collective fusion spells one all-reduce three times:
+# in the fused computation of an ``AsyncCollectiveStart`` custom call,
+# in the ``async_collective_fusion.N`` body that steps it beside a
+# matmul or an elementwise loop, and in the ``AsyncCollectiveDone`` one.
+_ASYNC_FUSION_START = 'custom_call_target="AsyncCollectiveStart"'
+
+
+def all_reduces_from_hlo(hlo_text: str) -> list[dict]:
+    """Every all-reduce a compiled, scheduled module issues, once each:
+    ``[{"name", "bytes", "async", "position"}]`` in schedule order.
+
+    ``bytes`` is the result's size — every element of a tuple-shaped
+    (combined) all-reduce counted.  ``async`` is True for an
+    ``all-reduce-start``/``-done`` pair and for an async-collective
+    fusion (counted at its start; the copies of the instruction inside
+    the fusion's later steps are the same collective and are skipped);
+    a plain ``all-reduce`` holds the core for its whole duration and is
+    False.  ``position`` is the index in the ENTRY schedule of the
+    instruction (or of the fusion that starts it) among its
+    ``schedule_length`` instructions, None for one inside a loop body.
+    """
+    computations: dict[str, list[str]] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION_RE.match(line)
+        if header:
+            current = header.group(2)
+            computations[current] = []
+            if header.group(1):
+                entry = current
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            computations[current].append(line)
+    if entry is None:
+        raise ValueError("no ENTRY computation found in HLO text")
+    fused = {callee for lines in computations.values() for line in lines
+             if " fusion(" in line for callee in _CALLS_RE.findall(line)}
+    # Where the ENTRY schedule holds an instruction, and where it calls a
+    # computation.
+    instruction_at: dict[str, int] = {}
+    called_at: dict[str, int] = {}
+    for i, line in enumerate(computations[entry]):
+        for callee in _CALLS_RE.findall(line):
+            called_at[callee] = i
+        name = _INSTR_NAME_RE.match(line)
+        if name:
+            instruction_at[name.group(1)] = i
+    out = []
+    for comp, lines in computations.items():
+        starts = any(_ASYNC_FUSION_START in line for line in lines)
+        for line in lines:
+            m = _ALL_REDUCE_DEF_RE.match(line)
+            if not m or m.group(3) == "-done":
+                continue
+            if comp in fused and not starts:
+                continue  # a later step of an async-collective fusion
+            out.append({
+                "name": m.group(1),
+                "bytes": sum(_shape_bytes(s)
+                             for s in _HLO_SHAPE_RE.findall(m.group(2))),
+                "async": starts or m.group(3) == "-start",
+                "position": (instruction_at.get(m.group(1))
+                             if comp == entry else called_at.get(comp)),
+                "schedule_length": len(computations[entry]),
+            })
+    out.sort(key=lambda r: (r["position"] is None, r["position"] or 0))
+    return out
+
+
+def grad_sync_bytes(rows: list[dict]) -> dict:
+    """``{"grad_sync_bytes", "grad_sync_async_bytes"}`` of a compiled
+    train step from its :func:`all_reduces_from_hlo` rows: bytes a chip
+    all-reduces a step in all, and through asynchronous collectives."""
+    return {
+        "grad_sync_bytes": sum(r["bytes"] for r in rows),
+        "grad_sync_async_bytes": sum(r["bytes"] for r in rows if r["async"]),
+    }
+
+
 def compile_ring_hlo(mesh, length: int, *, compress: str = "none",
                      topk_frac: float = 0.125,
                      bucket_bytes: int | None = None,

@@ -9,7 +9,7 @@ Usage::
                              [--layer2] [--layer3 [--quick]]
                              [--mutate NAME,NAME] [--repro-dir DIR]
                              [--replay FILE] [--list-rules]
-                             [--write-baseline]
+                             [--write-baseline] [--dp-lm-step]
 
 Layer 1 (default, stdlib-only, no jax import, <10 s): the AST rules in
 ``distributed_machine_learning_tpu/analysis/ast_rules.py`` over the
@@ -23,6 +23,9 @@ exhaustive small configs (CI-sized, <30 s); a violated invariant
 (DML301, DML302 for deadlocks) carries a minimized schedule trace and
 a reproducer file ``--replay`` re-runs bit-for-bit.  ``--mutate``
 re-introduces a known-bug seed (the mutation-test gate).
+``--dp-lm-step`` is a mode of its own, by hand (needs libtpu): the
+four-chip dp LM step AOT-compiled for a described ``v5e:2x2``, every
+all-reduce of its schedule listed, exit 1 where a large one is synchronous.
 
 Exit codes: 0 clean (every finding baselined, no stale baseline
 entries), 1 non-baselined ERROR findings or stale entries, 2 usage /
@@ -84,6 +87,33 @@ def _run_layer2():
     )
 
     return run_layer2()
+
+
+def _run_dp_lm_step(as_json: bool) -> int:
+    """The four-chip dp LM step compiled for a described ``v5e:2x2``:
+    every all-reduce of its schedule, and exit 1 where a large one is
+    synchronous (``analysis.program_audit.audit_dp_lm_step``)."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from distributed_machine_learning_tpu.analysis.program_audit import (
+        audit_dp_lm_step,
+    )
+
+    findings, report = audit_dp_lm_step()
+    if as_json:
+        report["findings"] = [f.as_dict() for f in findings]
+        print(json.dumps(report, indent=1))
+    else:
+        for row in report["all_reduces"]:
+            print(f"  {row['position']:>5} / {row['schedule_length']}  "
+                  f"{'async' if row['async'] else 'SYNC '}  "
+                  f"{row['bytes'] / 2**20:9.2f} MiB  {row['name']}")
+        print(f"{report['metric']}: {report['grad_sync_async_bytes']} of "
+              f"{report['grad_sync_bytes']} bytes asynchronous; arguments "
+              f"{report['argument_gib']:.2f} GiB, temporaries "
+              f"{report['temp_gib']:.2f} GiB")
+        for f in findings:
+            print(f"  {f.rule}: {f.message}")
+    return 1 if findings else 0
 
 
 def _run_replay(path: str, as_json: bool) -> int:
@@ -157,6 +187,14 @@ def main(argv=None) -> int:
                              "reproducer recorded, print the "
                              "annotated trace, exit 1 if it still "
                              "fails (deterministic)")
+    parser.add_argument("--dp-lm-step", action="store_true",
+                        help="by hand (needs libtpu, ~1 min): AOT-compile "
+                             "the four-chip dp LM step for a described "
+                             "v5e:2x2 at the benchmark cell's sizes and "
+                             "list every all-reduce of its schedule — "
+                             "bytes, sync or async, position; exit 1 "
+                             "where one over 64 MiB is synchronous "
+                             "(DML102's twin for train/lm_step.py)")
     parser.add_argument("--list-rules", action="store_true")
     parser.add_argument("--write-baseline", action="store_true",
                         help="print a baseline skeleton for the "
@@ -171,6 +209,8 @@ def main(argv=None) -> int:
 
     if args.replay:
         return _run_replay(args.replay, as_json=args.json)
+    if args.dp_lm_step:
+        return _run_dp_lm_step(as_json=args.json)
 
     LAYER2_RULES = {"DML101", "DML102", "DML103", "DML104"}
     LAYER3_RULES = {"DML301", "DML302"}
