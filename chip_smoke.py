@@ -44,7 +44,7 @@ PART3_FUSED_ARGV = PART3_ARGV + [
     "--optimizer", "adamw", "--fused-update",
     "--max-iters", "6", "--eval-batches", "2", "--dist-eval",
 ]
-# The width docs/PERF.md calls realistic; --batch-size (the GLOBAL batch,
+# A d2048 GQA LM that fills the MXU's tiles; --batch-size (the GLOBAL batch,
 # 4 per chip) is appended once the chip count is known.
 LM_ARGV = [
     "--parallel", "dp", "--d-model", "2048", "--n-heads", "16",
@@ -176,12 +176,30 @@ def _cnn_batch(per_rank: int):
 # -- phases -------------------------------------------------------------
 
 
+def require_tpu() -> dict:
+    """The device this smoke run is about, as JAX reports it — or exit.
+
+    JAX falls back to the CPU with only a warning when a TPU fails to
+    initialise; a run that finds no TPU fails instead of continuing on
+    another backend."""
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"no TPU found: JAX's default backend is {dev.platform!r} "
+            f"({dev.device_kind}); chip_smoke.py only runs on the chip"
+        )
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(devices)}
+
+
 def phase_device() -> dict:
     import importlib.metadata as md
 
     import jax
 
-    from distributed_machine_learning_tpu.bench.harness import require_tpu
     from distributed_machine_learning_tpu.runtime.compile_cache import (
         configure_compile_cache,
     )

@@ -393,6 +393,30 @@ class ExploreStats:
         self.seconds = 0.0
 
 
+@contextlib.contextmanager
+def _on_one_cpu():
+    """Hold the calling thread — and the scenario threads it spawns,
+    which inherit its mask — to ONE of its CPUs (as a decorator: for
+    the call).
+
+    The cooperative scheduler runs exactly one thread at a time, so
+    more cores buy nothing, while every hand-off between threads the
+    OS has spread over cores pays a cross-core wake-up (an IPI and an
+    idle core's exit latency; 40% of an unpinned sweep is system
+    time).  Which CPU is irrelevant to the schedule set; taking it
+    from the pid spreads concurrent sweeps over the allowed ones."""
+    if not hasattr(os, "sched_setaffinity"):   # not Linux: leave it
+        yield
+        return
+    allowed = os.sched_getaffinity(0)
+    os.sched_setaffinity(0, {sorted(allowed)[os.getpid() % len(allowed)]})
+    try:
+        yield
+    finally:
+        os.sched_setaffinity(0, allowed)
+
+
+@_on_one_cpu()
 def explore(build, max_schedules: int = 2000,
             stop_on_violation: bool = True,
             preemption_bound: int | None = None,

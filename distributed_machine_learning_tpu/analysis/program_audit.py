@@ -4,7 +4,7 @@ Layer 1 lints what the source says; these passes check what the
 executable actually does — the invariants live in the compiled
 artifact, and source-level truth can be compiled away (an unaliasable
 donation silently becomes a copy; a "sharded" update can still gather
-on the critical path).  Builds on ``bench/overlap_audit.py``'s HLO-text
+on the critical path).  Builds on ``ops/hlo.py``'s HLO-text
 walkers (the ppermute overlap audit and the wire-byte parser grew
 there; this module generalizes them into reusable passes):
 
@@ -40,9 +40,11 @@ import re
 from typing import Iterable, Sequence
 
 from distributed_machine_learning_tpu.analysis.findings import Finding
-from distributed_machine_learning_tpu.bench.overlap_audit import (
-    audit_schedule,
+from distributed_machine_learning_tpu.analysis.overlap_audit import (
     compile_ring_hlo,
+)
+from distributed_machine_learning_tpu.ops.hlo import (
+    audit_schedule,
     sync_collectives_from_hlo,
     wire_bytes_from_hlo,
 )
@@ -541,7 +543,7 @@ def audit_zero1_step(mesh, global_batch: int = 16,
     stays gather-free.
 
     The legacy sync build (``overlap=False``) still exists for parity
-    testing and the bench baseline; it is not audited here because its
+    testing; it is not audited here because its
     critical-path gather is now a *documented baseline*, not the
     shipped default."""
     import jax
@@ -671,7 +673,7 @@ def audit_dp_lm_step(
     ``cli.lm --parallel dp`` for a DESCRIBED multi-chip TPU topology (no
     chip; needs libtpu) and report every all-reduce of the schedule —
     bytes, synchronous or asynchronous, position
-    (``bench.overlap_audit.all_reduces_from_hlo``).  The defaults are the
+    (``ops.hlo.all_reduces_from_hlo``).  The defaults are the
     benchmark cell ``sc2_3b_dp_w4``'s sizes (``benchmark/configs/
     starcoder2_3b.json``, ``benchmark/traffic/dp_2x4096_w4.json``; bf16,
     flash attention, AdamW).  A synchronous all-reduce of more than
@@ -689,8 +691,10 @@ def audit_dp_lm_step(
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from distributed_machine_learning_tpu.bench.overlap_audit import (
+    from distributed_machine_learning_tpu.analysis.overlap_audit import (
         _tpu_topology_mesh,
+    )
+    from distributed_machine_learning_tpu.ops.hlo import (
         all_reduces_from_hlo,
         grad_sync_bytes,
     )

@@ -1,8 +1,8 @@
 """Draft-from-target distillation — one command from a trained target
 checkpoint to a servable speculative-decoding draft.
 
-The measured speculative speedups (docs/PERF.md: 2.1× end-to-end)
-require a draft that actually agrees with the target; round 4 got one
+Speculative decoding only pays with
+a draft that actually agrees with the target; round 4 got one
 by hand-writing a second training run.  This entrypoint makes that a
 single command (VERDICT r4 item 6)::
 

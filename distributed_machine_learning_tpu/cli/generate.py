@@ -72,7 +72,7 @@ def make_parser() -> argparse.ArgumentParser:
                    help="weight-only quantized serving: projections read "
                         "int8 weights through the Pallas kernel "
                         "(ops/quant.py) — decode is weight-bandwidth-"
-                        "bound, measured 1.3-1.8x tokens/s (docs/PERF.md)")
+                        "bound")
     p.add_argument("--tp", default=1, type=int,
                    help="tensor-parallel decode over this many devices "
                         "(manual Megatron shard_map — heads, d_ff, and "

@@ -88,7 +88,7 @@ def make_parser():
                         "= Switch capacity + drops, GSPMD-sharded over the "
                         "expert axis; 'grouped' = dropless ragged-matmul "
                         "path (ops/grouped.py) — single-device fast path "
-                        "(measured 1.33x on the MoE portion on-chip) AND, "
+                        "(no cell measures it: ROADMAP X3) AND, "
                         "multi-device, the manual shard_map EP step with "
                         "an explicit token all_to_all to expert owners "
                         "(batch shards over data x expert; no attention "
@@ -241,8 +241,8 @@ def make_parser():
     p.add_argument("--attn", default="auto",
                    choices=["auto", "dense", "flash"],
                    help="attention kernel: for dp/fsdp, 'auto' picks the "
-                        "Pallas flash kernel from 512 context up (the "
-                        "measured crossover, docs/PERF.md) and 'dense' "
+                        "Pallas flash kernel from 512 context up "
+                        "(flash_wins; ROADMAP D5) and 'dense' "
                         "the XLA fused path; for --parallel ring, "
                         "'auto'/'flash' upgrade the per-chunk math to "
                         "the flash-kernel ring when the per-device chunk "

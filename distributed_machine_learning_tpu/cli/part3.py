@@ -10,8 +10,8 @@ Gradient wire compression (``--ring-compress {none,bf16,int8,topk}``,
 ``--ring-topk-frac``): compress each ring hop's payload — int8 with
 per-chunk fp32 scales or magnitude top-k sparsification, both carrying
 an error-feedback residual across steps (EF-SGD), or a cast-only bf16
-wire.  ~4x fewer bytes on the wire for int8/topk at loss-curve parity
-(docs/PERF.md "Compressed ring all-reduce"); ``--wire-dtype bfloat16``
+wire.  ~4x fewer bytes on the wire for int8/topk (read off the
+compiled program: ``tests/test_overlap_audit.py``); ``--wire-dtype bfloat16``
 is the deprecated spelling of ``--ring-compress bf16``.
 """
 

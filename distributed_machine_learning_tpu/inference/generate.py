@@ -6,8 +6,7 @@ serving half this framework adds: prefill the prompt once, then decode
 one token per step against per-layer K/V caches
 (``models/transformer.py`` ``decode=True``), the whole loop a single
 jitted program (`lax.scan`) — no per-token Python dispatch, which
-would cost more than a µs-scale decode step (same argument as
-bench.py's scanned epoch).
+would cost more than a µs-scale decode step.
 
 TPU notes: the decode step is memory-bound (matvec against the cache),
 so the cache stays in the model's compute dtype (bf16 halves HBM
@@ -98,7 +97,7 @@ def make_generate_fn(
     ``quantize="int8"`` serves weight-only int8: pass params already
     converted by ``ops.quant.quantize_lm_params`` (the ``generate``
     wrapper converts for you) — decode is weight-bandwidth-bound, so
-    halving the weight bytes is ~the step-time divisor (docs/PERF.md).
+    halving the weight bytes is ~the step-time divisor.
 
     ``eos_id`` (ISSUE 19): with an EOS token set, decode runs as a
     ``lax.while_loop`` that exits as soon as EVERY row has emitted

@@ -1,7 +1,7 @@
 """Speculative decoding — draft-and-verify autoregressive generation.
 
-Decode is bound by HBM reads of the target model's weights per token
-(docs/PERF.md); speculative decoding (Leviathan et al.) buys tokens per
+Decode is bound by HBM reads of the target model's weights per token;
+speculative decoding (Leviathan et al.) buys tokens per
 weight-read: a cheap DRAFT model proposes ``gamma`` tokens
 autoregressively, the TARGET verifies all of them in ONE forward pass
 (γ+1 positions against its cache — compute-parallel, the same weight

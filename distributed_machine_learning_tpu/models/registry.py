@@ -1,4 +1,4 @@
-"""Model registry: name → constructor, shared by the CLI and bench.
+"""Model registry: name → constructor, shared by the CLIs.
 
 The reference exposes exactly one model factory (`VGG11()` at
 `part1/model.py:49-50`); its cfg table lists VGG11/13/16/19

@@ -151,32 +151,32 @@ def _cached_attention_quant(q, k_int, ks, v_int, vs, q_positions):
     return out.reshape(B, Lq, H, D).astype(q.dtype)
 
 
-# Two-tier int8-KV-cache dispatch (VERDICT r4 item 7; measured by
-# bench/int8_tier.py): when True, single-token int8 decode picks at
-# RUNTIME between the frontier-clamped Pallas kernel (early in the
-# stream — it reads O(pos) while the einsum reads all S allocated
-# slots) and the scale-folding einsum (late).  Measured r5 on-chip at
-# S_alloc=32k (Hkv=8, D=64; 2000-iteration scanned slope):
-#   - einsum: FLAT ~60 µs at every fill; kernel: 20 µs at pos/S=0.05
-#     growing to 305 µs at 0.95 — crossover at pos/S ≈ 0.19 (r4's 0.36
-#     estimate assumed the kernel 2.8× costlier per byte; it measures
-#     ~5×, its exact-f32 dequant off the DMA roofline);
-#   - compile cost of the tiered program (8L, 32k-token generate):
-#     +4.6-5 s (11.3 s vs 6.7 s warm cache; 20.4 vs 15.7 cold).
+# Two-tier int8-KV-cache dispatch (VERDICT r4 item 7): when True,
+# single-token int8 decode picks at RUNTIME between the
+# frontier-clamped Pallas kernel (early in the stream — it reads
+# O(pos) while the einsum reads all S allocated slots) and the
+# scale-folding einsum (late).  What decides between them:
+#   - the einsum's cost is FLAT in the fill (it reads every allocated
+#     slot); the kernel's grows with pos, and its exact-f32 dequant
+#     keeps it off the DMA roofline, so the kernel only wins while
+#     pos/S_alloc is under a break-even;
+#   - the tiered program is a lax.cond over both, so it compiles
+#     both: seconds more per serving shape.
 # Verdict: default OFF — over any run-to-completion generation the
-# mean fill is >= 0.5, so the sub-0.19 phase is ~1-2% end-to-end, not
-# worth 5 s compile per serving shape.  Flip it ON for the workload the
-# numbers DO favor: serving that allocates a generous max_new_tokens
-# and usually stops early (fill stays below the crossover all request:
-# up to ~39 µs/layer/step back, ~0.3 ms/step on an 8L model — the
-# attention share drops ~3x).
+# mean fill is >= 0.5, so the phase under the break-even is a small
+# share of the whole, not worth the compile per serving shape.  The
+# workload that inverts this: serving that allocates a generous
+# max_new_tokens and usually stops early (fill stays under the
+# break-even all request).  The break-even below is from a round-5
+# timing at S_alloc=32k (Hkv=8, D=64) of older code, its script gone;
+# only tests/test_quant.py flips the switch.
 _INT8_TIERED_DISPATCH = False
-_INT8_TIER_BREAK_EVEN_PCT = 19  # measured crossover (bench/int8_tier.py)
+_INT8_TIER_BREAK_EVEN_PCT = 19  # not calibrated on v5e (ROADMAP D5)
 
 
 def _flash_wins(L: int) -> bool:
     """attn_impl="auto" policy — delegates to the kernel module's shared
-    ``flash_wins`` length rule (docs/PERF.md r02 crossover table)."""
+    ``flash_wins`` length rule (not calibrated on v5e: ROADMAP D5)."""
     from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
         flash_wins,
     )
@@ -188,7 +188,7 @@ def _ring_flash_wins(chunk_len: int) -> bool:
     """ring → ring_flash upgrade policy (one source of truth for the CLI
     and programmatic callers): the per-chunk math is exactly the
     unsharded-flash regime applied to the LOCAL chunk, so the same
-    measured length policy decides — delegate to ``flash_wins``, minus
+    length policy decides — delegate to ``flash_wins``, minus
     the lengths the single-chunk path handles by padding: the ring
     kernels operate on fixed chunk grids with no pad/slice wrapper, so
     a chunk Mosaic cannot tile natively stays on the einsum ring."""
@@ -209,7 +209,7 @@ class Attention(nn.Module):
     ``ops/pallas/ring_flash_attention.py``), "ulysses" (sequence sharded
     via all-to-all head re-sharding — ``ops/ulysses.py``), "flash" (the
     Pallas kernel — ``ops/pallas/flash_attention.py``), or "auto" (flash
-    from the measured 512-context crossover up when the length tiles
+    from ``flash_wins``'s 512-context crossover up when the length tiles
     natively, always from 2048 up via the kernel's pad-and-slice path,
     dense below — see ``flash_wins``; for the sharded ring the analogous
     policy is ``_ring_flash_wins``).
@@ -441,41 +441,41 @@ class Attention(nn.Module):
                 else:
                     # Narrow cache straight into GQA-native cached
                     # attention — no repeat, no widened materialization.
-                    # Dispatch (all measured on-chip, docs/PERF.md):
+                    # Dispatch (not calibrated on v5e: ROADMAP D5):
                     # - int8 caches: ALWAYS the scale-folding einsum
                     #   (_cached_attention_quant) — XLA fuses the s8
                     #   convert into the dot, so HBM reads int8 bytes,
-                    #   and it beats the kernel at any filled cache
-                    #   (the kernel's exact-f32 dequant takes it off
-                    #   its DMA-bound point).  Numbers: r5's scanned-
-                    #   slope bench (bench/int8_tier.py — the r4
-                    #   figures of 29/103/217 µs vs 83/282/612 came
-                    #   from chained dispatches, which that bench
-                    #   showed carry host dispatch jitter into µs ops;
-                    #   direction right, absolutes superseded)
-                    #   measures the einsum flat ~60 µs at 32k alloc
-                    #   vs the kernel's O(pos) 20→305 µs ladder —
-                    #   einsum from pos/S ≈ 0.19 of the ALLOCATION up.
-                    #   Caveat, priced in AND measured (r5,
-                    #   bench/int8_tier.py): the einsum reads all S
-                    #   ALLOCATED slots while the kernel's frontier
-                    #   clamp reads O(pos) — measured crossover at
-                    #   pos/S ≈ 0.19 (einsum flat ~60 µs at 32k alloc;
-                    #   kernel 20→305 µs across the fill ladder), and
-                    #   the mean of pos/S over ANY full generation is
-                    #   (Lp/S + 1)/2 ≥ 0.5, so the einsum wins
-                    #   integrated over every run-to-completion shape.
-                    #   The tiered lax.cond alternative costs a
-                    #   measured +4.6-5 s compile per serving shape
-                    #   for a ~1-2% end-to-end win — kept available as
+                    #   where the kernel's exact-f32 dequant takes it
+                    #   off its DMA-bound point.  The two differ in
+                    #   what they read: the einsum reads all S
+                    #   ALLOCATED slots, so its cost is flat in the
+                    #   fill; the kernel's frontier clamp reads
+                    #   O(pos), so its cost grows along the stream and
+                    #   it is the cheaper of the two only while pos/S
+                    #   is under a break-even fraction of the
+                    #   ALLOCATION (_INT8_TIER_BREAK_EVEN_PCT, above).
+                    #   Why the einsum is taken at every fill all the
+                    #   same: a generation that runs to completion
+                    #   starts at the prompt's length Lp and ends at
+                    #   the allocation S, so the mean of pos/S over
+                    #   ANY full generation is (Lp/S + 1)/2 ≥ 0.5:
+                    #   the einsum wins integrated over every
+                    #   run-to-completion shape.  The tiered lax.cond
+                    #   alternative compiles both paths for every
+                    #   serving shape, for a win in the first part of
+                    #   the stream only; it is kept available as
                     #   _INT8_TIERED_DISPATCH (above) for the one
                     #   workload that inverts the math: generous
-                    #   max_new allocations that usually stop early;
+                    #   max_new allocations that usually stop early
+                    #   (only tests/test_quant.py turns it on);
                     # - long bf16/f32 caches (≥4k): the flash-decode
                     #   kernel (frontier-clamped O(pos) reads);
                     # - short bf16/f32 caches: the head-major einsum
                     #   (the kernel's per-grid-step overhead loses to
-                    #   XLA's single fused op — 84 vs 48 µs at S=2k).
+                    #   XLA's single fused op).
+                    #   Where "long" starts is decode_flash_qualifies'
+                    #   rule (ops/pallas/decode_attention.py); no cell
+                    #   decodes yet (ROADMAP X2).
                     from distributed_machine_learning_tpu.ops.pallas.decode_attention import (  # noqa: E501
                         cached_flash_attention,
                         decode_flash_qualifies,
@@ -669,8 +669,8 @@ class Block(nn.Module):
     the flash kernel's saved ``(out, lse)`` (O(L·D), cheap) — stay
     resident, so the backward pass never re-runs the O(L²) attention
     forward.  Whole-block remat re-runs everything (flash forward
-    included) in backward — the ~4/3 HFU overhead docs/PERF.md's 16k/32k
-    rows paid in round 3; this policy converts most of that recompute
+    included) in backward, about a third more work than the model's
+    FLOPs at long context; this policy converts most of that recompute
     back into real tokens/sec at the cost of ~6·L·E saved activation
     bytes per layer instead of ~1·L·E."""
 

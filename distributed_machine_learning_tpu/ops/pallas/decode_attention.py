@@ -41,9 +41,9 @@ valid).  No backward pass: decode is inference.
 NOTE (round 4): the kernel's int8-dequant mode is SUPERSEDED in
 production by the scale-folding einsum
 (models/transformer.py::_cached_attention_quant) — XLA fuses the
-s8 convert into the attention dots and measures ~2.7-2.9x faster
-at every context (docs/PERF.md), so the model dispatch never
-routes int8 caches here anymore.  The mode stays implemented and
+s8 convert into the attention dots, so the model dispatch never
+routes int8 caches here anymore (a round-4 choice, not calibrated
+on v5e: ROADMAP D5).  The mode stays implemented and
 tested as the Pallas reference for in-register dequant; the
 kernel's production role is long bf16/f32 caches (>= 4k), where
 its frontier-clamped O(pos) DMA wins.

@@ -115,18 +115,18 @@ def _padded_len(L: int) -> int:
 
 
 def flash_wins(L: int) -> bool:
-    """Length policy shared by every "auto" dispatch: after the 512×512
-    block retune the flash kernels beat XLA dense attention from 512
-    context up on the measured chip (512k vs 421k tok/s @512; 1.6× @1k;
-    ~3× @4-8k — docs/PERF.md) and are the only option past ~8-16k where
-    dense's L² program stops compiling.  Dense still wins at 256 (584k
-    vs 479k) and at sub-2k lengths with degraded blocks: sub-1k lengths
-    not divisible by 512 forfeit the thin @512 margin, and 1-2k lengths
-    whose largest power-of-two divisor is under 128 would pay the pad-
-    to-512-multiple overhead (up to (L+511)²/L² ≈ 1.5× at 1k) against
-    only a ~1.6× dense deficit.  From 2048 up flash wins for EVERY
-    length — padded if needed — because dense is ≥2× behind (and soon
-    uncompilable) while the pad overhead shrinks quadratically."""
+    """Length policy shared by every "auto" dispatch: with 512×512
+    blocks the flash kernels are taken from 512 context up, and are
+    the only option past ~8-16k where dense's L² program stops
+    compiling.  XLA dense attention is kept at 256 and at sub-2k
+    lengths with degraded blocks: sub-1k lengths not divisible by 512
+    forfeit the thin margin at 512, and 1-2k lengths whose largest
+    power-of-two divisor is under 128 would pay the pad-to-512-multiple
+    overhead (up to (L+511)²/L² ≈ 1.5× at 1k).  From 2048 up flash is
+    taken for EVERY length — padded if needed — because the pad
+    overhead shrinks quadratically while dense's L² scores grow.  The
+    crossovers come from rounds 1-5's timings of older code: not
+    calibrated on v5e (ROADMAP D5)."""
     if L >= 2048:
         return True
     if L >= 1024:

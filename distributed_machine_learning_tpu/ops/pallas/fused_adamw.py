@@ -1,9 +1,9 @@
 """Fused AdamW update as a Pallas TPU kernel — the update-phase lever.
 
 The round-9 per-phase spans put the optimizer update on the critical
-path once the weight-update all-gather was overlapped (docs/PERF.md:
-the update phase is what remains between the backward and the next
-step's dispatch).  The XLA spelling of AdamW
+path once the weight-update all-gather was overlapped (the update
+phase is what remains between the backward and the next step's
+dispatch).  The XLA spelling of AdamW
 (``train/adamw.py::adamw_update``) is a chain of elementwise ops over
 four full-size vectors (p, mu, nu, g) whose intermediates (the decayed
 moments, the bias-corrected terms, the adam step) XLA may or may not

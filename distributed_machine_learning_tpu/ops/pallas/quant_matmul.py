@@ -1,12 +1,11 @@
 """Weight-only int8 matmul as a Pallas TPU kernel — the decode bandwidth lever.
 
-Autoregressive decode is bound by HBM reads of the weights (docs/PERF.md:
-the bf16 serving config sits at the weights+cache bandwidth floor), so
+Autoregressive decode is bound by HBM reads of the weights, so
 halving the weight bytes is a direct tokens/s multiplier.  The catch is
 that XLA does NOT fuse an ``int8 → bf16`` convert into a dot operand at
-these sizes: measured on this chip, ``x @ (q.astype(bf16) * scale)``
-inside a decode scan runs 0.65× bf16 — the dequantized matrix
-materializes in HBM, *tripling* traffic instead of halving it.  Hence
+these sizes: ``x @ (q.astype(bf16) * scale)`` inside a decode scan
+materializes the dequantized matrix in HBM, *tripling* traffic instead
+of halving it (no cell measures decode: ROADMAP X2).  Hence
 this kernel: the int8 tile is DMA'd into VMEM (half the bytes of bf16),
 converted to bf16 in-register, fed to the MXU with f32 accumulation,
 and scaled per output channel on the way out.  HBM never sees a

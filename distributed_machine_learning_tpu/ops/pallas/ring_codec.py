@@ -4,10 +4,9 @@ The compressed ring (``ops/ring.py::Int8Scheme``) spells each hop's
 dequantize–add–requantize as separate XLA ops: the encode materializes a
 dequantized copy of the partial to compute the error-feedback residual
 (``v − decode(encode(v))``), and the receive side materializes the
-dequantized payload before adding it into the accumulator chunk.  On
-the round-7/round-11 measurements those intermediates are the codec's
-whole cost (≤6% p50 for int8 on the flat ring — docs/PERF.md rounds 7
-and 11).  This module is the fused spelling: three kernels covering
+dequantized payload before adding it into the accumulator chunk.
+Those intermediates are the codec's whole cost (no cell runs the ring
+yet: ROADMAP W3).  This module is the fused spelling: three kernels covering
 every local piece of the hop, each one pass over the chunk with the
 intermediates held in-register, so **HBM never sees a dequantized
 partial**:

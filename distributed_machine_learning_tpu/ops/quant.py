@@ -18,7 +18,7 @@ Why serving-only: quantized weights are constants of the decode
 program; training keeps full-precision master weights (the usual
 weight-only recipe).  The reference has no inference path at all
 (part1/main.py:62-77 is classification eval) — this is beyond-parity
-capability, measured in docs/PERF.md.
+capability.
 """
 
 from __future__ import annotations

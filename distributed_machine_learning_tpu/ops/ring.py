@@ -26,13 +26,13 @@ collective scheduler overlaps bucket k's ppermutes with bucket k+1's
 adds — the same comm/compute overlap DDP's autograd hooks implement in
 C++ (``part3/main.py:59``, group25.pdf p.6), obtained from the compiler
 instead of hand-written callbacks.  **Verified, not assumed** (round 4,
-``bench/overlap_audit.py``): AOT-compiling the full part3 step for a
+``analysis/overlap_audit.py``): AOT-compiling the full part3 step for a
 real v5e 2×4 target shows 28 async ``collective-permute-start/done``
 pairs (= 2 buckets × 2·(N−1) steps), 21 of which have the *other*
 bucket's ``slice_add``/``slice_reduce`` fusions scheduled inside their
 in-flight window, with up to 2 ppermutes concurrently in flight and the
-two buckets' rings interleaved step-for-step — docs/PERF.md "Ring
-overlap audit" for the numbers and protocol.
+two buckets' rings interleaved step-for-step — a schedule, not a
+timeline: what the ring hides on the chip no cell measures yet.
 
 The ring steps use *static* chunk indices (the loop over steps is unrolled;
 N is a compile-time mesh constant), so every slice is a static-shape
@@ -97,7 +97,7 @@ class WireScheme:
     ``decode(payload, length) -> jax.Array`` reconstructs a dense fp32
     chunk of ``length`` elements; ``payload_bytes(length)`` is the
     static byte accounting the telemetry counters and the HLO wire-byte
-    audit (``bench/overlap_audit.py --wire-bytes``) check against.
+    audit (``analysis/overlap_audit.py --wire-bytes``) check against.
 
     The base class is the exact (identity) scheme.
     """
@@ -652,7 +652,7 @@ def ring_wire_bytes(
 
     Pure host arithmetic — the number the ``ring_wire_bytes`` telemetry
     counter accumulates per step, and the number the HLO audit
-    (``bench/overlap_audit.py --wire-bytes``) verifies against the
+    (``analysis/overlap_audit.py --wire-bytes``) verifies against the
     compiled program's actual collective-permute operand shapes.
 
     ``topology``: total over both axes of the hierarchical plan (see

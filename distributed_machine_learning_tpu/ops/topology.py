@@ -106,8 +106,8 @@ class LinkModel:
     split the :class:`Topology` descriptor declares.  Calibration:
     ``tests/test_netmodel.py`` pins the model's per-axis bytes to the
     static ``topology_wire_bytes`` accounting (itself pinned to the
-    compiled HLO by DML103) and its plan ordering to the measured
-    ``BENCH_r11_hier.json`` cells.
+    compiled HLO by DML103) and its plan ordering to the CPU-mesh rows
+    of ``BENCH_r11_hier.json``: not calibrated on v5e (ROADMAP D5).
     """
 
     inner_overhead_s: float = 1.0e-6
@@ -628,7 +628,7 @@ def classify_permute_pairs(pairs, inner: int) -> str:
     bottleneck-rank accounting: the block-edge ranks of a flat ring
     push every hop's payload inter-node, so a mixed permute's bytes ARE
     outer-axis exposure.  The HLO walker
-    (``bench.overlap_audit.wire_bytes_from_hlo``) classifies compiled
+    (``ops.hlo.wire_bytes_from_hlo``) classifies compiled
     ``source_target_pairs`` through this same function, so compiled and
     static attribution can never drift."""
     if any(s // inner != t // inner for s, t in pairs):
@@ -648,7 +648,7 @@ def topology_wire_bytes(
     Every hop is attributed through the SAME permutation-pair
     classifier the HLO audit applies to the compiled program's
     ``source_target_pairs`` (:func:`classify_permute_pairs`, which
-    ``bench.overlap_audit.wire_bytes_from_hlo`` imports) — the static
+    ``ops.hlo.wire_bytes_from_hlo`` imports) — the static
     accounting and the executable attribution cannot chunk or classify
     differently.  Note
     the flat plan's bytes land on the OUTER axis whenever the ring
@@ -696,25 +696,3 @@ def topology_wire_bytes(
                 2 * (topo.outer - 1) * so.payload_bytes(chunk_o, itemsize)
             )
     return out
-
-
-def predict_all_reduce_time(
-    n_elems: int,
-    topo: Topology,
-    bucket_bytes: int,
-    link: LinkModel | None = None,
-    itemsize: int = 4,
-) -> float:
-    """Modeled seconds for one FULL bucketed all-reduce (round 20):
-    every bucket priced under the plan the selector picks for it,
-    summed — serial buckets, the conservative no-overlap estimate.
-    This is the ``--modeled-network`` column of the bench suite and the
-    collective term of ``runtime.netmodel.NetModel.step_time``."""
-    link = link or DEFAULT_LINK_MODEL
-    if n_elems <= 0 or topo.world <= 1:
-        return 0.0
-    total = 0.0
-    for start, stop in _bucket_bounds(n_elems, bucket_bytes, itemsize):
-        total += topo.predict_bucket_time(
-            (stop - start) * itemsize, link=link, itemsize=itemsize)
-    return total

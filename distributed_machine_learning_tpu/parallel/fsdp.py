@@ -202,7 +202,7 @@ def make_fsdp_train_step(
     Returns ``step(fsdp_state, images_u8, labels) -> (fsdp_state, loss)``
     with the batch sharded along the data axis.  ``jit=False`` returns
     the traceable step for callers that compile it inside a larger
-    program (the bench harness's scan epoch — same convention as
+    program (a scanned epoch — same convention as
     ``make_train_step``); the donate-argnums buffer reuse only applies
     to the jitted form.
 

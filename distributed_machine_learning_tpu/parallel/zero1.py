@@ -155,7 +155,7 @@ def make_zero1_train_step(
     should outlast ``device_block`` on the trace timeline (the loss is
     back before the gather is).  ``step.update_for(cfg)`` /
     ``step.gather_inner`` expose the two jitted programs for AOT
-    lowering and the HLO overlap audit (``bench/overlap_audit.py``).
+    lowering and the HLO overlap audit (``analysis/overlap_audit.py``).
     """
     n = mesh.shape[axis_name]
 

@@ -146,7 +146,7 @@ def ensure_host_devices(n: int = 8) -> None:
     MUST run before the CPU client spins up (the first ``jax.devices()``
     call) — after that the flag is ignored.  The ONE copy of the dance
     the virtual-mesh entrypoints share (the dmlcheck CLI, the overlap
-    bench/audit ``--cpu-mesh`` paths), so the device count and the
+    audit's ``--cpu-mesh`` path), so the device count and the
     ordering invariant cannot drift between them.  tests/conftest.py
     keeps its own inline copy deliberately: it must mutate the env
     before importing ANYTHING from this package."""

@@ -1,7 +1,7 @@
 """Regime-aware dispatch scheduler for the serving tier (ISSUE 19).
 
-docs/PERF.md pins two measured serving levers: speculative decoding
-wins latency 2-5.7x when the batch is THIN (per-request wall time is
+The serving tier has two levers: speculative decoding
+wins latency when the batch is THIN (per-request wall time is
 decode-step count; extra draft FLOPs are free at low occupancy), and
 int8 weight-only ``quant_matmul`` wins throughput when the batch is
 WIDE (decode is weight-bandwidth-bound; halving weight bytes ~halves

@@ -339,7 +339,7 @@ class _StepWithSyncGauges:
         return self._jitted(state, tokens, targets)
 
     def _publish(self, *args) -> None:
-        from distributed_machine_learning_tpu.bench.overlap_audit import (
+        from distributed_machine_learning_tpu.ops.hlo import (
             all_reduces_from_hlo,
             grad_sync_bytes,
         )

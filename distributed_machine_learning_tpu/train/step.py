@@ -250,9 +250,8 @@ def make_train_step(
     AdamWConfig → adamw; an explicit registry name pins the update fn.
 
     ``jit=False`` returns the un-jitted step function (no donation) — for
-    callers that embed the step in a larger compiled program, e.g. the
-    benchmark's ``lax.scan``-ed epoch (bench.py), which keeps per-step
-    Python dispatch out of the measurement.
+    callers that embed the step in a larger compiled program, e.g. a
+    ``lax.scan``-ed epoch.
 
     Stateful strategies (``strategy.stateful``, e.g. the error-feedback
     compressed ring — ``RingAllReduce(compress="int8")``): the compiled
@@ -369,8 +368,8 @@ def make_train_step(
     if not jit:
         # Un-jitted stateful form: the caller threads the state
         # explicitly — step(state, x, y, sync_state) →
-        # (state, loss, sync_state) — e.g. a scanned-epoch bench
-        # carrying it alongside TrainState.
+        # (state, loss, sync_state) — e.g. a scanned epoch carrying it
+        # alongside TrainState.
         return sharded
     inner = jax.jit(sharded, donate_argnums=(0, 3))
 

@@ -68,7 +68,7 @@ def transformer_train_flops_per_token(
     entirely (compute AND DMA).  Set ``causal=False`` for the PaLM-style
     full-score-matrix convention; at long context the two differ by up
     to 2× on the attention term, so MFU tables must say which they use
-    (docs/PERF.md reports the causal/performed convention)."""
+    (``benchmark/flops.py`` counts the causal half too)."""
     attn = 12.0 * n_layers * d_model * seq_len
     if causal:
         attn /= 2.0

@@ -135,7 +135,7 @@ class MetricsLogger:
     def save(self, path: str | os.PathLike) -> None:
         """Write rows to `path`, format chosen by extension: ``.csv`` for
         CSV, anything else JSONL.  The single dispatch point for every
-        caller (CLI, bench, sweep).  In streaming mode a save to the
+        caller.  In streaming mode a save to the
         streamed path flushes (the rows are already on disk) instead of
         rewriting — rewriting would truncate prior attempts' appended
         history, the exact loss this logger was rebuilt to prevent."""

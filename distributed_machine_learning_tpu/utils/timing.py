@@ -19,7 +19,7 @@ from typing import Sequence
 def percentile(times: Sequence[float], q: float) -> float:
     """Exact ``q``-quantile (``q`` in [0, 1]) by linear interpolation
     between order statistics (numpy's default method, stdlib-only so the
-    bench/tools layer can share it without dependencies)."""
+    tools layer can share it without dependencies)."""
     if not 0.0 <= q <= 1.0:
         raise ValueError(f"q must be in [0, 1], got {q}")
     if not times:
@@ -33,7 +33,7 @@ def percentile(times: Sequence[float], q: float) -> float:
 
 def percentile_stats(times: Sequence[float]) -> dict:
     """{p50, p95, p99, max} of a sample — the tail-latency block every
-    timing surface (timer summary, bench result dicts) shares, because a
+    timing surface shares, because a
     mean hides exactly the straggler steps production debugging needs
     (ISSUE 2; arxiv 1811.05233's per-phase accounting)."""
     return {

@@ -26,7 +26,7 @@ from distributed_machine_learning_tpu.telemetry.registry import (
     MetricsRegistry,
 )
 
-EOS = 13  # the tiny model's greedy attractor (it emits runs of 13s)
+EOS_PROMPT = [9, 10, 11, 12]
 
 
 @pytest.fixture(scope="module")
@@ -38,6 +38,16 @@ def lm():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )["params"]
     return model, params
+
+
+@pytest.fixture(scope="module")
+def eos(lm):
+    """An EOS id the fixture model really emits: the second new token
+    of ``EOS_PROMPT``'s greedy stream.  Read from the model, because a
+    constant goes stale whenever the tiny model's initialisation moves
+    (13 was its attractor once; its streams no longer hold a 13)."""
+    model, params = lm
+    return _ref(model, params, EOS_PROMPT, 2)[-1]
 
 
 def _ref(model, params, prompt, n, **kw):
@@ -84,16 +94,17 @@ def test_engine_mid_flight_admission_parity(lm):
     assert done["b"]["tokens"] == _ref(model, params, [5, 6, 7], 8)
 
 
-def test_engine_eos_retires_and_backfills_same_step(lm):
+def test_engine_eos_retires_and_backfills_same_step(lm, eos):
     """EOS retirement frees the lane and the pool blocks, and a queued
     request backfills inside the same step() call."""
     model, params = lm
     eng = ContinuousEngine(model, params, EngineConfig(
         max_lanes=1, block_size=4, num_blocks=8, max_len=32,
-        eos_id=EOS, levers=("latency",),
+        eos_id=eos, levers=("latency",),
     ))
-    # [9,10,11,12] greedily continues 13 13 ... -> instant EOS.
-    eng.submit("a", [9, 10, 11, 12], max_new=10)
+    # EOS_PROMPT reaches EOS at its second new token; b's three never do.
+    assert eos not in _ref(model, params, [1, 2, 3], 3)[3:]
+    eng.submit("a", EOS_PROMPT, max_new=10)
     eng.submit("b", [1, 2, 3], max_new=3)
     # Step until a retires; b must be admitted in that same call.
     for _ in range(50):
@@ -102,34 +113,34 @@ def test_engine_eos_retires_and_backfills_same_step(lm):
             break
     assert out and out[0]["rid"] == "a"
     assert out[0]["finish"] == "eos"
-    assert out[0]["tokens"][-1] == EOS
+    assert out[0]["tokens"][-1] == eos
     assert eng.in_flight() == 1            # b backfilled immediately
     assert eng.queued() == 0
-    ref = _ref(model, params, [9, 10, 11, 12], 10, eos_id=EOS)
-    cut = ref.index(EOS, 4) + 1
+    ref = _ref(model, params, EOS_PROMPT, 10, eos_id=eos)
+    cut = ref.index(eos, len(EOS_PROMPT)) + 1
     assert out[0]["tokens"] == ref[:cut]
     done = eng.drain()
     assert done[0]["rid"] == "b"
     assert done[0]["tokens"] == _ref(model, params, [1, 2, 3], 3)
 
 
-def test_serving_step_eos_parity_token_for_token(lm):
+def test_serving_step_eos_parity_token_for_token(lm, eos):
     """The ISSUE 19 semantics-drift fix: make_serving_step(eos_id=...)
     matches generate() token for token — identical prefix through the
     first EOS, eos-padding after — while the eos-free path is
     unchanged."""
     model, params = lm
-    prompts = [[1, 2, 3, 4], [9, 10, 11, 12], [5, 6, 7, 8]]
-    step = make_serving_step(model, params, 10, eos_id=EOS)
+    prompts = [[1, 2, 3, 4], EOS_PROMPT, [5, 6, 7, 8]]
+    step = make_serving_step(model, params, 10, eos_id=eos)
     outs = step([list(p) for p in prompts])
     for p, out in zip(prompts, outs):
         ref = _ref(model, params, p, 10)          # no-eos reference
         gen_ref = ref[len(p):]
         gen_out = out[len(p):]
-        if EOS in gen_ref:
-            cut = gen_ref.index(EOS) + 1
+        if eos in gen_ref:
+            cut = gen_ref.index(eos) + 1
             assert gen_out[:cut] == gen_ref[:cut]
-            assert all(t == EOS for t in gen_out[cut:])
+            assert all(t == eos for t in gen_out[cut:])
         else:
             assert gen_out == gen_ref
     # eos_id=None keeps the original scan program's output exactly.

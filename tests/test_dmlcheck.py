@@ -309,11 +309,11 @@ def test_audit_flags_forced_critical_path_allgather(mesh8):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
+    from distributed_machine_learning_tpu.analysis.overlap_audit import (
+        compile_ring_hlo,
+    )
     from distributed_machine_learning_tpu.analysis.program_audit import (
         audit_critical_path_collectives,
-    )
-    from distributed_machine_learning_tpu.bench.overlap_audit import (
-        compile_ring_hlo,
     )
     from distributed_machine_learning_tpu.runtime.mesh import (
         shard_map_no_check,
@@ -402,7 +402,7 @@ def test_layer2_real_steps_have_no_errors(mesh8):
 
 def test_zero1_sync_baseline_still_flagged(mesh8):
     """The legacy sync zero1 build (overlap=False — kept for parity
-    tests and the bench baseline) must STILL trip DML102 at error
+    tests) must STILL trip DML102 at error
     severity: the gate's teeth are demonstrated against the known-bad
     program, so a future change can't silently neuter the pass while
     the overlap build stays green."""

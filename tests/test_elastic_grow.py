@@ -301,9 +301,13 @@ def _spare_stub_cmd(tmp_path, prefetched_step=0):
     return spare_cmd
 
 
-# Attempt-0 workers: rank 0 announces a (non-spare) join for JOINRANK,
-# then everyone waits on the abort latch and takes the coordinated
-# abort exit (43); attempt >= 1 workers record themselves and finish.
+# Attempt-0 workers: rank 0 announces a (non-spare) join for JOINRANK
+# — once SPAREFILE, the spare's own announcement, is there, where a
+# test has a spare: the supervisor takes its snapshot of promotable
+# spares at the boundary the join triggers, and a spare process still
+# starting up is not in it — then everyone waits on the abort latch and
+# takes the coordinated abort exit (43); attempt >= 1 workers record
+# themselves and finish.
 _GROW_BODY = (
     "import json, os, sys, time\n"
     "rank, attempt, world, orig = {rank}, {attempt}, {world}, {orig}\n"
@@ -313,13 +317,17 @@ _GROW_BODY = (
     "    f.write(json.dumps(dict(rank=rank, attempt=attempt,\n"
     "                            world=world, orig=orig)) + '\\n')\n"
     "if attempt == 0:\n"
+    "    deadline = time.time() + 20\n"
     "    if rank == 0:\n"
+    "        spare = 'SPAREFILE'\n"
+    "        while (spare and time.time() < deadline and not\n"
+    "               os.path.exists(os.path.join(gang, spare))):\n"
+    "            time.sleep(0.01)\n"
     "        tmp = os.path.join(gang, '.join_tmp')\n"
     "        with open(tmp, 'w') as f:\n"
     "            json.dump(dict(rank=JOINRANK, spare=False,\n"
     "                           time=time.time()), f)\n"
     "        os.replace(tmp, os.path.join(gang, 'join_rankJOINRANK.json'))\n"
-    "    deadline = time.time() + 20\n"
     "    while time.time() < deadline:\n"
     "        if os.path.exists(os.path.join(gang, 'abort.json')):\n"
     "            os._exit(43)\n"
@@ -341,7 +349,8 @@ def test_gang_supervise_grows_on_announced_join(tmp_path):
     gang = tmp_path / "gang"
     events = FaultEvents()
     codes = gang_supervise(
-        _stub_worker_cmd(tmp_path, _GROW_BODY.replace("JOINRANK", "2")),
+        _stub_worker_cmd(tmp_path, _GROW_BODY.replace("JOINRANK", "2")
+                         .replace("SPAREFILE", "")),
         2, gang, max_world=3, events=events, poll_s=0.05,
         max_restarts=1, grace_s=5.0,
     )
@@ -367,7 +376,8 @@ def test_gang_supervise_promotes_spare_to_fill_grown_world(tmp_path):
     gang = tmp_path / "gang"
     events = FaultEvents()
     codes = gang_supervise(
-        _stub_worker_cmd(tmp_path, _GROW_BODY.replace("JOINRANK", "3")),
+        _stub_worker_cmd(tmp_path, _GROW_BODY.replace("JOINRANK", "3")
+                         .replace("SPAREFILE", "join_rank2.json")),
         2, gang, max_world=4, spares=1,
         spare_cmd=_spare_stub_cmd(tmp_path, prefetched_step=7),
         events=events, poll_s=0.05, max_restarts=1, grace_s=5.0,

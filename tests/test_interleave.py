@@ -13,7 +13,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import pytest
 
@@ -134,19 +133,30 @@ def test_chooser_survives_stale_prefix():
 # The tier-1 gate: the fixed tree is clean, quickly
 # ---------------------------------------------------------------------------
 
+# Scenarios whose quick schedule space is larger than their cap: the
+# rest are explored exhaustively, and must stay so.
+QUICK_CAPPED = {"beat_read_race", "dedup_inflight", "join_dup",
+                "ledger_storm"}
+# The sweep's size is its schedule count (33 090 today), not its
+# duration: quick mode reads no clock, so the count is the same on
+# every machine and under any load.
+QUICK_BUDGET = 40_000
+
+
 def test_quick_sweep_is_clean_and_bounded(tmp_path):
-    t0 = time.monotonic()
     findings, stats = run_layer3(quick=True,
                                  repro_dir=str(tmp_path / "repros"))
-    elapsed = time.monotonic() - t0
     assert findings == [], [f.message for f in findings]
-    assert elapsed < 30.0, (
-        f"--layer3 --quick took {elapsed:.1f}s (budget 30s): "
-        f"{stats}")
     assert set(stats["scenarios"]) == set(SCENARIOS)
     for name, entry in stats["scenarios"].items():
         assert entry["violations"] == 0, (name, entry)
-        assert entry["schedules"] >= 1
+        cap = SCENARIOS[name]["quick_max"]
+        assert 1 <= entry["schedules"] <= cap, (name, entry)
+        assert entry["capped"] == (name in QUICK_CAPPED), (name, entry)
+        if entry["capped"]:
+            assert entry["schedules"] == cap, (name, entry)
+    total = sum(e["schedules"] for e in stats["scenarios"].values())
+    assert total <= QUICK_BUDGET, (total, stats)
 
 
 # ---------------------------------------------------------------------------

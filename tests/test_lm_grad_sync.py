@@ -14,7 +14,7 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from distributed_machine_learning_tpu.bench.overlap_audit import (
+from distributed_machine_learning_tpu.ops.hlo import (
     all_reduces_from_hlo,
     grad_sync_bytes,
 )

@@ -1,5 +1,5 @@
 """Schedule-walker unit tests for the ring overlap audit
-(bench/overlap_audit.py); the TPU AOT compile itself is exercised by
+(ops/hlo.py's walkers, analysis/overlap_audit.py's compiles); the TPU AOT compile itself is exercised by
 the audit's __main__ on TPU-capable hosts.  The wire-byte audit
 (--wire-bytes) additionally gets a REAL compile check here: the CPU
 backend names collective-permute identically, so the int8-vs-exact
@@ -7,9 +7,11 @@ byte ratio is asserted against actual compiled executables in CI."""
 
 import pytest
 
-from distributed_machine_learning_tpu.bench.overlap_audit import (
-    audit_schedule,
+from distributed_machine_learning_tpu.analysis.overlap_audit import (
     compile_ring_hlo,
+)
+from distributed_machine_learning_tpu.ops.hlo import (
+    audit_schedule,
     wire_bytes_from_hlo,
 )
 
@@ -69,6 +71,53 @@ def test_wire_bytes_parser_counts_defs_once():
     assert got["by_dtype"] == {"s8": 64, "f32": 68}
 
 
+AXIS_HLO = """\
+HloModule m
+
+ENTRY main {
+  p0 = f32[8]{0} parameter(0)
+  cp.in = f32[8]{0} collective-permute(p0), source_target_pairs={{0,1},{1,0},{2,3},{3,2}}
+  cp.out = s8[8]{0} collective-permute(p0), source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+  cp.bare = f32[2]{0} collective-permute(p0)
+  ROOT r = f32[8]{0} add(cp.in, cp.in)
+}
+"""
+
+
+def test_wire_bytes_by_axis_from_routing_tables():
+    """Inner-major blocks of 2: a permute whose every pair stays inside a
+    block rides the inner links; one with ANY cross-block pair, or with
+    no routing table at all, is charged to the outer (bottleneck) axis."""
+    from distributed_machine_learning_tpu.ops.hlo import (
+        permute_pairs_from_line,
+    )
+
+    got = wire_bytes_from_hlo(AXIS_HLO, inner=2)
+    assert got["by_axis"] == {"inner": 32, "outer": 8 + 8}
+    assert got["total_bytes"] == 48 and got["count"] == 3
+    assert "by_axis" not in wire_bytes_from_hlo(AXIS_HLO)
+    assert permute_pairs_from_line(
+        "x = f32[1] collective-permute(y), source_target_pairs={{0,1},{3,2}}"
+    ) == [(0, 1), (3, 2)]
+    assert permute_pairs_from_line("x = f32[1] add(y, y)") is None
+
+
+@pytest.mark.parametrize("shape, nbytes", [
+    ("f32[2,4]", 32), ("f32[]", 4), ("bf16[3]", 6), ("pred[5]", 5),
+])
+def test_shape_bytes(shape, nbytes):
+    from distributed_machine_learning_tpu.ops.hlo import _shape_bytes
+
+    assert _shape_bytes(shape) == nbytes
+
+
+def test_shape_bytes_refuses_an_unknown_type():
+    """A width the table lacks must not count as zero bytes."""
+    with pytest.raises(ValueError, match="unknown HLO primitive type"):
+        wire_bytes_from_hlo(
+            "ENTRY e {\n  c = s4[8]{0} collective-permute(p)\n}")
+
+
 def test_wire_bytes_parser_empty_module():
     got = wire_bytes_from_hlo("HloModule m\nENTRY main { ROOT r = f32[] constant(0) }")
     assert got == {"total_bytes": 0, "count": 0, "by_dtype": {}}
@@ -116,7 +165,7 @@ def test_sync_collectives_feed_root_through_gte():
     into a variadic all-reduce) reach ROOT via get-tuple-element; the
     feeds_root attribution must see through one GTE hop, or the sync
     baseline's critical-path collective reads as innocent."""
-    from distributed_machine_learning_tpu.bench.overlap_audit import (
+    from distributed_machine_learning_tpu.ops.hlo import (
         sync_collectives_from_hlo,
     )
 
@@ -134,7 +183,7 @@ def test_zero1_overlap_audit_ci_regression(mesh8):
     contains no all-gather and no root-feeding collective of any kind;
     the consume program is permute-only.  A future change that
     re-serializes the gather fails here."""
-    from distributed_machine_learning_tpu.bench.overlap_audit import (
+    from distributed_machine_learning_tpu.analysis.overlap_audit import (
         zero1_overlap_audit,
     )
 

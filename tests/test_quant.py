@@ -236,8 +236,8 @@ def test_tp_decode_with_int8_kv_cache_token_exact(rng):
 
 
 def test_int8_tiered_dispatch_token_exact(rng):
-    """The gated two-tier int8-cache dispatch (bench/int8_tier.py;
-    models/transformer.py::_INT8_TIERED_DISPATCH) must be semantics-
+    """The gated two-tier int8-cache dispatch
+    (models/transformer.py::_INT8_TIERED_DISPATCH) must be semantics-
     neutral: same greedy stream as the default einsum-only dispatch,
     with the generation crossing the break-even so BOTH branches run.
 

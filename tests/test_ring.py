@@ -492,7 +492,7 @@ def test_int8_ring_acceptance_audit_and_parity(mesh8, rng):
        int8+error-feedback ring's final loss is within 1% relative of
        the uncompressed ring's.
     """
-    from distributed_machine_learning_tpu.bench.overlap_audit import (
+    from distributed_machine_learning_tpu.ops.hlo import (
         wire_bytes_from_hlo,
     )
     from distributed_machine_learning_tpu.cli.common import (

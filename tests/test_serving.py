@@ -600,13 +600,7 @@ def test_tcp_subprocess_replica_partition_is_healed(tmp_path):
     try:
         # Gate the load on BOTH subprocess replicas being live, so the
         # partition is guaranteed to hit a serving replica.
-        deadline = time.monotonic() + 30.0
-        while True:
-            with router._lock:
-                if sorted(router._replicas) == [0, 1]:
-                    break
-            assert time.monotonic() < deadline, "replicas never joined"
-            time.sleep(0.02)
+        _wait_replicas(router, 2)
         _submit_with_backpressure(router, 60)
         # The warm spare joins mid-load, ready for the heal.
         procs.append(subprocess.Popen([*cmd, "--rank", "2"], env=env))
@@ -688,6 +682,17 @@ def _observed_fleet(tmp_path, backend, world, step_fn, *,
     return gang, teldir, router, router_tel, worker_tels, fleet
 
 
+def _wait_replicas(router, n: int) -> None:
+    """Block until the running router has promoted ``n`` replicas."""
+    deadline = time.monotonic() + 30.0
+    while True:
+        with router._lock:
+            if len(router._replicas) == n:
+                return
+        assert time.monotonic() < deadline, f"never saw {n} live replicas"
+        time.sleep(0.005)
+
+
 def _teardown_fleet(router, rt, stop_router, fleet, router_tel,
                     worker_tels):
     verdict = router.close()
@@ -720,6 +725,10 @@ def test_request_journey_lands_in_every_artifact_plane(tmp_path,
     rt.start()
     rids = []
     try:
+        # (c) below wants BOTH replicas in the served records: the router
+        # dispatches to the least loaded of those it has promoted, so a
+        # replica that joins after the load has gone out serves none.
+        _wait_replicas(router, 2)
         for i in range(8):
             rids.append(router.submit([1 + i, 2]))
         assert router.wait_idle(60.0), router.audit()
@@ -849,13 +858,7 @@ def test_chaos_kill_replica_mid_compute_terminates_the_record(tmp_path):
                           daemon=True)
     rt.start()
     try:
-        deadline = time.monotonic() + 30.0
-        while True:
-            with router._lock:
-                if len(router._replicas) == 2:
-                    break
-            assert time.monotonic() < deadline, "fleet never warmed up"
-            time.sleep(0.005)
+        _wait_replicas(router, 2)
         rid = router.submit(poison)
         for i in range(10):
             router.submit([1 + i])

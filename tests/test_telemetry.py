@@ -726,7 +726,7 @@ def test_get_logger_is_idempotent():
 
 
 # ---------------------------------------------------------------------------
-# IterationTimer percentiles satellite (utils/timing.py, bench/harness.py)
+# IterationTimer percentiles satellite (utils/timing.py)
 # ---------------------------------------------------------------------------
 
 
@@ -757,29 +757,6 @@ def test_iteration_timer_summary_includes_tail():
     text = t.summary()
     assert "Total execution time is" in text  # reference lines intact
     assert "p50/p95/p99/max" in text
-
-
-def test_timed_scan_epoch_fills_stats(rng):
-    import jax.numpy as jnp
-
-    from distributed_machine_learning_tpu.bench.harness import (
-        timed_scan_epoch,
-    )
-
-    def step(c, x, y):
-        return c + jnp.sum(x) + jnp.sum(y), jnp.sum(x) * 0.0
-
-    xs = jnp.asarray(rng.normal(size=(3, 2)).astype(np.float32))
-    ys = jnp.asarray(rng.normal(size=(3, 2)).astype(np.float32))
-    stats = {}
-    best, _, _ = timed_scan_epoch(step, jnp.float32(0.0), xs, ys, reps=2,
-                                  chain=2, stats=stats)
-    # Longest-chain regime only: the 1-dispatch reps carry the full
-    # dispatch round-trip the chained ones amortize — pooling them
-    # would make "p95" measure RTT, not step stragglers.
-    assert stats["samples"] == 2
-    assert 0 < stats["p50_s"] <= stats["p95_s"] <= stats["max_s"]
-    assert best > 0
 
 
 # ---------------------------------------------------------------------------

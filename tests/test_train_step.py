@@ -93,8 +93,8 @@ def test_ring_step_equals_single_device_full_vgg11(batch, mesh8):
     """The same part3 keystone at the reference's FULL VGG-11 size —
     excluded from the default (1-core-host) run; the fast run proves the
     strategy math on the narrow VGGTest, whose invariants are
-    model-independent, and the full model is exercised by bench.py and
-    the dryrun regardless."""
+    model-independent, and the full model is exercised by the benchmark's
+    cells and the dryrun regardless."""
     from distributed_machine_learning_tpu.models.vgg import VGG11
 
     full = VGG11()
