@@ -59,6 +59,7 @@ KERNEL_SHAPES = dict(
     page_block=128, pages=64, page_positions=(5, 700, 127, 1500),
     matmul=(8, 2048, 8192),               # decode rows × d_model × d_ff
     codec_len=1_600_000,                  # a VGG-11 ring chunk at world 4
+    delta_len=1000, delta_heads=4,        # 16 chunks, the last one padded
     ring_seq_per_chip=1024, ring_batch=1,
 )
 # On the MXU both kernel and reference multiply in bf16, so the f32
@@ -409,6 +410,36 @@ def phase_kernels(shapes=KERNEL_SHAPES, kernels: str = "compiled") -> None:
                  f"ring_codec: seam {seam!r} is not bit-equal to the XLA "
                  "build")
     print("[chip_smoke]   ring_codec: 5 seams bit-equal to the XLA build")
+
+    # gdn_state_fwd / gdn_state_bwd: at head widths of 128 the delta rule's
+    # dispatch takes them on a TPU (and the scan anywhere else).
+    from distributed_machine_learning_tpu.ops import delta_rule
+
+    Td, Hd = shapes["delta_len"], shapes["delta_heads"]
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    qd, kd = (unit(normal((1, Td, Hd, 128), jnp.float32)) for _ in range(2))
+    rule_args = ((qd * 128 ** -0.5).astype(bf16), kd.astype(bf16),
+                 normal((1, Td, Hd, 128)),
+                 -0.1 * jax.nn.softplus(normal((1, Td, Hd), jnp.float32)),
+                 jax.nn.sigmoid(normal((1, Td, Hd), jnp.float32)))
+
+    def out_and_grads(rule):
+        def f(*a):
+            out = rule(*a).astype(jnp.float32)
+            return out, jax.grad(lambda *b: jnp.sin(
+                rule(*b).astype(jnp.float32)).sum(), argnums=(0, 1, 2))(*a)
+        return f
+
+    want = _exact(out_and_grads(delta_rule.gated_delta_rule_recurrent),
+                  *f32(*rule_args))
+    got = _run_kernel("gated_delta_rule",
+                      out_and_grads(delta_rule.gated_delta_rule), rule_args,
+                      kernels)
+    for name, g, w in zip(("out", "dq", "dk", "dv"),
+                          jax.tree_util.tree_leaves(got),
+                          jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.abs(w).max())
+        _close(f"gated_delta_rule[{name}]", g / scale, w / scale, BF16_TOL)
 
     # ring_flash_attention: a ring needs more than one chip.
     n = jax.device_count()

@@ -15,24 +15,50 @@ step at a time — what the tests compare against.
 
 :func:`gated_delta_rule` computes the same in chunks of ``CHUNK`` steps
 (Yang et al., "Gated Delta Networks", arXiv:2412.06464, §3.3; the WY/UT
-form).  With ``γ_i = Σ_{t<=i} g_t`` inside a chunk and ``S₀`` the state
-entering it, the corrections ``u_i = Δ_i`` of a chunk solve one unit
-lower-triangular system::
+form), in three parts.
+
+**Preparation** (``_prepare``; XLA, batched over all chunks).  With ``γ_i =
+Σ_{t<=i} g_t`` inside a chunk and ``S₀`` the state entering it, the
+corrections ``u_i = Δ_i`` of a chunk solve one unit lower-triangular system::
 
     (I + tril(β_i · k_i·k_j · exp(γ_i − γ_j), −1)) [W | U] = [β k exp(γ) | β v]
     u = U − W S₀
 
-so the triangular solves of all chunks run at once, batched, and only the
-state crosses chunks, in one ``lax.scan`` with four small matmuls a step::
+so the triangular solves of all chunks run at once, and with them ``attn =
+tril(q_i·k_j · exp(γ_i − γ_j))``, ``q_in = q exp(γ)``, ``k_out = k exp(γ_C −
+γ)`` and ``d = exp(γ_C)``.
 
-    o  = (q exp(γ)) S₀ + tril(q_i·k_j · exp(γ_i − γ_j)) u
-    S₁ = S₀ exp(γ_C) + (k exp(γ_C − γ))ᵀ u
+**The state pass** — all that crosses chunks, one chunk after the other::
 
-Plain JAX, gradients by autodiff (of a forward pass made again in the
-backward pass: :func:`gated_delta_rule`).  The triangular system, the decays and the
-state are float32; the matmuls take their operands in the inputs' dtype
-(bf16 on the MXU) and accumulate in float32.  A length that is no multiple of
-the chunk is padded with steps that leave the state alone (``β = 0, g = 0``).
+    u  = U − W S₀
+    o  = q_in S₀ + attn u
+    S₁ = S₀ d + k_outᵀ u
+
+**Its reverse** — the backward pass is written by hand (``jax.custom_vjp``),
+because it is the same kind of recurrence, walked from the last chunk to the
+first with the cotangent ``dS`` of the state as its carry::
+
+    du = attnᵀ do + k_out dS₁
+    dS₀ = dS₁ d + q_inᵀ do − Wᵀ du
+
+and ``dU = du``, ``dW = −du S₀ᵀ``, ``dk_out = u dS₁ᵀ``, ``dq_in = do S₀ᵀ``,
+``dattn = do uᵀ``, ``dd = ⟨dS₁, S₀⟩`` in the same step.  The backward pass
+keeps the five inputs only: it makes the preparation again (its own
+gradient is ``jax.vjp`` of ``_prepare``) and the forward states again.
+
+The state pass has two implementations of one arithmetic
+(``ops/pallas/gdn_state.py``: ``fwd_step``, ``read_out``, ``bwd_step``), and
+:func:`state_pass` says which a call gets, from what it can see: Pallas
+kernels that hold the state in VMEM for the whole pass (``gdn_state_fwd``,
+``gdn_state_bwd``) where the head widths are multiples of 128, the chunk is
+64 and the platform is a TPU; otherwise ``lax.scan`` over the same step
+functions, mapped over batch and heads (test widths, CPU runs: the Pallas
+interpreter walks a 128-step grid slowly).
+
+The triangular system, the decays and the state are float32; the matmuls
+take their operands in the inputs' dtype (bf16 on the MXU) and accumulate in
+float32.  A length that is no multiple of the chunk is padded with steps
+that leave the state alone (``β = 0, g = 0``).
 """
 
 from __future__ import annotations
@@ -42,6 +68,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from distributed_machine_learning_tpu.ops.pallas import gdn_state
+from distributed_machine_learning_tpu.ops.pallas.common import interpret
 
 CHUNK = 64
 
@@ -68,41 +97,85 @@ def gated_delta_rule_recurrent(q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1)
 
 
+def state_pass(dk: int, dv: int, chunk: int, platform: str) -> str:
+    """Which implementation of the state pass a call gets: ``"kernel"``
+    (``ops/pallas/gdn_state.py``'s ``pallas_call``s) or ``"scan"``
+    (``lax.scan`` over the same step functions).  The kernels tile the state
+    in (8, 128) registers and 128-wide MXU passes and were written for the
+    chunk of 64; anywhere but on a TPU Pallas interprets, a grid step at a
+    time."""
+    fits = dk % 128 == 0 and dv % 128 == 0 and chunk == 64
+    return "kernel" if fits and platform == "tpu" else "scan"
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     """The chunked form (module docstring).  Shapes as
     :func:`gated_delta_rule_recurrent`; returns [B, T, H, dv] in
     ``v.dtype``.
 
-    The backward pass keeps the five inputs and computes the chunked
-    forward again (``jax.checkpoint``), as a flash-attention kernel
-    recomputes its probabilities: the chunk-local systems, their solutions
-    and the scan's per-chunk states are float32 arrays of ``[T/C, B, H, C,
-    dk + dv]`` and ``[T/C, B, H, dk, dv]`` — some 2 GB a layer at 8192
-    tokens and 32 heads of 128, against 0.2 GB of inputs — and cost a few
-    small matmuls a chunk to make again."""
+    The backward pass keeps the five inputs and computes the preparation
+    and the forward states again, as a flash-attention kernel recomputes
+    its probabilities: the chunk-local systems, their solutions and the
+    per-chunk states are float32 arrays of ``[T/C, B, H, C, dk + dv]`` and
+    ``[T/C, B, H, dk, dv]`` — some 2 GB a layer at 8192 tokens and 32 heads
+    of 128, against 0.2 GB of inputs — and cost a few small matmuls a chunk
+    to make again."""
     return _chunked(q, k, v, g, beta, chunk)
 
 
-@partial(jax.checkpoint, static_argnums=(5,))
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _chunked(q, k, v, g, beta, chunk):
-    f32 = jnp.float32
-    dt = v.dtype
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
+    W, U, attn, q_in, k_out, d = _prepare(q, k, v, g, beta, chunk)
+    o = _pass(_kind(W, U), W, U, k_out, d, q_in, attn)  # [nc, B, H, C, dv]
+    B, H, T = q.shape[0], q.shape[2], q.shape[1]
+    o = jnp.moveaxis(o, (0, 2), (1, 3))                  # [B, nc, C, H, dv]
+    return o.reshape(B, -1, H, o.shape[-1])[:, :T]
+
+
+def _chunked_fwd(q, k, v, g, beta, chunk):
+    return _chunked(q, k, v, g, beta, chunk), (q, k, v, g, beta)
+
+
+def _chunked_bwd(chunk, inputs, do):
+    # What is made again here is, to the compiler, what the forward pass
+    # made: without the barrier it keeps that (0.7 GB a layer) instead.  The
+    # barrier also ties the inputs to ``do``: nothing starts before it.
+    inputs, do = lax.optimization_barrier((inputs, do))
+    (W, U, attn, q_in, k_out, d), prepare_vjp = jax.vjp(
+        partial(_prepare, chunk=chunk), *inputs)
+    kind = _kind(W, U)
+    S, u = _pass(kind, W, U, k_out, d)
+    dU, dW, dk_out, dq_in, dattn, dd = _reverse_pass(
+        kind, S, u, W, k_out, q_in, attn, _chunks(do, chunk), d)
+    return prepare_vjp((dW, dU, dattn, dq_in, dk_out, dd))
+
+
+_chunked.defvjp(_chunked_fwd, _chunked_bwd)
+
+
+def _chunks(a, chunk):
+    """[B, T, H, ...] -> [nc, B, H, chunk, ...], ``T`` padded with zeros to
+    a multiple of the chunk."""
+    B, T, H = a.shape[:3]
     pad = -T % chunk
     if pad:
-        widths = ((0, 0), (0, pad)) + ((0, 0),) * 2
-        q, k, v = (jnp.pad(a, widths) for a in (q, k, v))
-        g, beta = (jnp.pad(a, widths[:3]) for a in (g, beta))
-    nc = (T + pad) // chunk
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    a = a.reshape(B, (T + pad) // chunk, chunk, H, *a.shape[3:])
+    return jnp.moveaxis(a, (1, 3), (0, 2))
 
-    def chunks(a):  # [B, T, H, ...] -> [nc, B, H, chunk, ...]
-        a = a.reshape(B, nc, chunk, H, *a.shape[3:])
-        return jnp.moveaxis(a, (1, 3), (0, 2))
 
-    q, k, v = chunks(q), chunks(k), chunks(v)
-    beta = chunks(beta.astype(f32))[..., None]          # [nc, B, H, C, 1]
-    gamma = jnp.cumsum(chunks(g.astype(f32)), axis=-1)  # [nc, B, H, C]
+def _prepare(q, k, v, g, beta, chunk):
+    """Everything chunk-local, batched over the chunks: ``W`` [nc, B, H, C,
+    dk], ``U`` float32 [nc, B, H, C, dv], ``attn`` [nc, B, H, C, C],
+    ``q_in``, ``k_out`` [nc, B, H, C, dk] of the module docstring, and
+    ``d`` float32 [nc, B, H, 1, dv]: a head's number as a row of equal
+    lanes, the form in which it meets the state's rows."""
+    f32 = jnp.float32
+    dt = v.dtype
+    dk, dv = q.shape[-1], v.shape[-1]
+    q, k, v = (_chunks(a, chunk) for a in (q, k, v))
+    beta = _chunks(beta.astype(f32), chunk)[..., None]   # [nc, B, H, C, 1]
+    gamma = jnp.cumsum(_chunks(g.astype(f32), chunk), axis=-1)
     # exp(γ_i − γ_j) for j <= i, zero above the diagonal.  The difference is
     # masked before the exponential: above the diagonal it is positive and
     # may overflow, which would poison the gradient of the masked entries.
@@ -126,21 +199,55 @@ def _chunked(q, k, v, g, beta, chunk):
     q_in = (q.astype(f32) * jnp.exp(gamma)[..., None]).astype(dt)
     gamma_end = gamma[..., -1:]                          # [nc, B, H, 1]
     k_out = (kf * jnp.exp(gamma_end - gamma)[..., None]).astype(dt)
+    d = jnp.broadcast_to(jnp.exp(gamma_end)[..., None], (*W.shape[:3], 1, dv))
+    return W, U, attn, q_in, k_out, d
 
-    def matmul(a, b):
-        return jnp.matmul(a, b, preferred_element_type=f32)
+
+def _kind(W, U):
+    chunk, dk = W.shape[-2:]
+    return state_pass(dk, U.shape[-1], chunk,
+                      "cpu" if interpret() else "tpu")
+
+
+def _pass(kind, W, U, k_out, d, q_in=None, attn=None):
+    """The state pass over [nc, B, H, ...] arrays.  With ``q_in`` and
+    ``attn``: ``o`` [nc, B, H, C, dv] in the operands' dtype.  Without: the
+    state entering each chunk (float32 [nc, B, H, dk, dv]) and ``u`` [nc, B,
+    H, C, dv]."""
+    reads = q_in is not None
+    xs = (W, U, k_out, d) + ((q_in, attn) if reads else ())
+    if kind == "kernel":
+        return _through_kernels(gdn_state.state_fwd, xs)
 
     def step(S, x):
-        W_c, U_c, attn_c, q_c, k_c, decay_c = x
-        S_in = S.astype(dt)
-        u = U_c - matmul(W_c, S_in)                      # [B, H, C, dv]
-        u_in = u.astype(dt)
-        out = matmul(q_c, S_in) + matmul(attn_c, u_in)
-        S = S * decay_c[..., None] + matmul(jnp.swapaxes(k_c, -1, -2), u_in)
-        return S, out
+        S_new, u = _heads(gdn_state.fwd_step)(S, *x[:4])
+        if reads:
+            return S_new, _heads(gdn_state.read_out)(S, u, *x[4:]).astype(
+                u.dtype)
+        return S_new, (S, u)
 
-    S0 = jnp.zeros((B, H, dk, dv), f32)
-    _, out = lax.scan(
-        step, S0, (W, U, attn, q_in, k_out, jnp.exp(gamma_end)))
-    out = jnp.moveaxis(out, (0, 2), (1, 3))              # [B, nc, C, H, dv]
-    return out.reshape(B, nc * chunk, H, dv)[:, :T].astype(dt)
+    S0 = jnp.zeros((*W.shape[1:3], W.shape[-1], U.shape[-1]), jnp.float32)
+    return lax.scan(step, S0, xs)[1]
+
+
+def _reverse_pass(kind, S, u, W, k_out, q_in, attn, do, d):
+    """The reverse state pass over [nc, B, H, ...] arrays: the cotangents
+    of ``U, W, k_out, q_in, attn, d``."""
+    xs = (S, u, W, k_out, q_in, attn, do, d)
+    if kind == "kernel":
+        return _through_kernels(gdn_state.state_bwd, xs)
+    return lax.scan(lambda dS, x: _heads(gdn_state.bwd_step)(dS, *x),
+                    jnp.zeros(S.shape[1:], jnp.float32), xs, reverse=True)[1]
+
+
+def _heads(f):
+    return jax.vmap(jax.vmap(f))
+
+
+def _through_kernels(kernels, arrays):
+    """``kernels(*arrays)`` with batch and heads folded for the call ([nc, B,
+    H, ...] -> [nc, BH, ...]) and unfolded in what comes back."""
+    lead = arrays[0].shape[:3]
+    out = kernels(*(a.reshape(lead[0], -1, *a.shape[3:]) for a in arrays))
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape(*lead, *a.shape[2:]), out)

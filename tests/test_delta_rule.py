@@ -6,11 +6,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 
+from distributed_machine_learning_tpu.ops import delta_rule
 from distributed_machine_learning_tpu.ops.delta_rule import (
     CHUNK,
     gated_delta_rule,
     gated_delta_rule_recurrent,
+    state_pass,
 )
 
 #: float32 on both sides; the two differ by the order of their sums and by
@@ -79,3 +82,148 @@ def test_bf16_operands_accumulate_in_float32():
     assert out.dtype == jnp.bfloat16
     ref = gated_delta_rule_recurrent(*low)
     assert _rel(out.astype(jnp.float32), ref) < 0.03
+
+
+# --- The state pass: the Pallas kernels (interpreted here) against the
+# --- ``lax.scan`` of the same step functions, and the hand-written backward
+# --- pass against autodiff.
+
+#: The widths at which ``state_pass`` picks the kernels on a TPU.
+WIDE = dict(B=1, H=2, dk=128, dv=128)
+
+
+@pytest.fixture
+def through_kernels(monkeypatch):
+    """Takes the kernel path whatever the platform: the steering a CPU test
+    needs, since the dispatch itself leaves the interpreter alone."""
+    monkeypatch.setattr(delta_rule, "state_pass", lambda *a: "kernel")
+
+
+def _value_and_grads(rule, args, seed=9):
+    w = jax.random.normal(jax.random.PRNGKey(seed), args[2].shape)
+    loss = lambda *a: (rule(*a).astype(jnp.float32) * w).sum()
+    return rule(*args), jax.grad(loss, argnums=range(5))(*args)
+
+
+def _through(kind, monkeypatch, args):
+    with monkeypatch.context() as m:
+        m.setattr(delta_rule, "state_pass", lambda *a: kind)
+        return _value_and_grads(gated_delta_rule, args)
+
+
+def _autodiff_oracle(q, k, v, g, beta, chunk=CHUNK):
+    """The chunked rule as it was before the state pass had a backward pass
+    of its own: the preparation, then one ``lax.scan`` with four matmuls a
+    step, differentiated by autodiff."""
+    f32, dt = jnp.float32, v.dtype
+    W, U, attn, q_in, k_out, d = delta_rule._prepare(q, k, v, g, beta, chunk)
+    matmul = lambda a, b: jnp.matmul(a, b, preferred_element_type=f32)
+
+    def step(S, x):
+        W_c, U_c, attn_c, q_c, k_c, d_c = x
+        S_in = S.astype(dt)
+        u = U_c - matmul(W_c, S_in)
+        u_in = u.astype(dt)
+        out = matmul(q_c, S_in) + matmul(attn_c, u_in)
+        return S * d_c + matmul(jnp.swapaxes(k_c, -1, -2), u_in), out
+
+    B, T, H, dk = q.shape
+    S0 = jnp.zeros((B, H, dk, v.shape[-1]), f32)
+    _, out = lax.scan(step, S0, (W, U, attn, q_in, k_out, d))
+    out = jnp.moveaxis(out, (0, 2), (1, 3))
+    return out.reshape(B, -1, H, v.shape[-1])[:, :T].astype(dt)
+
+
+@pytest.mark.parametrize("dk, dv, chunk, platform, kind", [
+    (128, 128, 64, "tpu", "kernel"),
+    (256, 128, 64, "tpu", "kernel"),
+    (128, 128, 64, "cpu", "scan"),
+    (128, 128, 64, "gpu", "scan"),
+    (16, 24, 64, "tpu", "scan"),
+    (128, 192, 64, "tpu", "scan"),
+    (64, 128, 64, "tpu", "scan"),
+    (128, 128, 32, "tpu", "scan"),
+])
+def test_state_pass_is_chosen_from_shapes_and_platform(
+        dk, dv, chunk, platform, kind):
+    assert state_pass(dk, dv, chunk, platform) == kind
+
+
+def test_the_dispatch_asks_state_pass(monkeypatch):
+    """What ``state_pass`` says is what runs: the forward and the backward
+    pass each ask it with the call's own widths, and here — no TPU — with
+    ``"cpu"``."""
+    asked = []
+
+    def record(*a):
+        asked.append(a)
+        return state_pass(*a)
+
+    monkeypatch.setattr(delta_rule, "state_pass", record)
+    _value_and_grads(gated_delta_rule, _inputs(0, 70))
+    assert asked and set(asked) == {(16, 24, CHUNK, "cpu")}
+    assert state_pass(*asked[0]) == "scan"
+
+
+@pytest.mark.parametrize("T", [2 * CHUNK, 150])
+def test_kernels_match_the_recurrence(through_kernels, T):
+    args = _inputs(4, T, **WIDE)
+    out, grads = _value_and_grads(gated_delta_rule, args)
+    ref, ref_grads = _value_and_grads(gated_delta_rule_recurrent, args)
+    assert _rel(out, ref) < TOL
+    for name, a, b in zip("q k v g beta".split(), grads, ref_grads):
+        assert _rel(a, b) < TOL, name
+
+
+@pytest.mark.parametrize("T", [2 * CHUNK, 150])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16_operands"])
+def test_kernels_match_the_scan(monkeypatch, T, dtype):
+    """One arithmetic, two ways to walk the chunks: float32 within ``TOL``,
+    bf16 operands to the bit or within one bf16 ulp of a tensor's largest
+    entry (both round at the same places)."""
+    args = _inputs(5, T, **WIDE)
+    args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    out, grads = _through("kernel", monkeypatch, args)
+    ref, ref_grads = _through("scan", monkeypatch, args)
+    limit = TOL if dtype == jnp.float32 else 2.0 ** -8
+    assert out.dtype == dtype
+    assert _rel(out.astype(jnp.float32), ref.astype(jnp.float32)) < limit
+    for name, a, b in zip("q k v g beta".split(), grads, ref_grads):
+        assert a.dtype == b.dtype and jnp.isfinite(a).all(), name
+        assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < limit, name
+
+
+@pytest.mark.parametrize("kind, sizes, T, decay_shift", [
+    ("scan", {}, 150, -8.0), ("scan", {}, 150, 0.0), ("scan", {}, 150, 3.0),
+    ("kernel", WIDE, 2 * CHUNK, 0.0), ("kernel", WIDE, 150, -8.0),
+], ids=["scan_decay_near_1", "scan_decay_mid", "scan_decay_near_0",
+        "kernel_decay_mid", "kernel_decay_near_1"])
+def test_backward_pass_by_hand_matches_autodiff(
+        monkeypatch, kind, sizes, T, decay_shift):
+    args = _inputs(6, T, decay_shift, **sizes)
+    with monkeypatch.context() as m:
+        m.setattr(delta_rule, "state_pass", lambda *a: kind)
+        out, grads = _value_and_grads(gated_delta_rule, args)
+    ref, ref_grads = _value_and_grads(_autodiff_oracle, args)
+    assert _rel(out, ref) < TOL
+    for name, a, b in zip("q k v g beta".split(), grads, ref_grads):
+        assert jnp.isfinite(a).all(), name
+        assert _rel(a, b) < TOL, name
+
+
+def test_padding_steps_are_inert_through_the_kernels(through_kernels):
+    args = _inputs(7, 2 * CHUNK, **WIDE)
+    short = tuple(a[:, :CHUNK + 5] for a in args)
+    assert _rel(gated_delta_rule(*short),
+                gated_delta_rule(*args)[:, :CHUNK + 5]) < TOL
+
+
+def test_backward_pass_keeps_the_five_inputs_only(through_kernels):
+    """The residuals of the forward pass are its inputs: what the backward
+    pass needs of the chunks it makes again."""
+    args = _inputs(8, 2 * CHUNK, **WIDE)
+    _, vjp = jax.vjp(gated_delta_rule, *args)
+    kept = sorted(a.shape for a in jax.tree_util.tree_leaves(vjp)
+                  if hasattr(a, "shape"))
+    assert kept == sorted(a.shape for a in args)

@@ -63,39 +63,83 @@ def _randomized(params, seed):
         treedef, [draw(k, p, a) for k, (p, a) in zip(keys, leaves)])
 
 
-@pytest.fixture(scope="module")
-def tiny():
+def _against_the_reference(config, tokens_shape):
     from distributed_machine_learning_tpu.train.losses import lm_cross_entropy
 
-    model = hm.HybridMoELM(hm.HybridMoESizes.from_config(TINY))
-    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 71), 0, 97)
-    targets = jax.random.randint(jax.random.PRNGKey(5), (2, 71), 0, 97)
+    vocab = config["vocab_size"]
+    model = hm.HybridMoELM(hm.HybridMoESizes.from_config(config))
+    tokens = jax.random.randint(jax.random.PRNGKey(0), tokens_shape, 0, vocab)
+    targets = jax.random.randint(jax.random.PRNGKey(5), tokens_shape, 0, vocab)
     params = _randomized(
         model.init(jax.random.PRNGKey(1), tokens)["params"], seed=3)
-    loss, grads = jax.value_and_grad(lambda p: lm_cross_entropy(
-        model.apply({"params": p}, tokens), targets))(params)
+
+    def loss_and_logits(p):
+        logits = model.apply({"params": p}, tokens)
+        return lm_cross_entropy(logits, targets), logits
+
+    (loss, logits), grads = jax.value_and_grad(
+        loss_and_logits, has_aux=True)(params)
     ref_loss, ref_grads = jax.value_and_grad(
-        lambda p: reference.loss(p, TINY, tokens, targets))(params)
-    return dict(model=model, params=params, tokens=tokens, targets=targets,
-                loss=loss, grads=grads, ref_loss=ref_loss,
-                ref_grads=ref_grads)
+        lambda p: reference.loss(p, config, tokens, targets))(params)
+    return dict(model=model, config=config, params=params, tokens=tokens,
+                targets=targets, logits=logits, loss=loss, grads=grads,
+                ref_loss=ref_loss, ref_grads=ref_grads)
 
 
-def test_logits_match_the_reference(tiny):
-    logits = tiny["model"].apply({"params": tiny["params"]}, tiny["tokens"])
-    ref = reference.logits(tiny["params"], TINY, tiny["tokens"])
-    assert float(jnp.abs(logits - ref).max() / jnp.abs(ref).max()) < TOL
+@pytest.fixture(scope="module")
+def tiny():
+    return _against_the_reference(TINY, (2, 71))
 
 
-def test_loss_matches_the_reference(tiny):
-    assert float(tiny["loss"]) == pytest.approx(float(tiny["ref_loss"]),
-                                                rel=1e-6)
+#: The delta rule at the cell's head widths, where ``ops/delta_rule.py``'s
+#: ``state_pass`` picks the Pallas kernels on a TPU: one DeltaNet layer and
+#: one attention layer, two chunks (70 steps padded to 128).
+WIDE = {**TINY, "num_hidden_layers": 2, "full_attention_interval": 2,
+        "linear_num_key_heads": 1, "linear_num_value_heads": 2,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128}
 
 
-def test_every_gradient_matches_the_reference(tiny):
-    flat = jax.tree_util.tree_flatten_with_path(tiny["grads"])[0]
-    ref = jax.tree_util.tree_leaves(tiny["ref_grads"])
-    assert len(flat) == len(ref) > 60
+@pytest.fixture(scope="module")
+def wide():
+    """The model with its state pass through the kernels (interpreted: the
+    dispatch would take the scan here, so the test steers it)."""
+    from distributed_machine_learning_tpu.ops import delta_rule
+
+    asked = []
+
+    def kernels(*a):
+        asked.append(a)
+        return "kernel"
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(delta_rule, "state_pass", kernels)
+        out = _against_the_reference(WIDE, (1, 70))
+    assert asked and {a[:3] for a in asked} == {(128, 128, delta_rule.CHUNK)}
+    return out
+
+
+@pytest.fixture(params=["tiny", "wide"])
+def compared(request):
+    return request.getfixturevalue(request.param)
+
+
+def test_logits_match_the_reference(compared):
+    ref = reference.logits(compared["params"], compared["config"],
+                           compared["tokens"])
+    assert float(jnp.abs(compared["logits"] - ref).max()
+                 / jnp.abs(ref).max()) < TOL
+
+
+def test_loss_matches_the_reference(compared):
+    assert float(compared["loss"]) == pytest.approx(
+        float(compared["ref_loss"]), rel=1e-6)
+
+
+def test_every_gradient_matches_the_reference(compared):
+    flat = jax.tree_util.tree_flatten_with_path(compared["grads"])[0]
+    ref = jax.tree_util.tree_leaves(compared["ref_grads"])
+    layers = compared["config"]["num_hidden_layers"]
+    assert len(flat) == len(ref) > 15 * layers
     worst = {_name(p): float(jnp.abs(a - b).max() / jnp.abs(b).max())
              for (p, a), b in zip(flat, ref)}
     assert max(worst.values()) < TOL, max(worst, key=worst.get)
