@@ -112,8 +112,11 @@ def make_parser():
                         "(intermediate_size, rope_theta, norm_epsilon); "
                         "its model_type picks the module — 'qwen3_next' is "
                         "the hybrid Gated-DeltaNet / gated-attention MoE "
-                        "(models/hybrid_moe.py, --parallel dp), anything "
-                        "else the dense TransformerLM")
+                        "(models/hybrid_moe.py), 'deepseek_v3' the latent-"
+                        "attention MoE with bias-corrected sigmoid routing "
+                        "behind leading dense layers (models/mla_moe.py), "
+                        "both under --parallel dp only; anything else the "
+                        "dense TransformerLM")
     p.add_argument("--d-model", dest="d_model", default=256, type=int)
     p.add_argument("--n-layers", dest="n_layers", default=4, type=int)
     p.add_argument("--n-heads", dest="n_heads", default=8, type=int)
@@ -298,15 +301,25 @@ def read_model_config(args) -> dict:
     return config
 
 
+#: ``model_type``s of ``--model-config`` that bring a module of their own:
+#: (module under ``models/``, model class, sizes class).  Each trains under
+#: ``--parallel dp`` only.
+CONFIG_MODELS = {
+    "qwen3_next": ("hybrid_moe", "HybridMoELM", "HybridMoESizes"),
+    "deepseek_v3": ("mla_moe", "MLAMoELM", "MLAMoESizes"),
+}
+
+
 def dp_model(args, config: dict, **common):
     """The model ``--parallel dp`` trains: the module ``config``'s
     ``model_type`` names, or ``TransformerLM(**common)``."""
-    if config.get("model_type") != "qwen3_next":
+    if config.get("model_type") not in CONFIG_MODELS:
         return TransformerLM(**common)
-    from distributed_machine_learning_tpu.models.hybrid_moe import (
-        HybridMoELM,
-        HybridMoESizes,
-    )
+    import importlib
+
+    module, model_cls, sizes_cls = CONFIG_MODELS[config["model_type"]]
+    module = importlib.import_module(
+        "distributed_machine_learning_tpu.models." + module)
     from distributed_machine_learning_tpu.models.transformer import (
         _flash_wins,
     )
@@ -314,8 +327,8 @@ def dp_model(args, config: dict, **common):
     attn = common["attn_impl"]
     if attn == "auto":
         attn = "flash" if _flash_wins(args.seq_len) else "dense"
-    return HybridMoELM(
-        HybridMoESizes.from_config(config), attn_impl=attn,
+    return getattr(module, model_cls)(
+        getattr(module, sizes_cls).from_config(config), attn_impl=attn,
         compute_dtype=common["compute_dtype"], remat=common["remat"],
         remat_policy=common["remat_policy"])
 
@@ -330,12 +343,12 @@ def build(args):
     dtype = jnp.bfloat16 if args.compute_dtype == "bfloat16" else jnp.float32
     attn = getattr(args, "attn", "auto")
     config = read_model_config(args)
-    if config.get("model_type") == "qwen3_next" and args.parallel != "dp":
+    if config.get("model_type") in CONFIG_MODELS and args.parallel != "dp":
         raise ValueError(
-            "--model-config with model_type 'qwen3_next' trains under "
-            f"--parallel dp only (got --parallel {args.parallel}): the "
-            "hybrid model has no sequence-, tensor- or pipeline-sharded "
-            "step yet")
+            f"--model-config with model_type {config['model_type']!r} (as "
+            f"each of {sorted(CONFIG_MODELS)}) trains under --parallel dp "
+            f"only (got --parallel {args.parallel}): these models have no "
+            "sequence-, tensor- or pipeline-sharded step yet")
     carried = sorted({"intermediate_size", "rope_theta", "norm_epsilon"}
                      & set(config))
     if carried and args.parallel in ("pp", "3d", "ep"):

@@ -58,6 +58,7 @@ from distributed_machine_learning_tpu.ops.delta_rule import gated_delta_rule
 from distributed_machine_learning_tpu.ops.grouped import (
     grouped_expert_mlp,
     route_topk,
+    selection_moved_share,
 )
 from distributed_machine_learning_tpu.ops.ring_attention import (
     dense_self_attention,
@@ -87,15 +88,21 @@ def rms_norm(x, weight, eps: float, zero_centred: bool = True):
 
 
 class RMSNorm(nn.Module):
-    """Zero-centred RMSNorm: float32 inside, ``dtype`` out."""
+    """RMSNorm, float32 inside, ``dtype`` out: zero-centred (``1 + w``, the
+    weight starts at zero) or, with ``zero_centred=False``, plain (``w``,
+    starting at one)."""
 
     eps: float
     dtype: Any
+    zero_centred: bool = True
 
     @nn.compact
     def __call__(self, x):
-        weight = self.param("weight", nn.initializers.zeros, (x.shape[-1],))
-        return rms_norm(x, weight, self.eps).astype(self.dtype)
+        init = (nn.initializers.zeros if self.zero_centred
+                else nn.initializers.ones)
+        weight = self.param("weight", init, (x.shape[-1],))
+        return rms_norm(x, weight, self.eps,
+                        self.zero_centred).astype(self.dtype)
 
 
 def _a_log_init(key, shape):
@@ -250,11 +257,23 @@ def _sigmoid_gated(out, gate):
             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
 
 
+def _selection_bias_init(key, shape):
+    """Uniform on ±0.05: wide enough beside sigmoid scores near one half
+    that the bias changes the chosen set for a measurable share of tokens."""
+    return jax.random.uniform(key, shape, minval=-0.05, maxval=0.05)
+
+
 class SparseMoE(nn.Module):
-    """``p = softmax(x·W_r)`` over ``router_width`` experts in float32; the
-    ``experts_per_token`` largest, renormalised to sum to one when
-    ``norm_topk_prob``; expert ``e`` is ``W_down(SiLU(W_gate x) ⊙ W_up x)``;
-    ``y = Σ_{e ∈ top-k ∩ held} p̃_e·E_e(x) + σ(x·w_s)·E_shared(x)``."""
+    """``p = score(x·W_r)`` over ``router_width`` experts in float32,
+    ``score`` the softmax or (``score_func="sigmoid"``) the element-wise
+    sigmoid; the chosen set ``S`` is the ``experts_per_token`` largest of
+    ``p`` — of ``p + b`` with ``selection_bias``, ``b`` a buffer
+    (``e_score_correction_bias``: in the parameter tree, no gradient, named
+    in the model's ``frozen_params``) that picks and never weighs; ``p̃_e =
+    routed_scale · p_e / Σ_{j∈S} p_j`` (no division unless
+    ``norm_topk_prob``); expert ``e`` is ``W_down(SiLU(W_gate x) ⊙ W_up x)``;
+    ``y = Σ_{e ∈ S ∩ held} p̃_e·E_e(x) + g·E_shared(x)``, ``g = σ(x·w_s)``
+    with ``shared_gate``, else one."""
 
     router_width: int
     held_experts: tuple  # (first, count)
@@ -263,6 +282,10 @@ class SparseMoE(nn.Module):
     shared_d_ff: int
     norm_topk_prob: bool
     compute_dtype: Any
+    score_func: str = "softmax"
+    selection_bias: bool = False
+    routed_scale: float = 1.0
+    shared_gate: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -285,8 +308,21 @@ class SparseMoE(nn.Module):
                 self.router_width, use_bias=False, dtype=jnp.float32,
                 kernel_init=_INIT, precision=lax.Precision.HIGHEST,
                 name="router")(tokens.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
-            expert_idx, weights = route_topk(probs, k, self.norm_topk_prob)
+            if self.score_func not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"score_func must be 'softmax' or 'sigmoid', got "
+                    f"{self.score_func!r}")
+            probs = (jax.nn.sigmoid(logits) if self.score_func == "sigmoid"
+                     else jax.nn.softmax(logits, axis=-1))
+            bias = self.param(
+                "e_score_correction_bias", _selection_bias_init,
+                (self.router_width,)) if self.selection_bias else None
+            expert_idx, weights = route_topk(
+                probs, k, self.norm_topk_prob, bias=bias,
+                scale=self.routed_scale)
+            if bias is not None:
+                self.sow(STATS_COLLECTION, "bias_moved",
+                         selection_moved_share(probs, expert_idx))
         self.sow("moe_routing", "expert_idx", expert_idx)
         # Every assignment there is when all experts are held; else the
         # balanced share times HELD_ROWS_FACTOR, in whole 128-row tiles.
@@ -306,10 +342,33 @@ class SparseMoE(nn.Module):
             h = (jax.nn.silu(_dense(self.shared_d_ff, dt, "shared_gate_proj")(
                 tokens)) * _dense(self.shared_d_ff, dt, "shared_up_proj")(tokens))
             shared = _dense(D, dt, "shared_down_proj")(h)
-            gate = jax.nn.sigmoid(_dense(1, dt, "shared_expert_gate")(
-                tokens).astype(jnp.float32))
-            y = y + (gate * shared.astype(jnp.float32)).astype(dt)
+            if self.shared_gate:
+                gate = jax.nn.sigmoid(_dense(1, dt, "shared_expert_gate")(
+                    tokens).astype(jnp.float32))
+                shared = (gate * shared.astype(jnp.float32)).astype(dt)
+            y = y + shared
         return y.reshape(B, T, D)
+
+
+def routing_counts(sown) -> dict:
+    """One step's routing counts from what the sparse layers sowed: from
+    each layer's ``[rows, max/mean, dropped]`` the assignments computed here
+    a layer (the mean over the layers), the fullest held expert over the
+    mean (the worst layer) and the rows dropped (all layers); and, where
+    the layers select with a bias, the share of tokens whose chosen set the
+    bias changed (the mean over the layers)."""
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        name = [k.key for k in path if hasattr(k, "key")][-1]
+        by_name.setdefault(name, []).append(leaf)
+    layers = jnp.stack(by_name["layer"])
+    counts = {"moe_held_rows": layers[:, 0].mean(),
+              "moe_load_max_over_mean": layers[:, 1].max(),
+              "moe_dropped_rows": layers[:, 2].sum()}
+    if "bias_moved" in by_name:
+        counts["moe_bias_moved_share"] = jnp.stack(
+            by_name["bias_moved"]).mean()
+    return counts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -444,16 +503,7 @@ class HybridMoELM(nn.Module):
     stats_collection = STATS_COLLECTION
     stats_counters = ("moe_held_rows", "moe_dropped_rows")
 
-    @staticmethod
-    def step_stats(sown) -> dict:
-        """One step's routing counts from the layers' ``[rows, max/mean,
-        dropped]``: assignments computed here a layer (the mean over the
-        layers), the fullest held expert over the mean (the worst layer)
-        and the rows dropped (all layers)."""
-        layers = jnp.stack(jax.tree_util.tree_leaves(sown))
-        return {"moe_held_rows": layers[:, 0].mean(),
-                "moe_load_max_over_mean": layers[:, 1].max(),
-                "moe_dropped_rows": layers[:, 2].sum()}
+    step_stats = staticmethod(routing_counts)
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False,

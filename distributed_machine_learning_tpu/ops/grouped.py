@@ -114,18 +114,46 @@ def _permute_rows_bwd(res, ct):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-def route_topk(probs: jax.Array, k: int, renormalize: bool = False):
+def route_topk(probs: jax.Array, k: int, renormalize: bool = False, *,
+               bias: jax.Array | None = None, scale: float = 1.0):
     """The ``k`` experts of every token and their weights.
 
-    ``probs``: [N, E] router probabilities over ALL experts, held here or
-    not.  Returns ``(expert_idx [N, k] int32, weights [N, k])`` in
-    descending order of probability; ``renormalize`` makes each token's
-    ``k`` weights sum to one (over the chosen ``k``, wherever they live).
-    ``k = 1`` is Switch routing: the argmax and its probability."""
-    weights, expert_idx = lax.top_k(probs, k)
+    ``probs``: [N, E] router scores over ALL experts, held here or not (a
+    softmax's probabilities or element-wise sigmoids).  Returns
+    ``(expert_idx [N, k] int32, weights [N, k])`` in descending order of
+    what was selected by; ``renormalize`` makes each token's ``k`` weights
+    sum to one (over the chosen ``k``, wherever they live), and ``scale``
+    multiplies them after that.  ``k = 1`` is Switch routing: the argmax
+    and its probability.
+
+    ``bias`` [E]: the set is the ``k`` largest of ``probs + bias`` and the
+    weights are the chosen experts' ``probs`` — the bias picks, it never
+    weighs (bias-corrected routing without an auxiliary loss).  It gets no
+    gradient: the choice is an integer."""
+    if bias is None:
+        weights, expert_idx = lax.top_k(probs, k)
+    else:
+        _, expert_idx = lax.top_k(probs + bias, k)
+        weights = jnp.take_along_axis(probs, expert_idx, axis=-1)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        # + 1e-20: k sigmoids may sum to nothing; a softmax's top-k sum
+        # (>= k/E) takes it without moving a bit.
+        weights = weights / (
+            jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
     return expert_idx, weights
+
+
+def selection_moved_share(probs: jax.Array, expert_idx: jax.Array):
+    """The share of tokens whose chosen set ``expert_idx`` [N, k] is not the
+    ``k`` largest of ``probs`` [N, E]: some expert left out scores higher
+    than some expert chosen (what a selection bias changed)."""
+    chosen = jnp.any(
+        expert_idx[:, :, None] == jnp.arange(probs.shape[-1]), axis=1)
+    lowest_in = jnp.min(jnp.where(chosen, probs, jnp.inf), axis=-1)
+    highest_out = jnp.max(jnp.where(chosen, -jnp.inf, probs), axis=-1)
+    return jnp.mean((highest_out > lowest_in).astype(jnp.float32))
 
 
 def _gather_sum(rows, slot_of_assignment, weights=None):

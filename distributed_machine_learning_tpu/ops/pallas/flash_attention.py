@@ -344,10 +344,19 @@ def _flash_fwd_kernel(
         lse_ref[0] = m_ref[:, 0] + jnp.log2(l)
 
 
+def _kernel_name(base: str, D: int, Dv: int) -> str:
+    """The Pallas ``name=`` (the instruction's name in a device trace):
+    ``base`` where query/key and value heads are equally wide, else
+    ``base_qk<D>v<Dv>`` so that a trace tells the two kinds of call apart."""
+    return base if D == Dv else f"{base}_qk{D}v{Dv}"
+
+
 def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
-    """q: [BHq, L, D], k/v: [BHq // kv_groups, L, D] →
-    (out [BHq, L, D], lse [BHq, 1, L] fp32 — exact rows, not
-    lane-replicated).
+    """q: [BHq, L, D], k: [BHq // kv_groups, L, D], v: [BHq // kv_groups,
+    L, Dv] → (out [BHq, L, Dv], lse [BHq, 1, L] fp32 — exact rows, not
+    lane-replicated).  ``Dv`` may differ from ``D`` (latent attention: a
+    query/key head of 192 against a value head of 128); the scale is
+    ``D^-½``.
 
     ``kv_groups > 1`` is grouped-query attention natively: the K/V tile
     index maps divide the batch·head grid index by the group factor, so
@@ -356,23 +365,31 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
     heads fastest-varying, so bh // kv_groups is exactly the query
     head's KV group — the jnp.repeat(axis=2) convention.)"""
     BH, L, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / (D**0.5)
     grid = (BH, L // block_q, L // block_k)
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, scale=scale
     )
-    q_spec = pl.BlockSpec(
-        (1, block_q, D), lambda bh, qi, kb: (bh, qi, 0), memory_space=pltpu.VMEM
-    )
+
+    def q_spec(d):
+        return pl.BlockSpec(
+            (1, block_q, d), lambda bh, qi, kb: (bh, qi, 0),
+            memory_space=pltpu.VMEM,
+        )
+
     # Clamp above-diagonal K/V fetches to the diagonal tile: the index
     # repeats, so Pallas skips the copy (causal DMA elision).
-    k_spec = pl.BlockSpec(
-        (1, block_k, D),
-        lambda bh, qi, kb: (
-            bh // kv_groups, jnp.minimum(kb, _last_kb(qi, block_q, block_k)), 0
-        ),
-        memory_space=pltpu.VMEM,
-    )
+    def k_spec(d):
+        return pl.BlockSpec(
+            (1, block_k, d),
+            lambda bh, qi, kb: (
+                bh // kv_groups,
+                jnp.minimum(kb, _last_kb(qi, block_q, block_k)), 0
+            ),
+            memory_space=pltpu.VMEM,
+        )
+
     # (None, 1, block_q) block of a [BH, 1, L] array: the singleton
     # middle dim satisfies Mosaic's block-shape rule (last two dims
     # (1, block_q) — 1 equals the array dim, block_q % 128 == 0) while
@@ -384,21 +401,21 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
     scratch = [
         pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
         pltpu.VMEM((block_q, _LANES), jnp.float32),  # running normalizer
-        pltpu.VMEM((block_q, D), jnp.float32),  # output accumulator
+        pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
     ]
     return pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((BH, L, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, L, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, L), jnp.float32),
         ),
         grid=grid,
-        in_specs=[q_spec, k_spec, k_spec],
-        out_specs=(q_spec, lse_spec),
+        in_specs=[q_spec(D), k_spec(D), k_spec(Dv)],
+        out_specs=(q_spec(Dv), lse_spec),
         scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", D, Dv),
     )(q, k, v)
 
 
@@ -469,24 +486,33 @@ def _flash_bwd_dkv_kernel(
 
 
 def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
-    """q/do/lse/delta: [BHq, ...], k/v: [BHq // kv_groups, L, D] →
-    (dq [BHq, L, D], dk, dv [BHq, L, D] — PER QUERY HEAD; the caller
-    group-sums dk/dv down to the narrow KV heads, one cheap XLA
-    reduction, while the kernels never materialize repeated K/V)."""
+    """q/lse/delta: [BHq, ...], k: [BHq // kv_groups, L, D], v: [BHq //
+    kv_groups, L, Dv], do: [BHq, L, Dv] → (dq [BHq, L, D], dk [BHq, L,
+    D], dv [BHq, L, Dv] — PER QUERY HEAD; the caller group-sums dk/dv
+    down to the narrow KV heads, one cheap XLA reduction, while the
+    kernels never materialize repeated K/V)."""
     BH, L, D = q.shape
+    Dv = v.shape[-1]
     scale = 1.0 / (D**0.5)
 
     block_q, block_k = _fwd_blocks(L)  # dQ kernel: Q stationary, like fwd
-    q_spec_q = pl.BlockSpec(
-        (1, block_q, D), lambda bh, qi, kb: (bh, qi, 0), memory_space=pltpu.VMEM
-    )
-    k_spec_q = pl.BlockSpec(
-        (1, block_k, D),
-        lambda bh, qi, kb: (
-            bh // kv_groups, jnp.minimum(kb, _last_kb(qi, block_q, block_k)), 0
-        ),
-        memory_space=pltpu.VMEM,
-    )
+
+    def q_spec_q(d):
+        return pl.BlockSpec(
+            (1, block_q, d), lambda bh, qi, kb: (bh, qi, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    def k_spec_q(d):
+        return pl.BlockSpec(
+            (1, block_k, d),
+            lambda bh, qi, kb: (
+                bh // kv_groups,
+                jnp.minimum(kb, _last_kb(qi, block_q, block_k)), 0
+            ),
+            memory_space=pltpu.VMEM,
+        )
+
     # lse/Δ ride as exact (1, block_q) rows of [BH, 1, L] — sequence in
     # lanes, no replication; in-kernel use pays one lane→sublane
     # relayout per tile.
@@ -501,36 +527,44 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
         ),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         grid=(BH, L // block_q, L // block_k),
-        in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q, row_spec_q,
-                  row_spec_q],
-        out_specs=q_spec_q,
+        in_specs=[q_spec_q(D), k_spec_q(D), k_spec_q(Dv), q_spec_q(Dv),
+                  row_spec_q, row_spec_q],
+        out_specs=q_spec_q(D),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name="flash_bwd_dq",
+        name=_kernel_name("flash_bwd_dq", D, Dv),
     )(q, k, v, do, lse, delta)
 
     # dK/dV: K blocks own the accumulators, Q innermost.  Below-diagonal
     # Q/dO fetches clamp to the first in-range tile (DMA elision).
     block_q, block_k = _dkv_blocks(L)
-    q_spec_k = pl.BlockSpec(
-        (1, block_q, D),
-        lambda bh, kb, qi: (
-            bh, jnp.maximum(qi, _first_qi(kb, block_q, block_k)), 0
-        ),
-        memory_space=pltpu.VMEM,
-    )
+
+    def q_spec_k(d):
+        return pl.BlockSpec(
+            (1, block_q, d),
+            lambda bh, kb, qi: (
+                bh, jnp.maximum(qi, _first_qi(kb, block_q, block_k)), 0
+            ),
+            memory_space=pltpu.VMEM,
+        )
+
     # K/V input tiles read the narrow heads; the dk/dv OUTPUTS stay per
     # query head (out_specs use bh as-is) — accumulating across a group
     # inside the kernel would serialize the bh grid axis, so the group
     # sum happens outside in XLA instead.
-    kv_in_spec = pl.BlockSpec(
-        (1, block_k, D), lambda bh, kb, qi: (bh // kv_groups, kb, 0),
-        memory_space=pltpu.VMEM,
-    )
-    k_spec_k = pl.BlockSpec(
-        (1, block_k, D), lambda bh, kb, qi: (bh, kb, 0), memory_space=pltpu.VMEM
-    )
+    def kv_in_spec(d):
+        return pl.BlockSpec(
+            (1, block_k, d), lambda bh, kb, qi: (bh // kv_groups, kb, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    def k_spec_k(d):
+        return pl.BlockSpec(
+            (1, block_k, d), lambda bh, kb, qi: (bh, kb, 0),
+            memory_space=pltpu.VMEM,
+        )
+
     row_spec_k = pl.BlockSpec(
         (None, 1, block_q),
         lambda bh, kb, qi: (
@@ -545,19 +579,19 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
         ),
         out_shape=(
             jax.ShapeDtypeStruct((BH, L, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, L, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, L, Dv), v.dtype),
         ),
         grid=(BH, L // block_k, L // block_q),
-        in_specs=[q_spec_k, kv_in_spec, kv_in_spec, q_spec_k, row_spec_k,
-                  row_spec_k],
-        out_specs=(k_spec_k, k_spec_k),
+        in_specs=[q_spec_k(D), kv_in_spec(D), kv_in_spec(Dv), q_spec_k(Dv),
+                  row_spec_k, row_spec_k],
+        out_specs=(k_spec_k(D), k_spec_k(Dv)),
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name="flash_bwd_dkv",
+        name=_kernel_name("flash_bwd_dkv", D, Dv),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -573,9 +607,10 @@ def _unfold(a, B, H):
 
 
 def _kv_groups(q, k, v) -> int:
-    if k.shape != v.shape:
+    if k.shape[:-1] != v.shape[:-1] or q.shape[-1] != k.shape[-1]:
         raise ValueError(
-            f"k and v must have identical shapes, got {k.shape} vs {v.shape}"
+            "k and v must have identical shapes up to the head width, and "
+            f"q k's head width; got q {q.shape}, k {k.shape}, v {v.shape}"
         )
     H, Hkv = q.shape[2], k.shape[2]
     if H % Hkv:
@@ -626,7 +661,7 @@ def _flash_core_bwd(res, g):
         # exposes the group axis.
         Hkv = H // groups
         dk = dk.reshape(B, L, Hkv, groups, D).sum(axis=3)
-        dv = dv.reshape(B, L, Hkv, groups, D).sum(axis=3)
+        dv = dv.reshape(B, L, Hkv, groups, -1).sum(axis=3)
     return dq, dk, dv
 
 
@@ -634,7 +669,9 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def flash_self_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Causal flash attention: q [B, L, H, D] in, [B, L, H, D] out.
+    """Causal flash attention: q [B, L, H, D] in, [B, L, H, D] out; with
+    a value head of another width (``v``: [B, L, Hkv, Dv]; latent
+    attention has D = 192, Dv = 128) [B, L, H, Dv] out, scaled ``D^-½``.
 
     Drop-in for ``ops.ring_attention.dense_self_attention`` on contiguous
     (offset-0) sequences — the unsharded model path.  Both directions run

@@ -146,6 +146,22 @@ def lm_loss(model, params, tokens, targets,
     return (loss, sown) if stats else loss
 
 
+def _update(model, state: TrainState, grads):
+    """The optimizer's ``(new_params, new_momentum)``.  Leaves a model names
+    in ``frozen_params`` — buffers it keeps in the parameter tree, such as a
+    router's selection bias (``models/mla_moe.py``) — stay as they are: no
+    update, no weight decay."""
+    new_params, new_momentum = update_fn_for_config(state.config)(
+        state.params, state.momentum, grads, state.config, step=state.step
+    )
+    frozen = getattr(model, "frozen_params", ())
+    if frozen:
+        new_params = jax.tree_util.tree_map_with_path(
+            lambda path, new, old: old if path[-1].key in frozen else new,
+            new_params, state.params)
+    return new_params, new_momentum
+
+
 def _lm_step_impl(model, state: TrainState, tokens, targets, *, axis_names,
                   fused_ce_chunks: int | None = None, guard: bool = False):
     stats = getattr(model, "stats_collection", None) is not None
@@ -159,9 +175,7 @@ def _lm_step_impl(model, state: TrainState, tokens, targets, *, axis_names,
     if axis_names:
         grads = lax.pmean(grads, axis_names)
         loss = lax.pmean(loss, axis_names)
-    new_params, new_momentum = update_fn_for_config(state.config)(
-        state.params, state.momentum, grads, state.config, step=state.step
-    )
+    new_params, new_momentum = _update(model, state, grads)
     new_state = state.replace(
         params=new_params, momentum=new_momentum, step=state.step + 1
     )
@@ -238,9 +252,7 @@ def _lm_scaled_step_impl(model, sstate: DynamicScaleState, tokens, targets,
         lambda g: (g.astype(jnp.float32) / scale).astype(g.dtype), grads
     )
     finite = tree_all_finite(grads)
-    new_params, new_momentum = update_fn_for_config(state.config)(
-        state.params, state.momentum, grads, state.config, step=state.step
-    )
+    new_params, new_momentum = _update(model, state, grads)
     new_inner = guard_update(
         finite,
         state.replace(params=new_params, momentum=new_momentum,
