@@ -3,7 +3,7 @@
 The hot op of the transformer family, written for the hardware per the
 Pallas playbook (/opt/skills/guides/pallas_guide.md): the L×L score
 matrix never hits HBM in either direction, and on-chip memory is
-O(block), not O(L).
+O(block) — plus, in the fused backward, one head's dq.
 
 Forward: grid (batch·heads, Q blocks, K blocks) with the K dimension
 innermost, so Pallas streams one [block_k, D] K/V tile into VMEM per
@@ -16,15 +16,29 @@ not change between grid steps — so causal masking saves both halves of
 the work, not just the FLOPs.  The forward also emits the per-row
 logsumexp — the one O(L) residual the backward needs.
 
-Backward: the standard two-kernel flash-bwd split (no atomics needed —
-each kernel owns its accumulator):
+Backward: ONE kernel, ``flash_bwd_fused``, grid (BH, K blocks, Q blocks)
+with Q innermost.  Per tile it recomputes the score block from Q/K and
+the saved logsumexp (``p = exp2(s − lse)``), forms ``ds = p·(dp − Δ)``
+with ``dp = dO·Vᵀ`` and ``Δ = rowsum(dO ∘ O)`` precomputed outside, and
+feeds all three gradients from that one ``p`` and ``ds``: ``dv += pᵀ·dO``
+and ``dk += dsᵀ·Q`` into [block_k, ·] VMEM accumulators that belong to the
+K block, ``dq += ds·K`` into a float32 copy of the WHOLE head's dq that
+stays in VMEM while the K blocks sweep (8 MiB at L 8192 and a head of 192
+or 256; v5e has 128 MiB) and leaves through an output block that moves
+with the head alone.  Five matmuls and one exp pass a tile.
 
-- **dQ kernel**, grid (BH, Q blocks, K blocks): recomputes each score
-  block from Q/K and the saved logsumexp (``p = exp(s − lse)``), forms
-  ``ds = p·(dp − Δ)`` with ``Δ = rowsum(dO ∘ O)`` precomputed outside,
-  and accumulates ``dq += ds·K`` in VMEM scratch over the K steps.
-- **dK/dV kernel**, grid (BH, K blocks, Q blocks): same recomputation
-  with Q innermost, accumulating ``dv += pᵀ·dO`` and ``dk += dsᵀ·Q``.
+The standard two-kernel split (no atomics needed — each kernel owns its
+accumulator) remains for a head whose dq cannot stay in VMEM
+(``_bwd_fused``: a pure function of L, head widths and dtype):
+
+- **dQ kernel**, grid (BH, Q blocks, K blocks): ``dq += ds·K`` in VMEM
+  scratch over the K steps.
+- **dK/dV kernel**, grid (BH, K blocks, Q blocks): the same ``s``, ``p``,
+  ``dp`` and ``ds`` AGAIN with Q innermost, for ``dv`` and ``dk``.
+
+Seven matmuls and two exp passes a tile: the backward kernels' time
+follows their matmuls (see the measured context below), which is why the
+fused kernel is the one the cells run.
 
 MXU discipline: matmuls run on the INPUT dtype (bf16 in training) with
 ``preferred_element_type=f32`` accumulation — a bf16×bf16→f32 matmul is
@@ -33,7 +47,7 @@ attention runs bf16 too, so anything else loses to dense by
 construction).  The online-softmax state (m, l, acc) stays f32.
 
 Blocks are picked per L from an on-chip sweep: 512×512 squares for
-both kernels (see ``_fwd_blocks``) — large stationary blocks buy
+every kernel (see ``_fwd_blocks``) — large stationary blocks buy
 arithmetic intensity, and the sweep showed the streamed block also
 wants to be large (fewer grid steps, bigger MXU tiles) rather than
 held at MXU width; smaller powers of two engage only when L demands.
@@ -70,15 +84,27 @@ _LANES = 128  # VMEM lane width: m/l scratch is (block_q, _LANES)
 # saved lse therefore live in log2 space; probabilities and outputs are
 # unchanged because exp2((s·log2e) − m2) == exp(s − m).
 #
-# Measured context (8k ablation at constant FLOPs): the exp over the
-# score tile IS the kernel's critical path — per-tile time is ~2.2 µs
-# regardless of head dim, i.e. one exp per score element at the VPU's
-# ~118 Gelem/s transcendental rate, with the MXU work hidden under it.
-# That makes the performed-FLOPs roofline exp-bound at 4·D FLOPs per
-# exp: 30 TF/s at D=64, 60 TF/s at D=128 — this kernel reaches ~90%
-# and ~94% of those ceilings.  (exp2 itself measured neutral vs exp
-# under Mosaic — its exp is already pow2-based — but base-2 keeps the
-# kernel at the floor of what the lowering can emit.)
+# Measured context, per active 512 × 512 tile (a call's device time ÷ its
+# causal tiles; PERF_LEDGER.jsonl, PR 31 ``breakdown``s, and PERF.md §5):
+#
+#   head qk / v   forward   dQ + dK/dV (split)   MXU passes, split → fused
+#   128 / 128     2.46 µs   3.40 µs              3 + 4 = 7   → 5
+#   192 / 128     2.84 µs   2.27 + 2.65 = 4.92   5 + 6 = 11  → 8
+#   256 / 256     2.49 µs   2.52 + 3.18 = 5.70   6 + 8 = 14  → 10
+#
+# (a pass = one 512 × 512 × 128 matmul, 0.34 µs at the chip's 197 TFLOP/s;
+# 192 counts as two).  The FORWARD kernel sits on a vector-unit floor of
+# ≈ 2.45 µs a tile whatever its matmul load (exp, running max and sum,
+# the rescale of the accumulator), 0.4 µs more at the 192-wide tile.  The
+# BACKWARD kernels are bound by their matmuls with the exp hidden: the
+# split's two exp passes at head 128 cost 1.70 µs each, and its time over
+# the three heads is a line of ≈ 0.55 µs a kernel + ≈ 0.33 µs a pass.  So
+# the backward got faster by doing fewer: fused, the same calls take 2.38 /
+# 3.68 / 4.15 µs a tile where the split takes 3.71 / 5.28 / 5.99 (one chip
+# run of both at the cells' shapes, host-timed; PERF.md §6, PR 32), the
+# gradients bit for bit the split's.  (exp2 itself measured neutral vs
+# exp under Mosaic — its exp is already pow2-based — but base-2 keeps the
+# kernels at the floor of what the lowering can emit.)
 LOG2E = 1.4426950408889634
 
 
@@ -145,8 +171,44 @@ def _fwd_blocks(L: int) -> tuple[int, int]:
 
 def _dkv_blocks(L: int) -> tuple[int, int]:
     # Same sweep for the dK/dV kernel: (512,512) gives 301k tok/s vs
-    # 284k for the old (256,512) and 235k for (256,256).
+    # 284k for the old (256,512) and 235k for (256,256).  The fused
+    # backward kernel has this grid and takes these blocks as they are.
     return _pick(L, 512), _pick(L, 512)
+
+
+#: What the fused backward kernel may ask of VMEM: under half of a v5e
+#: core's 128 MiB, so the choice never rests on the last MiB of a chip.
+_FUSED_VMEM_BUDGET = 48 * 2**20
+#: Room for what Mosaic keeps beside the declared buffers: the [block_q,
+#: block_k] float32 score, p, dp and ds tiles and their bf16 copies (1 MiB
+#: each at 512 × 512) and its own scratch.  An AOT compile for v5e passes
+#: with 4 MiB and fails with 2 at head 256, L 8192 (2 and 0 at head 128).
+_TILE_TEMPORARIES = 8 * 2**20
+
+
+def _fused_vmem_bytes(L: int, D: int, Dv: int, dtype) -> int:
+    """VMEM the fused backward kernel needs at these shapes, in bytes —
+    its ``vmem_limit_bytes``.  The resident dq (float32 scratch plus the
+    double-buffered output block, lane-padded), the double-buffered
+    Q/K/V/dO tiles, lse/Δ rows and dk/dv output tiles, the two [block_k,
+    ·] accumulators, and ``_TILE_TEMPORARIES``."""
+    item = jnp.dtype(dtype).itemsize
+    block_q, block_k = _dkv_blocks(L)
+    d, dv = (-(-w // _LANES) * _LANES for w in (D, Dv))
+    resident_dq = L * d * (4 + 2 * item)
+    tiles = 2 * item * (block_q + 2 * block_k) * (d + dv)
+    rows = 2 * 2 * 8 * max(block_q, _LANES) * 4
+    accumulators = 4 * block_k * (d + dv)
+    return resident_dq + tiles + rows + accumulators + _TILE_TEMPORARIES
+
+
+def _bwd_fused(L: int, D: int, Dv: int, dtype) -> bool:
+    """Which backward a call gets, from its shapes alone: the fused
+    kernel (one score tile, one exp pass and five matmuls a tile, the
+    head's dq resident in VMEM) where that fits ``_FUSED_VMEM_BUDGET``;
+    the dQ + dK/dV split (seven matmuls, two exp passes, O(block) VMEM)
+    past it — bf16 heads of 128 from L 65 536, of 192 or 256 from 32 768."""
+    return _fused_vmem_bytes(L, D, Dv, dtype) <= _FUSED_VMEM_BUDGET
 
 
 def _last_kb(qi, block_q: int, block_k: int):
@@ -233,6 +295,22 @@ def _tile_scores(q, k, q_start, k_start, block_q, block_k, scale,
     return _full_scores(q, k, scale)
 
 
+def _rows_dot(a, b):
+    """``a·b`` ([bq, bk]·[bk, d]): the operands in ``b``'s dtype, f32 out."""
+    return jax.lax.dot_general(
+        a.astype(b.dtype), b, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _cols_dot(a, b):
+    """``aᵀ·b`` ([bq, bk]ᵀ·[bq, d]): the operands in ``b``'s dtype, f32 out."""
+    return jax.lax.dot_general(
+        a.astype(b.dtype), b, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _online_update(s, m, l, acc, v, causal: bool):
     """One online-softmax block update of the (m, l, acc) running triple.
     ``s`` fp32 scores [bq, bk] in LOG2 space (pre-scaled by log2e);
@@ -245,10 +323,7 @@ def _online_update(s, m, l, acc, v, causal: bool):
         # (there s == m_new == NEG_INF and the exp above gives 1, not 0).
         p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
     l_new = l * alpha + p.sum(axis=-1)
-    acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_new = acc * alpha[:, None] + _rows_dot(p, v)
     return m_new, l_new, acc_new
 
 
@@ -260,35 +335,36 @@ def _p_from_lse(s, lse, causal: bool):
     return p
 
 
-def _dq_contrib(s, k, v, do, lse, delta, scale, causal: bool):
-    """dq += ds·K for one tile (backward recompute from the saved lse)."""
+def _p_ds(s, v, do, lse, delta, scale, causal: bool):
+    """(p, ds) of one tile from the saved lse: the arithmetic every
+    backward kernel shares — ``p = exp2(s − lse)``, ``dp = dO·Vᵀ``,
+    ``ds = p·(dp − Δ)·scale`` (f32)."""
     p = _p_from_lse(s, lse, causal)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    ds = p * (dp - delta[:, None]) * scale
-    return jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    return p, p * (dp - delta[:, None]) * scale
+
+
+def _dq_contrib(s, k, v, do, lse, delta, scale, causal: bool):
+    """dq += ds·K for one tile (backward recompute from the saved lse)."""
+    _, ds = _p_ds(s, v, do, lse, delta, scale, causal)
+    return _rows_dot(ds, k)
 
 
 def _dkv_contrib(s, q, v, do, lse, delta, scale, causal: bool):
-    """(dv += pᵀ·dO, dk += dsᵀ·Q) for one tile."""
-    p = _p_from_lse(s, lse, causal)
-    dv_c = jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (dp - delta[:, None]) * scale
-    dk_c = jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return dk_c, dv_c
+    """(dk += dsᵀ·Q, dv += pᵀ·dO) for one tile."""
+    p, ds = _p_ds(s, v, do, lse, delta, scale, causal)
+    return _cols_dot(ds, q), _cols_dot(p, do)
+
+
+def _dqkv_contrib(s, q, k, v, do, lse, delta, scale, causal: bool):
+    """All three of one tile from ONE ``p`` and ``ds``: (ds·K, dsᵀ·Q,
+    pᵀ·dO) — what ``_dq_contrib`` and ``_dkv_contrib`` return, for five
+    matmuls and one exp pass where the pair makes seven and two."""
+    p, ds = _p_ds(s, v, do, lse, delta, scale, causal)
+    ds = ds.astype(q.dtype)
+    return _rows_dot(ds, k), _cols_dot(ds, q), _cols_dot(p, do)
 
 
 def _flash_fwd_kernel(
@@ -485,12 +561,106 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
-    """q/lse/delta: [BHq, ...], k: [BHq // kv_groups, L, D], v: [BHq //
-    kv_groups, L, Dv], do: [BHq, L, Dv] → (dq [BHq, L, D], dk [BHq, L,
-    D], dv [BHq, L, Dv] — PER QUERY HEAD; the caller group-sums dk/dv
-    down to the narrow KV heads, one cheap XLA reduction, while the
-    kernels never materialize repeated K/V)."""
+def _flash_bwd_fused_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_acc, dk_acc, dv_acc, *, block_q, block_k, scale,
+):
+    """One (K block, Q block) tile of all three gradients.  ``dk_acc`` /
+    ``dv_acc`` belong to the K block, as in the dK/dV kernel; ``dq_acc``
+    is the WHOLE head's dq in float32, resident across the K sweep, and
+    ``dq_ref`` the head's output block, written back once a head.  Each
+    dq row block is zeroed in the first K column, cast out in the last,
+    and gathers its K blocks in ascending order between the two — the
+    dQ kernel's order of accumulation."""
+    kb = pl.program_id(1)
+    qi = pl.program_id(2)
+    q_start = qi * block_q
+    k_start = kb * block_k
+    rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+
+    @pl.when(kb == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), dq_acc.dtype)
+
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _do_update(causal):
+        q = q_ref[0]
+        k = k_ref[0]
+        s = _tile_scores(q, k, q_start, k_start, block_q, block_k,
+                         scale * LOG2E, causal=causal)
+        dq_c, dk_c, dv_c = _dqkv_contrib(
+            s, q, k, v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+            scale, causal=causal,
+        )
+        dq_acc[rows, :] = dq_acc[rows, :] + dq_c
+        dk_acc[:] = dk_acc[:] + dk_c
+        dv_acc[:] = dv_acc[:] + dv_c
+
+    _dispatch_tiles(_do_update, q_start, k_start, block_q, block_k,
+                    causal=True)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finalize_dkv():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _finalize_dq():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+def _k_major_specs(D: int, Dv: int, block_q: int, block_k: int,
+                   kv_groups: int):
+    """Block specs of a backward kernel on the grid (BH, K blocks, Q
+    blocks), Q innermost: (in_specs for q, k, v, do, lse, Δ; out_specs for
+    dk, dv).  Below-diagonal Q/dO/lse/Δ fetches clamp to the first
+    in-range tile (DMA elision).  K/V input tiles read the narrow heads;
+    the dk/dv OUTPUTS stay per query head (out_specs use bh as-is) —
+    accumulating across a group inside the kernel would serialize the bh
+    grid axis, so the group sum happens outside in XLA instead."""
+
+    def q_spec(d):
+        return pl.BlockSpec(
+            (1, block_q, d),
+            lambda bh, kb, qi: (
+                bh, jnp.maximum(qi, _first_qi(kb, block_q, block_k)), 0
+            ),
+            memory_space=pltpu.VMEM,
+        )
+
+    def kv_in_spec(d):
+        return pl.BlockSpec(
+            (1, block_k, d), lambda bh, kb, qi: (bh // kv_groups, kb, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    def k_out_spec(d):
+        return pl.BlockSpec(
+            (1, block_k, d), lambda bh, kb, qi: (bh, kb, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    row_spec = pl.BlockSpec(
+        (None, 1, block_q),
+        lambda bh, kb, qi: (
+            bh, 0, jnp.maximum(qi, _first_qi(kb, block_q, block_k))
+        ),
+        memory_space=pltpu.VMEM,
+    )
+    return (
+        [q_spec(D), kv_in_spec(D), kv_in_spec(Dv), q_spec(Dv),
+         row_spec, row_spec],
+        (k_out_spec(D), k_out_spec(Dv)),
+    )
+
+
+def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int):
+    """The two-kernel backward (each kernel owns its accumulator): what a
+    head whose dq cannot stay in VMEM takes (``_bwd_fused``)."""
     BH, L, D = q.shape
     Dv = v.shape[-1]
     scale = 1.0 / (D**0.5)
@@ -536,42 +706,9 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
         name=_kernel_name("flash_bwd_dq", D, Dv),
     )(q, k, v, do, lse, delta)
 
-    # dK/dV: K blocks own the accumulators, Q innermost.  Below-diagonal
-    # Q/dO fetches clamp to the first in-range tile (DMA elision).
+    # dK/dV: K blocks own the accumulators, Q innermost.
     block_q, block_k = _dkv_blocks(L)
-
-    def q_spec_k(d):
-        return pl.BlockSpec(
-            (1, block_q, d),
-            lambda bh, kb, qi: (
-                bh, jnp.maximum(qi, _first_qi(kb, block_q, block_k)), 0
-            ),
-            memory_space=pltpu.VMEM,
-        )
-
-    # K/V input tiles read the narrow heads; the dk/dv OUTPUTS stay per
-    # query head (out_specs use bh as-is) — accumulating across a group
-    # inside the kernel would serialize the bh grid axis, so the group
-    # sum happens outside in XLA instead.
-    def kv_in_spec(d):
-        return pl.BlockSpec(
-            (1, block_k, d), lambda bh, kb, qi: (bh // kv_groups, kb, 0),
-            memory_space=pltpu.VMEM,
-        )
-
-    def k_spec_k(d):
-        return pl.BlockSpec(
-            (1, block_k, d), lambda bh, kb, qi: (bh, kb, 0),
-            memory_space=pltpu.VMEM,
-        )
-
-    row_spec_k = pl.BlockSpec(
-        (None, 1, block_q),
-        lambda bh, kb, qi: (
-            bh, 0, jnp.maximum(qi, _first_qi(kb, block_q, block_k))
-        ),
-        memory_space=pltpu.VMEM,
-    )
+    in_specs, out_specs = _k_major_specs(D, Dv, block_q, block_k, kv_groups)
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
@@ -582,9 +719,8 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
             jax.ShapeDtypeStruct((BH, L, Dv), v.dtype),
         ),
         grid=(BH, L // block_k, L // block_q),
-        in_specs=[q_spec_k(D), kv_in_spec(D), kv_in_spec(Dv), q_spec_k(Dv),
-                  row_spec_k, row_spec_k],
-        out_specs=(k_spec_k(D), k_spec_k(Dv)),
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, Dv), jnp.float32),
@@ -594,6 +730,58 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
         name=_kernel_name("flash_bwd_dkv", D, Dv),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
+
+
+def _flash_bwd_fused(q, k, v, do, lse, delta, kv_groups: int):
+    """One kernel for dq, dk and dv: the dK/dV kernel's grid, index maps
+    and accumulators, plus the head's dq resident in VMEM (f32 scratch and
+    a ``(1, L, D)`` output block whose index moves with bh alone)."""
+    BH, L, D = q.shape
+    Dv = v.shape[-1]
+    block_q, block_k = _dkv_blocks(L)
+    in_specs, dkv_specs = _k_major_specs(D, Dv, block_q, block_k, kv_groups)
+    dq_spec = pl.BlockSpec(
+        (1, L, D), lambda bh, kb, qi: (bh, 0, 0), memory_space=pltpu.VMEM
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _flash_bwd_fused_kernel, block_q=block_q, block_k=block_k,
+            scale=1.0 / (D**0.5),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((BH, L, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, L, D), k.dtype),
+            jax.ShapeDtypeStruct((BH, L, Dv), v.dtype),
+        ),
+        grid=(BH, L // block_k, L // block_q),
+        in_specs=in_specs,
+        out_specs=(dq_spec, *dkv_specs),
+        scratch_shapes=[
+            pltpu.VMEM((L, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
+        ],
+        # Both inner axes carry accumulators: dk/dv over Q, dq over K.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_fused_vmem_bytes(L, D, Dv, q.dtype),
+        ),
+        interpret=_interpret(),
+        name=_kernel_name("flash_bwd_fused", D, Dv),
+    )(q, k, v, do, lse, delta)
+
+
+def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
+    """q/lse/delta: [BHq, ...], k: [BHq // kv_groups, L, D], v: [BHq //
+    kv_groups, L, Dv], do: [BHq, L, Dv] → (dq [BHq, L, D], dk [BHq, L,
+    D], dv [BHq, L, Dv] — PER QUERY HEAD; the caller group-sums dk/dv
+    down to the narrow KV heads, one cheap XLA reduction, while the
+    kernels never materialize repeated K/V).  The fused kernel where the
+    head's dq fits VMEM (``_bwd_fused``), else the two-kernel split."""
+    _, L, D = q.shape
+    bwd = (_flash_bwd_fused if _bwd_fused(L, D, v.shape[-1], q.dtype)
+           else _flash_bwd_split)
+    return bwd(q, k, v, do, lse, delta, kv_groups)
 
 
 def _fold(a):
@@ -675,8 +863,8 @@ def flash_self_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
 
     Drop-in for ``ops.ring_attention.dense_self_attention`` on contiguous
     (offset-0) sequences — the unsharded model path.  Both directions run
-    as Pallas kernels (O(block) on-chip memory; the backward recomputes
-    score blocks from the forward's saved logsumexp).
+    as Pallas kernels (the backward recomputes score blocks from the
+    forward's saved logsumexp; one kernel where a head's dq fits VMEM).
 
     Grouped-query attention is native: pass k/v with Hkv < H heads
     (Hkv | H, the ``jnp.repeat``-convention grouping) and the kernels

@@ -1,0 +1,86 @@
+"""AOT Mosaic compiles of the backward flash kernels at the cells' real
+shapes for a described ``v5e:2x2`` device — no chip.
+
+What interpret mode cannot show: that the fused kernel's resident dq, its
+``(1, L, D)`` output block and its ``vmem_limit_bytes`` are legal and fit at
+every head shape a cell runs, and at the largest length the chooser still
+hands it; a VMEM overflow or an illegal block fails here, off-chip.
+
+Written as the ``on-chip-measurement`` guide's section 2 requires: the
+topology is described inside a module-scoped fixture (never at import),
+the file skips from there, nothing starts a child process.  The memory
+fits of whole steps live in ``tests/benchmark_checks/test_benchmark_aot_fit.py``
+(the benchmark's file); where only one process may load the TPU's library
+and that file's worker holds it, this file skips.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tests.test_flash_attention import CELL_SHAPES
+
+#: (B, L, H, Hkv, D, Dv) → which backward the chooser must hand it.
+SHAPES = {
+    **{cell: (shape, "fused") for cell, shape in CELL_SHAPES.items()},
+    # The longest bf16 head of 128 whose dq still fits the budget.
+    "fused_at_the_budget": ((1, 32768, 2, 1, 128, 128), "fused"),
+    "split_past_the_budget": ((1, 65536, 1, 1, 128, 128), "split"),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip to compile for, Mosaic kernels compiled (not
+    interpreted) and the persistent cache off (a described-device entry
+    cannot be read back without a chip, and warns)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from distributed_machine_learning_tpu.ops.pallas import flash_attention
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mp = pytest.MonkeyPatch()
+    mp.setattr(flash_attention, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+@pytest.mark.parametrize("name", SHAPES)
+def test_backward_kernels_compile_for_v5e(one_chip, name):
+    from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
+        _flash_bwd,
+    )
+
+    (B, L, H, Hkv, D, Dv), which = SHAPES[name]
+    groups = H // Hkv
+
+    def arg(heads, width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((B * heads, L, width), dtype,
+                                    sharding=one_chip)
+
+    row = jax.ShapeDtypeStruct((B * H, 1, L), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda *a: _flash_bwd(*a, kv_groups=groups)).lower(
+        arg(H, D), arg(Hkv, D), arg(Hkv, Dv), arg(H, Dv), row, row,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    suffix = "" if D == Dv else f"_qk{D}v{Dv}"
+    kernels = {base: f"flash_bwd_{base}{suffix}" in text
+               for base in ("fused", "dq", "dkv")}
+    assert kernels == {"fused": which == "fused", "dq": which == "split",
+                       "dkv": which == "split"}

@@ -223,3 +223,103 @@ def test_flash_grad_through_training_loss(rng):
                     jax.tree_util.tree_leaves(gd)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-6)
+
+
+# --- The fused backward kernel (dq resident in VMEM) against the
+# --- two-kernel split it replaces where the head's dq fits.
+
+#: (B, L, H, Hkv, D, Dv): L 384 tiles as 3 × 3 blocks of 128, so every dq
+#: row block is revisited by up to three K blocks; L 48 pads to 512.
+FUSED_CASES = {
+    "mha": (2, 384, 2, 2, 16, 16),
+    "gqa4": (1, 384, 4, 1, 16, 16),
+    "qk192v128": (1, 384, 2, 2, 192, 128),
+    "padded": (2, 48, 2, 1, 16, 16),
+}
+
+#: The head shapes the benchmark's cells run, (B, L, H, Hkv, D, Dv).
+CELL_SHAPES = {
+    "sc2_3b_dp_s4096": (2, 4096, 24, 2, 128, 128),
+    "sc2_3b_dp_s1024": (8, 1024, 24, 2, 128, 128),
+    "q3next_a3b_dp_s8192": (1, 8192, 16, 2, 256, 256),
+    "kanana2_a3b_dp_s8192": (1, 8192, 32, 32, 192, 128),
+}
+
+
+def _flash_grads(monkeypatch, fused: bool, q, k, v, g):
+    from distributed_machine_learning_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_bwd_fused", lambda *_: fused)
+    _, vjp = jax.vjp(flash_self_attention, q, k, v)
+    return vjp(g)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_fused_backward_equals_split(monkeypatch, case, dtype):
+    """One kernel owning dq, dk and dv returns what the dQ and dK/dV
+    kernels return: the same tiles, operands and order of accumulation."""
+    B, L, H, Hkv, D, Dv = FUSED_CASES[case]
+    rng = np.random.default_rng(3207)
+    q, k, v, g = (
+        jnp.asarray(rng.standard_normal(shape), dtype)
+        for shape in ((B, L, H, D), (B, L, Hkv, D), (B, L, Hkv, Dv),
+                      (B, L, H, Dv))
+    )
+    fused = _flash_grads(monkeypatch, True, q, k, v, g)
+    split = _flash_grads(monkeypatch, False, q, k, v, g)
+    # float32 round-off of the accumulators; a bf16 output may land one
+    # rounding (2^-8 relative) apart.
+    rtol = 1e-6 if dtype == jnp.float32 else 2.0**-7
+    for got, want, name in zip(fused, split, ("dq", "dk", "dv")):
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=rtol, atol=1e-6, err_msg=f"{case}: {name}",
+        )
+
+
+@pytest.mark.parametrize("shape, dtype, fused", [
+    *((CELL_SHAPES[c][1:2] + CELL_SHAPES[c][4:], jnp.bfloat16, True)
+      for c in CELL_SHAPES),
+    ((8192, 256, 256), jnp.float32, True),
+    ((32768, 128, 128), jnp.bfloat16, True),
+    # The head's dq (f32 scratch + double-buffered output block) cannot
+    # stay in VMEM: the two-kernel split, O(block) on chip.
+    ((65536, 128, 128), jnp.bfloat16, False),
+    ((32768, 192, 128), jnp.bfloat16, False),
+    ((32768, 128, 128), jnp.float32, False),
+])
+def test_backward_choice_is_a_function_of_shape_and_dtype(shape, dtype, fused):
+    from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
+        _FUSED_VMEM_BUDGET,
+        _bwd_fused,
+    )
+
+    assert _bwd_fused(*shape, dtype) is fused
+    assert _FUSED_VMEM_BUDGET <= 64 * 2**20  # well under v5e's 128 MiB
+
+
+@pytest.mark.parametrize("cell", CELL_SHAPES)
+def test_cell_shapes_lower_to_the_fused_kernel(monkeypatch, cell):
+    """The program lowered for a TPU at each cell's attention shapes holds
+    ``flash_bwd_fused*`` (the name a device trace shows) and neither
+    kernel of the split.  Lowering needs no chip and no TPU compiler."""
+    import re
+
+    from distributed_machine_learning_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    B, L, H, Hkv, D, Dv = CELL_SHAPES[cell]
+
+    def loss(q, k, v):
+        return jnp.sum(flash_self_attention(q, k, v).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16)
+            for s in ((B, L, H, D), (B, L, Hkv, D), (B, L, Hkv, Dv))]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    suffix = "" if D == Dv else f"_qk{D}v{Dv}"
+    assert set(re.findall(r"flash_\w+", text)) == {
+        "flash_fwd" + suffix, "flash_bwd_fused" + suffix}
