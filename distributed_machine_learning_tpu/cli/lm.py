@@ -115,7 +115,15 @@ def make_parser():
                         "(models/hybrid_moe.py), 'deepseek_v3' the latent-"
                         "attention MoE with bias-corrected sigmoid routing "
                         "behind leading dense layers (models/mla_moe.py), "
-                        "both under --parallel dp only; anything else the "
+                        "'afmoe' the sliding-window / full gated-attention "
+                        "MoE with sandwich norms whose selection bias the "
+                        "balancing rule moves (models/window_moe.py; the "
+                        "benchmark's cell: --parallel dp --compute-dtype "
+                        "bfloat16 --attn flash --optimizer adamw "
+                        "--fused-ce-chunks 8 --remat --remat-policy block "
+                        "--lr 5e-6 --seq-len 16384 --batch-size 1; its plain "
+                        "reference: benchmark/reference/window_moe_lm.py), "
+                        "all under --parallel dp only; anything else the "
                         "dense TransformerLM")
     p.add_argument("--d-model", dest="d_model", default=256, type=int)
     p.add_argument("--n-layers", dest="n_layers", default=4, type=int)
@@ -307,6 +315,7 @@ def read_model_config(args) -> dict:
 CONFIG_MODELS = {
     "qwen3_next": ("hybrid_moe", "HybridMoELM", "HybridMoESizes"),
     "deepseek_v3": ("mla_moe", "MLAMoELM", "MLAMoESizes"),
+    "afmoe": ("window_moe", "WindowMoELM", "WindowMoESizes"),
 }
 
 

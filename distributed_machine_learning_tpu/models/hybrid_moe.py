@@ -71,6 +71,11 @@ HELD_ROWS_FACTOR = 2.0
 #: attribute on a model returns the collection beside the loss.
 STATS_COLLECTION = "moe_stats"
 
+#: The selection bias in the parameter tree, and what a layer that has its
+#: bias balanced sows for the rule: its assignments a router output.
+SELECTION_BIAS = "e_score_correction_bias"
+ASSIGNMENTS = "assignments"
+
 _INIT = nn.initializers.normal(stddev=0.02)  # the family's initializer_range
 
 
@@ -205,11 +210,14 @@ def _gated_norm(o, z, weight, eps):
 
 class GatedAttention(nn.Module):
     """Softmax attention with an output gate.  ``[q, gate] = x·W_q``,
-    ``k = x·W_k``, ``v = x·W_v``; ``q`` and ``k`` take a zero-centred
-    RMSNorm per head; the first ``rotary_dim`` dimensions of each head are
-    rotated (half-split pairs, base ``rope_base``); causal attention with
-    each key/value head serving ``n_heads / n_kv_heads`` query heads;
-    ``out = (attn ⊙ σ(gate))·W_o``."""
+    ``k = x·W_k``, ``v = x·W_v``; ``q`` and ``k`` take an RMSNorm per head
+    (zero-centred, or plain with ``zero_centred_norm=False``); the first
+    ``rotary_dim`` dimensions of each head are rotated (half-split pairs,
+    base ``rope_base``; ``rotary_dim = 0``: no position encoding at all);
+    causal attention — through a sliding ``window`` of that many keys, the
+    query's own among them, where one is given — with each key/value head
+    serving ``n_heads / n_kv_heads`` query heads; ``out = (attn ⊙
+    σ(gate))·W_o``."""
 
     n_heads: int
     n_kv_heads: int
@@ -219,6 +227,8 @@ class GatedAttention(nn.Module):
     eps: float
     attn_impl: str
     compute_dtype: Any
+    window: int | None = None
+    zero_centred_norm: bool = True
 
     @nn.compact
     def __call__(self, x, positions):
@@ -230,23 +240,31 @@ class GatedAttention(nn.Module):
         gate = q_gate[..., H * dh:]
         k = _dense(Hkv * dh, dt, "k_proj")(x).reshape(B, T, Hkv, dh)
         v = _dense(Hkv * dh, dt, "v_proj")(x).reshape(B, T, Hkv, dh)
-        q = RMSNorm(self.eps, dt, name="q_norm")(q)
-        k = RMSNorm(self.eps, dt, name="k_norm")(k)
-        q = apply_rope(q, positions, self.rope_base, self.rotary_dim)
-        k = apply_rope(k, positions, self.rope_base, self.rotary_dim)
-        with jax.named_scope("attn"):
+        with jax.named_scope("attn.qk"):
+            q = RMSNorm(self.eps, dt, self.zero_centred_norm,
+                        name="q_norm")(q)
+            k = RMSNorm(self.eps, dt, self.zero_centred_norm,
+                        name="k_norm")(k)
+            if self.rotary_dim:
+                q = apply_rope(q, positions, self.rope_base, self.rotary_dim)
+                k = apply_rope(k, positions, self.rope_base, self.rotary_dim)
+        with jax.named_scope(
+                "attn.core.full" if self.window is None else "attn.core.window"):
             if self.attn_impl == "flash":
                 from distributed_machine_learning_tpu.ops.pallas.flash_attention import (  # noqa: E501
                     flash_self_attention,
                 )
 
-                out = flash_self_attention(q, k, v)
+                out = flash_self_attention(q, k, v, window=self.window)
             else:
                 rep = H // Hkv
                 out = dense_self_attention(
-                    q, _repeat_kv(k, rep), _repeat_kv(v, rep), positions)
-        out = _sigmoid_gated(out.reshape(B, T, H * dh), gate)
-        return _dense(D, dt, "o_proj")(out)
+                    q, _repeat_kv(k, rep), _repeat_kv(v, rep), positions,
+                    window=self.window)
+        with jax.named_scope("attn.gate"):
+            out = _sigmoid_gated(out.reshape(B, T, H * dh), gate)
+        with jax.named_scope("attn.out"):
+            return _dense(D, dt, "o_proj")(out)
 
 
 @jax.checkpoint
@@ -273,7 +291,9 @@ class SparseMoE(nn.Module):
     routed_scale · p_e / Σ_{j∈S} p_j`` (no division unless
     ``norm_topk_prob``); expert ``e`` is ``W_down(SiLU(W_gate x) ⊙ W_up x)``;
     ``y = Σ_{e ∈ S ∩ held} p̃_e·E_e(x) + g·E_shared(x)``, ``g = σ(x·w_s)``
-    with ``shared_gate``, else one."""
+    with ``shared_gate``, else one.  With ``balance_bias`` the layer also
+    hands out ``c``, this step's assignments to each of the ``router_width``
+    experts, for the rule that moves ``b`` (:func:`balanced_bias`)."""
 
     router_width: int
     held_experts: tuple  # (first, count)
@@ -286,6 +306,8 @@ class SparseMoE(nn.Module):
     selection_bias: bool = False
     routed_scale: float = 1.0
     shared_gate: bool = True
+    balance_bias: bool = False
+    bias_init: Any = _selection_bias_init
 
     @nn.compact
     def __call__(self, x):
@@ -315,7 +337,7 @@ class SparseMoE(nn.Module):
             probs = (jax.nn.sigmoid(logits) if self.score_func == "sigmoid"
                      else jax.nn.softmax(logits, axis=-1))
             bias = self.param(
-                "e_score_correction_bias", _selection_bias_init,
+                SELECTION_BIAS, self.bias_init,
                 (self.router_width,)) if self.selection_bias else None
             expert_idx, weights = route_topk(
                 probs, k, self.norm_topk_prob, bias=bias,
@@ -323,6 +345,12 @@ class SparseMoE(nn.Module):
             if bias is not None:
                 self.sow(STATS_COLLECTION, "bias_moved",
                          selection_moved_share(probs, expert_idx))
+            if self.balance_bias:
+                self.sow(STATS_COLLECTION, ASSIGNMENTS, jnp.sum(
+                    expert_idx[..., None] == jnp.arange(self.router_width),
+                    axis=(0, 1), dtype=jnp.int32))
+                self.sow(STATS_COLLECTION, "bias_abs",
+                         jnp.mean(jnp.abs(bias)))
         self.sow("moe_routing", "expert_idx", expert_idx)
         # Every assignment there is when all experts are held; else the
         # balanced share times HELD_ROWS_FACTOR, in whole 128-row tiles.
@@ -350,13 +378,27 @@ class SparseMoE(nn.Module):
         return y.reshape(B, T, D)
 
 
+def balanced_bias(bias, assignments, rate: float):
+    """The auxiliary-loss-free balancing rule (Wang et al.,
+    arXiv:2408.15664), once a step: ``b_e ← b_e + rate · sign(mean(c) −
+    c_e)`` with ``c`` the step's assignments to each expert — an expert
+    under the mean load becomes likelier to be picked, one over it less.
+    Not a gradient step: ``b`` picks and never weighs."""
+    c = assignments.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(c) - c)
+
+
 def routing_counts(sown) -> dict:
     """One step's routing counts from what the sparse layers sowed: from
     each layer's ``[rows, max/mean, dropped]`` the assignments computed here
     a layer (the mean over the layers), the fullest held expert over the
-    mean (the worst layer) and the rows dropped (all layers); and, where
-    the layers select with a bias, the share of tokens whose chosen set the
-    bias changed (the mean over the layers)."""
+    mean (the worst layer) and the rows dropped (all layers); where the
+    layers select with a bias, the share of tokens whose chosen set the
+    bias changed (the mean over the layers); where a rule moves that bias,
+    its mean size (over the layers) and how many layers' it moved; and,
+    from a model that sows its attention layers' ``[active, causal,
+    windowed]`` tile counts, active over causal tiles and the windowed
+    calls."""
     by_name: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
         name = [k.key for k in path if hasattr(k, "key")][-1]
@@ -368,6 +410,13 @@ def routing_counts(sown) -> dict:
     if "bias_moved" in by_name:
         counts["moe_bias_moved_share"] = jnp.stack(
             by_name["bias_moved"]).mean()
+    if "bias_abs" in by_name:
+        counts["moe_bias_abs_mean"] = jnp.stack(by_name["bias_abs"]).mean()
+        counts["moe_bias_updates"] = jnp.float32(len(by_name["bias_abs"]))
+    if "attn_tiles" in by_name:
+        active, causal, windowed = jnp.stack(by_name["attn_tiles"]).sum(0)
+        counts["attn_active_tile_share"] = active / causal
+        counts["attn_window_calls"] = windowed
     return counts
 
 

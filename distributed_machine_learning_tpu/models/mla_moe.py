@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from distributed_machine_learning_tpu.models.hybrid_moe import (
     _INIT,
+    SELECTION_BIAS,
     STATS_COLLECTION,
     RMSNorm,
     SparseMoE,
@@ -271,7 +272,7 @@ class MLAMoELM(nn.Module):
     stats_collection = STATS_COLLECTION
     stats_counters = ("moe_held_rows", "moe_dropped_rows")
     step_stats = staticmethod(routing_counts)
-    frozen_params = ("e_score_correction_bias",)
+    frozen_params = (SELECTION_BIAS,)
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False,

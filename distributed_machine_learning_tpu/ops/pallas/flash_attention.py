@@ -1,4 +1,5 @@
-"""Flash attention (causal) as Pallas TPU kernels — forward AND backward.
+"""Flash attention (causal, optionally through a sliding window) as Pallas
+TPU kernels — forward AND backward.
 
 The hot op of the transformer family, written for the hardware per the
 Pallas playbook (/opt/skills/guides/pallas_guide.md): the L×L score
@@ -51,6 +52,16 @@ every kernel (see ``_fwd_blocks``) — large stationary blocks buy
 arithmetic intensity, and the sweep showed the streamed block also
 wants to be large (fewer grid steps, bigger MXU tiles) rather than
 held at MXU width; smaller powers of two engage only when L demands.
+
+A static ``window`` (sliding-window attention: key j visible to query i iff
+``i − window < j ≤ i``) adds a second boundary, the band's lower edge, to
+all of the above: tiles below the band are skipped like those above the
+diagonal — compute, copy and nearly every grid step, because a windowed
+kernel's inner grid axis spans the band's tiles alone — and tiles that the
+edge crosses are masked with both bounds.  The three tile classes are drawn
+where the window-side helpers stand (``_first_kb``); a call's kernels carry
+the window in their names (``flash_fwd_w2048``).  Without a window every
+kernel is the program it was.
 
 Total backward traffic is O(L·D) per tensor plus the recomputed block
 matmuls — the memory profile that lets long-context training fit, where
@@ -221,41 +232,113 @@ def _first_qi(kb, block_q: int, block_k: int):
     return (kb * block_k) // block_q
 
 
-def _tile_classes(q_start, k_start, block_q: int, block_k: int):
-    """(interior, on_diag) predicates for one (Q, K) tile of a causal
-    kernel.  ``interior``: every (q_pos, k_pos) pair satisfies
-    k_pos <= q_pos — the tile needs NO mask.  ``on_diag``: the tile
-    straddles the diagonal and must mask.  Tiles above the diagonal
-    match neither and are skipped entirely."""
+# --- The window.  With ``window = w`` key j is visible to query i iff
+# --- ``i − w < j ≤ i``: a band of width w under the diagonal.  Per
+# --- (Q, K) tile, at L = 8 blocks and a band of 2½ blocks:
+#
+#        K block →  0 1 2 3 4 5 6 7        e  edge: the diagonal or the
+#     Q block 0     e . . . . . . .           band's lower edge crosses the
+#             1     i e . . . . . .           tile; masked with both bounds
+#             2     e i e . . . . .        i  interior: every pair visible,
+#             3     - e i e . . . .           no mask
+#             4     - - e i e . . .        .  above the diagonal: skipped
+#             5     - - - e i e . .        -  below the band: skipped
+#             6     - - - - e i e .
+#             7     - - - - - e i e
+#
+# --- Skipped tiles cost neither compute nor a copy, and almost no grid
+# --- step: a windowed kernel's inner grid axis spans only ``_band_steps``
+# --- tiles (⌈(w − 1)/block⌉ + 1; 5 at w 2048, block 512, against the 32
+# --- of L 16 384) that start at the row's ``_first_kb`` (the column's
+# --- ``_first_qi``), so the only empty steps are those of the first rows,
+# --- whose band is cut by the sequence's start (the last columns', by its
+# --- end): 10 of 160 a head there.  Measured (PERF.md §5): a w 2048 call at
+# --- L 16 384 takes 0.295 (forward) / 0.278 (backward) of the windowless
+# --- call's time for 0.284 of its tiles.
+
+
+def _floor0(x):
+    """``max(x, 0)`` of a Python int (a grid size) or a traced block index
+    (an index map, a kernel)."""
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _first_kb(qi, block_q: int, block_k: int, window: int):
+    """First K block index that a query of Q block qi sees through the
+    window (its first row reaches back furthest)."""
+    return _floor0(qi * block_q - (window - 1)) // block_k
+
+
+def _last_qi(kb, block_q: int, block_k: int, window: int):
+    """Last Q block index that sees a key of K block kb through the window
+    (its last key is seen longest); may lie past the sequence's end."""
+    return ((kb + 1) * block_k - 1 + window - 1) // block_q
+
+
+def _band_steps(n_outer: int, first, last) -> int:
+    """Inner grid steps of a windowed kernel: the most tiles of the band in
+    any row (column), from the static block counts."""
+    return max(last(i) - first(i) + 1 for i in range(n_outer))
+
+
+def active_tiles(L: int, window: int | None = None) -> tuple[int, int]:
+    """(tiles a head's forward kernel computes, tiles of the causal
+    triangle) at the kernels' block sizes: 150 of 528 at L 16 384 and a
+    window of 2048, all of them without one."""
+    L = _padded_len(L) if _needs_pad(L) else L
+    block_q, block_k = _fwd_blocks(L)
+    rows = range(L // block_q)
+    causal = sum(_last_kb(qi, block_q, block_k) + 1 for qi in rows)
+    if window is None or window >= L:
+        return causal, causal
+    return causal - sum(_first_kb(qi, block_q, block_k, window)
+                        for qi in rows), causal
+
+
+def _tile_classes(q_start, k_start, block_q: int, block_k: int,
+                  window: int | None = None):
+    """(interior, edge) predicates for one (Q, K) tile of a causal
+    kernel.  ``interior``: every (q_pos, k_pos) pair is visible — k_pos <=
+    q_pos and, with a window, k_pos > q_pos − window — and the tile needs
+    NO mask.  ``edge``: some pairs are (the tile straddles the diagonal or
+    the band's lower edge) and it must mask.  Tiles above the diagonal or
+    below the band match neither and are skipped entirely."""
     interior = k_start + block_k - 1 <= q_start
     active = k_start <= q_start + block_q - 1
+    if window is not None:
+        interior &= k_start > q_start + block_q - 1 - window
+        active &= k_start + block_k - 1 > q_start - window
     return interior, active & jnp.logical_not(interior)
 
 
 def _dispatch_tiles(do_update, q_start, k_start, block_q: int, block_k: int,
-                    causal: bool):
+                    causal: bool, window: int | None = None, valid=None):
     """Shared tile dispatch for every flash/ring kernel: causal kernels
-    run the mask-free variant on tiles fully below the diagonal (the
-    per-tile iota/compare/select mask is VPU work rivaling the tile's
-    MXU time, and only diagonal-straddling tiles need it), the masked
-    variant on the diagonal, and skip above-diagonal tiles; non-causal
-    kernels run every tile mask-free.  ``do_update(tile_causal)`` is the
-    kernel-specific tile body."""
+    run the mask-free variant on interior tiles (the per-tile
+    iota/compare/select mask is VPU work rivaling the tile's MXU time, and
+    only tiles on the diagonal or the window's edge need it), the masked
+    variant on those, and skip the rest; non-causal kernels run every tile
+    mask-free.  ``do_update(tile_masked)`` is the kernel-specific tile
+    body; ``valid`` (windowed kernels) is false on a grid step whose block
+    index lies past the sequence's end."""
     if not causal:
         do_update(False)
         return
-    interior, on_diag = _tile_classes(q_start, k_start, block_q, block_k)
+    interior, edge = _tile_classes(q_start, k_start, block_q, block_k, window)
+    if valid is not None:
+        interior, edge = interior & valid, edge & valid
 
     @pl.when(interior)
     def _update_full():
         do_update(False)
 
-    @pl.when(on_diag)
+    @pl.when(edge)
     def _update_diag():
         do_update(True)
 
 
-def _block_scores(q, k, q_start, k_start, block_q, block_k, scale):
+def _block_scores(q, k, q_start, k_start, block_q, block_k, scale,
+                  window: int | None = None):
     """Masked scaled scores for one (Q, K) tile — shared fwd/bwd.
 
     The dot runs on the input dtype (bf16 on the training path) with f32
@@ -270,7 +353,10 @@ def _block_scores(q, k, q_start, k_start, block_q, block_k, scale):
     k_pos = k_start + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1
     )
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+    visible = q_pos >= k_pos
+    if window is not None:
+        visible &= k_pos > q_pos - window
+    return jnp.where(visible, s, NEG_INF)
 
 
 def _full_scores(q, k, scale):
@@ -287,11 +373,12 @@ def _full_scores(q, k, scale):
 
 
 def _tile_scores(q, k, q_start, k_start, block_q, block_k, scale,
-                 causal: bool):
+                 causal: bool, window: int | None = None):
     """Scores for one tile; callers on the log2-softmax path pass
     ``scale * LOG2E`` so the downstream exps become exp2."""
     if causal:
-        return _block_scores(q, k, q_start, k_start, block_q, block_k, scale)
+        return _block_scores(q, k, q_start, k_start, block_q, block_k, scale,
+                             window)
     return _full_scores(q, k, scale)
 
 
@@ -369,15 +456,18 @@ def _dqkv_contrib(s, q, k, v, do, lse, delta, scale, causal: bool):
 
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-    *, block_q, block_k, scale,
+    *, block_q, block_k, scale, window=None,
 ):
-    """One (Q block, K block) tile of the online-softmax recurrence."""
+    """One (Q block, K block) tile of the online-softmax recurrence.  With
+    a window the inner grid axis counts the tiles of the row's band."""
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
+    kb = step if window is None else (
+        _first_kb(qi, block_q, block_k, window) + step)
     q_start = qi * block_q
     k_start = kb * block_k
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -388,7 +478,7 @@ def _flash_fwd_kernel(
         k = k_ref[0]  # [block_k, D]
         v = v_ref[0]
         s = _tile_scores(q, k, q_start, k_start, block_q, block_k, scale * LOG2E,
-                         causal=causal)
+                         causal=causal, window=window)
         m_new, l_new, acc_new = _online_update(
             s, m_ref[:, 0], l_ref[:, 0], acc_ref[:], v, causal=causal
         )
@@ -403,9 +493,9 @@ def _flash_fwd_kernel(
     # that rivals the tile's MXU time, and only tiles straddling the
     # diagonal need it.
     _dispatch_tiles(_do_update, q_start, k_start, block_q, block_k,
-                    causal=True)
+                    causal=True, window=window)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:, 0], 1e-30)
         o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
@@ -420,14 +510,34 @@ def _flash_fwd_kernel(
         lse_ref[0] = m_ref[:, 0] + jnp.log2(l)
 
 
-def _kernel_name(base: str, D: int, Dv: int) -> str:
+def _kernel_name(base: str, D: int, Dv: int, window: int | None = None) -> str:
     """The Pallas ``name=`` (the instruction's name in a device trace):
     ``base`` where query/key and value heads are equally wide, else
-    ``base_qk<D>v<Dv>`` so that a trace tells the two kinds of call apart."""
-    return base if D == Dv else f"{base}_qk{D}v{Dv}"
+    ``base_qk<D>v<Dv>``, and ``…_w<window>`` behind either where the call
+    has a window, so that a trace tells the kinds of call apart."""
+    name = base if D == Dv else f"{base}_qk{D}v{Dv}"
+    return name if window is None else f"{name}_w{window}"
 
 
-def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
+def _q_major_grid(L: int, block_q: int, block_k: int, window):
+    """(grid's Q and K extents, the K block of grid step (qi, step)) of a
+    kernel whose Q block is stationary: the forward and the dQ kernel.  K
+    fetches above the diagonal clamp to the diagonal tile: the index
+    repeats, so Pallas skips the copy (causal DMA elision).  With a window
+    the inner axis spans the band alone and starts at its first tile."""
+    n_q, n_k = L // block_q, L // block_k
+    if window is None:
+        return (n_q, n_k), lambda qi, kb: jnp.minimum(
+            kb, _last_kb(qi, block_q, block_k))
+    first = functools.partial(_first_kb, block_q=block_q, block_k=block_k,
+                              window=window)
+    last = functools.partial(_last_kb, block_q=block_q, block_k=block_k)
+    return (n_q, _band_steps(n_q, first, last)), lambda qi, step: jnp.minimum(
+        first(qi) + step, last(qi))
+
+
+def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1,
+               window: int | None = None):
     """q: [BHq, L, D], k: [BHq // kv_groups, L, D], v: [BHq // kv_groups,
     L, Dv] → (out [BHq, L, Dv], lse [BHq, 1, L] fp32 — exact rows, not
     lane-replicated).  ``Dv`` may differ from ``D`` (latent attention: a
@@ -443,9 +553,11 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
     BH, L, D = q.shape
     Dv = v.shape[-1]
     scale = 1.0 / (D**0.5)
-    grid = (BH, L // block_q, L // block_k)
+    extents, k_block = _q_major_grid(L, block_q, block_k, window)
+    grid = (BH, *extents)
     kernel = functools.partial(
-        _flash_fwd_kernel, block_q=block_q, block_k=block_k, scale=scale
+        _flash_fwd_kernel, block_q=block_q, block_k=block_k, scale=scale,
+        window=window,
     )
 
     def q_spec(d):
@@ -454,15 +566,10 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
             memory_space=pltpu.VMEM,
         )
 
-    # Clamp above-diagonal K/V fetches to the diagonal tile: the index
-    # repeats, so Pallas skips the copy (causal DMA elision).
     def k_spec(d):
         return pl.BlockSpec(
             (1, block_k, d),
-            lambda bh, qi, kb: (
-                bh // kv_groups,
-                jnp.minimum(kb, _last_kb(qi, block_q, block_k)), 0
-            ),
+            lambda bh, qi, kb: (bh // kv_groups, k_block(qi, kb), 0),
             memory_space=pltpu.VMEM,
         )
 
@@ -491,20 +598,22 @@ def _flash_fwd(q, k, v, block_q: int, block_k: int, kv_groups: int = 1):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name=_kernel_name("flash_fwd", D, Dv),
+        name=_kernel_name("flash_fwd", D, Dv, window),
     )(q, k, v)
 
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, block_q, block_k, scale,
+    *, block_q, block_k, scale, window=None,
 ):
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
+    kb = step if window is None else (
+        _first_kb(qi, block_q, block_k, window) + step)
     q_start = qi * block_q
     k_start = kb * block_k
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -512,30 +621,42 @@ def _flash_bwd_dq_kernel(
         k = k_ref[0]
         v = v_ref[0]
         s = _tile_scores(q_ref[0], k, q_start, k_start, block_q, block_k,
-                         scale * LOG2E, causal=causal)
+                         scale * LOG2E, causal=causal, window=window)
         dq_acc[:] = dq_acc[:] + _dq_contrib(
             s, k, v, do_ref[0], lse_ref[0], delta_ref[0],
             scale, causal=causal,
         )
 
     _dispatch_tiles(_do_update, q_start, k_start, block_q, block_k,
-                    causal=True)
+                    causal=True, window=window)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
+def _k_major_step(kb, step, block_q: int, block_k: int, window, n_q):
+    """(Q block, whether it exists) of inner grid step ``step`` of K block
+    kb in a kernel whose K block is stationary: the step itself without a
+    window; with one, the band's tiles from the diagonal down, the last
+    columns' running past the sequence's end."""
+    if window is None:
+        return step, None
+    qi = _first_qi(kb, block_q, block_k) + step
+    return qi, qi < n_q
+
+
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, block_q, block_k, scale,
+    dk_acc, dv_acc, *, block_q, block_k, scale, window=None, n_q=None,
 ):
     kb = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi, valid = _k_major_step(kb, step, block_q, block_k, window, n_q)
     q_start = qi * block_q
     k_start = kb * block_k
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -544,7 +665,7 @@ def _flash_bwd_dkv_kernel(
         q = q_ref[0]
         v = v_ref[0]
         s = _tile_scores(q, k_ref[0], q_start, k_start, block_q, block_k,
-                         scale * LOG2E, causal=causal)
+                         scale * LOG2E, causal=causal, window=window)
         dk_c, dv_c = _dkv_contrib(
             s, q, v, do_ref[0], lse_ref[0], delta_ref[0],
             scale, causal=causal,
@@ -553,9 +674,9 @@ def _flash_bwd_dkv_kernel(
         dv_acc[:] = dv_acc[:] + dv_c
 
     _dispatch_tiles(_do_update, q_start, k_start, block_q, block_k,
-                    causal=True)
+                    causal=True, window=window, valid=valid)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -563,7 +684,8 @@ def _flash_bwd_dkv_kernel(
 
 def _flash_bwd_fused_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dq_acc, dk_acc, dv_acc, *, block_q, block_k, scale,
+    dq_acc, dk_acc, dv_acc, *, block_q, block_k, scale, window=None,
+    n_q=None,
 ):
     """One (K block, Q block) tile of all three gradients.  ``dk_acc`` /
     ``dv_acc`` belong to the K block, as in the dK/dV kernel; ``dq_acc``
@@ -571,18 +693,22 @@ def _flash_bwd_fused_kernel(
     ``dq_ref`` the head's output block, written back once a head.  Each
     dq row block is zeroed in the first K column, cast out in the last,
     and gathers its K blocks in ascending order between the two — the
-    dQ kernel's order of accumulation."""
+    dQ kernel's order of accumulation.  With a window a row block's first
+    K column is the band's (``_first_kb``) and its last the diagonal's."""
     kb = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi, valid = _k_major_step(kb, step, block_q, block_k, window, n_q)
     q_start = qi * block_q
     k_start = kb * block_k
     rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+    first_column = kb == 0 if window is None else valid & (
+        kb == _first_kb(qi, block_q, block_k, window))
 
-    @pl.when(kb == 0)
+    @pl.when(first_column)
     def _init_dq():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), dq_acc.dtype)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init_dkv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -591,7 +717,7 @@ def _flash_bwd_fused_kernel(
         q = q_ref[0]
         k = k_ref[0]
         s = _tile_scores(q, k, q_start, k_start, block_q, block_k,
-                         scale * LOG2E, causal=causal)
+                         scale * LOG2E, causal=causal, window=window)
         dq_c, dk_c, dv_c = _dqkv_contrib(
             s, q, k, v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
             scale, causal=causal,
@@ -601,24 +727,46 @@ def _flash_bwd_fused_kernel(
         dv_acc[:] = dv_acc[:] + dv_c
 
     _dispatch_tiles(_do_update, q_start, k_start, block_q, block_k,
-                    causal=True)
+                    causal=True, window=window, valid=valid)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize_dkv():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-    @pl.when(kb == pl.num_programs(1) - 1)
+    last_column = kb == pl.num_programs(1) - 1 if window is None else (
+        valid & (kb == _last_kb(qi, block_q, block_k)))
+
+    @pl.when(last_column)
     def _finalize_dq():
         dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
+def _k_major_grid(L: int, block_q: int, block_k: int, window):
+    """(grid's K and Q extents, the Q block of grid step (kb, step)) of a
+    kernel whose K block is stationary: the dK/dV and the fused kernel.
+    Q/dO/lse/Δ fetches above the diagonal clamp to the first in-range tile
+    (DMA elision); with a window the inner axis spans the band alone, from
+    the diagonal down, and clamps at the band's (or the sequence's) end."""
+    n_q, n_k = L // block_q, L // block_k
+    first = functools.partial(_first_qi, block_q=block_q, block_k=block_k)
+    if window is None:
+        return (n_k, n_q), lambda kb, qi: jnp.maximum(qi, first(kb))
+
+    def last(kb):
+        return _last_qi(kb, block_q, block_k, window)
+
+    steps = _band_steps(n_k, first, lambda kb: min(last(kb), n_q - 1))
+    return (n_k, steps), lambda kb, step: jnp.minimum(
+        first(kb) + step, jnp.minimum(last(kb), n_q - 1))
+
+
 def _k_major_specs(D: int, Dv: int, block_q: int, block_k: int,
-                   kv_groups: int):
+                   kv_groups: int, q_block):
     """Block specs of a backward kernel on the grid (BH, K blocks, Q
     blocks), Q innermost: (in_specs for q, k, v, do, lse, Δ; out_specs for
-    dk, dv).  Below-diagonal Q/dO/lse/Δ fetches clamp to the first
-    in-range tile (DMA elision).  K/V input tiles read the narrow heads;
+    dk, dv); ``q_block`` is ``_k_major_grid``'s.  K/V input tiles read the
+    narrow heads;
     the dk/dv OUTPUTS stay per query head (out_specs use bh as-is) —
     accumulating across a group inside the kernel would serialize the bh
     grid axis, so the group sum happens outside in XLA instead."""
@@ -626,9 +774,7 @@ def _k_major_specs(D: int, Dv: int, block_q: int, block_k: int,
     def q_spec(d):
         return pl.BlockSpec(
             (1, block_q, d),
-            lambda bh, kb, qi: (
-                bh, jnp.maximum(qi, _first_qi(kb, block_q, block_k)), 0
-            ),
+            lambda bh, kb, qi: (bh, q_block(kb, qi), 0),
             memory_space=pltpu.VMEM,
         )
 
@@ -646,9 +792,7 @@ def _k_major_specs(D: int, Dv: int, block_q: int, block_k: int,
 
     row_spec = pl.BlockSpec(
         (None, 1, block_q),
-        lambda bh, kb, qi: (
-            bh, 0, jnp.maximum(qi, _first_qi(kb, block_q, block_k))
-        ),
+        lambda bh, kb, qi: (bh, 0, q_block(kb, qi)),
         memory_space=pltpu.VMEM,
     )
     return (
@@ -658,7 +802,8 @@ def _k_major_specs(D: int, Dv: int, block_q: int, block_k: int,
     )
 
 
-def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int):
+def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int,
+                     window: int | None = None):
     """The two-kernel backward (each kernel owns its accumulator): what a
     head whose dq cannot stay in VMEM takes (``_bwd_fused``)."""
     BH, L, D = q.shape
@@ -666,6 +811,7 @@ def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int):
     scale = 1.0 / (D**0.5)
 
     block_q, block_k = _fwd_blocks(L)  # dQ kernel: Q stationary, like fwd
+    extents, k_block = _q_major_grid(L, block_q, block_k, window)
 
     def q_spec_q(d):
         return pl.BlockSpec(
@@ -676,10 +822,7 @@ def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int):
     def k_spec_q(d):
         return pl.BlockSpec(
             (1, block_k, d),
-            lambda bh, qi, kb: (
-                bh // kv_groups,
-                jnp.minimum(kb, _last_kb(qi, block_q, block_k)), 0
-            ),
+            lambda bh, qi, kb: (bh // kv_groups, k_block(qi, kb), 0),
             memory_space=pltpu.VMEM,
         )
 
@@ -693,32 +836,34 @@ def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int):
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-            scale=scale,
+            scale=scale, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-        grid=(BH, L // block_q, L // block_k),
+        grid=(BH, *extents),
         in_specs=[q_spec_q(D), k_spec_q(D), k_spec_q(Dv), q_spec_q(Dv),
                   row_spec_q, row_spec_q],
         out_specs=q_spec_q(D),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name=_kernel_name("flash_bwd_dq", D, Dv),
+        name=_kernel_name("flash_bwd_dq", D, Dv, window),
     )(q, k, v, do, lse, delta)
 
     # dK/dV: K blocks own the accumulators, Q innermost.
     block_q, block_k = _dkv_blocks(L)
-    in_specs, out_specs = _k_major_specs(D, Dv, block_q, block_k, kv_groups)
+    extents, q_block = _k_major_grid(L, block_q, block_k, window)
+    in_specs, out_specs = _k_major_specs(D, Dv, block_q, block_k, kv_groups,
+                                         q_block)
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-            scale=scale,
+            scale=scale, window=window, n_q=L // block_q,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((BH, L, D), k.dtype),
             jax.ShapeDtypeStruct((BH, L, Dv), v.dtype),
         ),
-        grid=(BH, L // block_k, L // block_q),
+        grid=(BH, *extents),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
@@ -727,33 +872,36 @@ def _flash_bwd_split(q, k, v, do, lse, delta, kv_groups: int):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
-        name=_kernel_name("flash_bwd_dkv", D, Dv),
+        name=_kernel_name("flash_bwd_dkv", D, Dv, window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-def _flash_bwd_fused(q, k, v, do, lse, delta, kv_groups: int):
+def _flash_bwd_fused(q, k, v, do, lse, delta, kv_groups: int,
+                     window: int | None = None):
     """One kernel for dq, dk and dv: the dK/dV kernel's grid, index maps
     and accumulators, plus the head's dq resident in VMEM (f32 scratch and
     a ``(1, L, D)`` output block whose index moves with bh alone)."""
     BH, L, D = q.shape
     Dv = v.shape[-1]
     block_q, block_k = _dkv_blocks(L)
-    in_specs, dkv_specs = _k_major_specs(D, Dv, block_q, block_k, kv_groups)
+    extents, q_block = _k_major_grid(L, block_q, block_k, window)
+    in_specs, dkv_specs = _k_major_specs(D, Dv, block_q, block_k, kv_groups,
+                                         q_block)
     dq_spec = pl.BlockSpec(
         (1, L, D), lambda bh, kb, qi: (bh, 0, 0), memory_space=pltpu.VMEM
     )
     return pl.pallas_call(
         functools.partial(
             _flash_bwd_fused_kernel, block_q=block_q, block_k=block_k,
-            scale=1.0 / (D**0.5),
+            scale=1.0 / (D**0.5), window=window, n_q=L // block_q,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((BH, L, D), q.dtype),
             jax.ShapeDtypeStruct((BH, L, D), k.dtype),
             jax.ShapeDtypeStruct((BH, L, Dv), v.dtype),
         ),
-        grid=(BH, L // block_k, L // block_q),
+        grid=(BH, *extents),
         in_specs=in_specs,
         out_specs=(dq_spec, *dkv_specs),
         scratch_shapes=[
@@ -767,11 +915,12 @@ def _flash_bwd_fused(q, k, v, do, lse, delta, kv_groups: int):
             vmem_limit_bytes=_fused_vmem_bytes(L, D, Dv, q.dtype),
         ),
         interpret=_interpret(),
-        name=_kernel_name("flash_bwd_fused", D, Dv),
+        name=_kernel_name("flash_bwd_fused", D, Dv, window),
     )(q, k, v, do, lse, delta)
 
 
-def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
+def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1,
+               window: int | None = None):
     """q/lse/delta: [BHq, ...], k: [BHq // kv_groups, L, D], v: [BHq //
     kv_groups, L, Dv], do: [BHq, L, Dv] → (dq [BHq, L, D], dk [BHq, L,
     D], dv [BHq, L, Dv] — PER QUERY HEAD; the caller group-sums dk/dv
@@ -781,7 +930,7 @@ def _flash_bwd(q, k, v, do, lse, delta, kv_groups: int = 1):
     _, L, D = q.shape
     bwd = (_flash_bwd_fused if _bwd_fused(L, D, v.shape[-1], q.dtype)
            else _flash_bwd_split)
-    return bwd(q, k, v, do, lse, delta, kv_groups)
+    return bwd(q, k, v, do, lse, delta, kv_groups, window)
 
 
 def _fold(a):
@@ -808,26 +957,22 @@ def _kv_groups(q, k, v) -> int:
     return H // Hkv
 
 
-@jax.custom_vjp
-def _flash_core(q, k, v):
-    B, L, H, D = q.shape
-    bq, bk = _fwd_blocks(L)
-    out, _ = _flash_fwd(
-        _fold(q), _fold(k), _fold(v), bq, bk, kv_groups=_kv_groups(q, k, v)
-    )
-    return _unfold(out, B, H)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_core(q, k, v, window):
+    return _flash_core_fwd(q, k, v, window)[0]
 
 
-def _flash_core_fwd(q, k, v):
+def _flash_core_fwd(q, k, v, window):
     B, L, H, D = q.shape
     bq, bk = _fwd_blocks(L)
     out, lse = _flash_fwd(
-        _fold(q), _fold(k), _fold(v), bq, bk, kv_groups=_kv_groups(q, k, v)
+        _fold(q), _fold(k), _fold(v), bq, bk, kv_groups=_kv_groups(q, k, v),
+        window=window,
     )
     return _unfold(out, B, H), (q, k, v, out, lse)
 
 
-def _flash_core_bwd(res, g):
+def _flash_core_bwd(window, res, g):
     q, k, v, out, lse = res  # out/lse already folded [BH, ...]
     B, L, H, D = q.shape
     groups = _kv_groups(q, k, v)
@@ -838,7 +983,8 @@ def _flash_core_bwd(res, g):
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )[:, None, :]  # [BH, 1, L] — exact, same layout as the saved lse
     dq, dk, dv = _flash_bwd(
-        _fold(q), _fold(k), _fold(v), do, lse, delta, kv_groups=groups
+        _fold(q), _fold(k), _fold(v), do, lse, delta, kv_groups=groups,
+        window=window,
     )
     dq = _unfold(dq, B, H)
     dk = _unfold(dk, B, H)  # [B, L, H, D] — per query head
@@ -856,10 +1002,17 @@ def _flash_core_bwd(res, g):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def flash_self_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+def flash_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                         window: int | None = None) -> jax.Array:
     """Causal flash attention: q [B, L, H, D] in, [B, L, H, D] out; with
     a value head of another width (``v``: [B, L, Hkv, Dv]; latent
     attention has D = 192, Dv = 128) [B, L, H, Dv] out, scaled ``D^-½``.
+
+    ``window`` (static): key j is visible to query i iff ``i − window < j
+    ≤ i`` — sliding-window attention.  Tiles below the band are skipped
+    like those above the diagonal, compute, copy and grid step (the drawing
+    above ``_first_kb``); a window of L or more is the windowless call, bit
+    for bit, and None traces to the program there always was.
 
     Drop-in for ``ops.ring_attention.dense_self_attention`` on contiguous
     (offset-0) sequences — the unsharded model path.  Both directions run
@@ -882,9 +1035,13 @@ def flash_self_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     the custom_vjp, so JAX's pad/slice VJPs route gradients correctly.
     """
     L = q.shape[1]
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window must be at least 1, got {window}")
+        window = None if window >= L else int(window)
     if not _needs_pad(L):
-        return _flash_core(q, k, v)
+        return _flash_core(q, k, v, window)
     pad = ((0, 0), (0, _padded_len(L) - L), (0, 0), (0, 0))
     return _flash_core(
-        jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+        jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad), window
     )[:, :L]

@@ -119,17 +119,21 @@ def dense_self_attention(
     k: jax.Array,
     v: jax.Array,
     positions: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Single-device exact causal attention — the ring op's reference
     semantics (and the attention used when the model runs unsharded).
 
-    [B, L, H, D] in, [B, L, H, D] out.
+    [B, L, H, D] in, [B, L, H, D] out.  ``window``: key j is visible to
+    query i iff ``i − window < j ≤ i`` (sliding-window attention).
     """
     B, L, H, D = q.shape
     if positions is None:
         positions = jnp.arange(L)
     s = _block_scores(q, k, 1.0 / (D**0.5))
     causal = positions[:, None] >= positions[None, :]
+    if window is not None:
+        causal &= positions[None, :] > positions[:, None] - window
     s = jnp.where(causal[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum(

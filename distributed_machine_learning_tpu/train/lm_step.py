@@ -146,11 +146,15 @@ def lm_loss(model, params, tokens, targets,
     return (loss, sown) if stats else loss
 
 
-def _update(model, state: TrainState, grads):
+def _update(model, state: TrainState, grads, sown=None, axis_names=()):
     """The optimizer's ``(new_params, new_momentum)``.  Leaves a model names
     in ``frozen_params`` — buffers it keeps in the parameter tree, such as a
     router's selection bias (``models/mla_moe.py``) — stay as they are: no
-    update, no weight decay."""
+    update, no weight decay.  Then the model's ``param_rules`` ``{leaf name:
+    (sown name, rule)}``: such a leaf becomes ``rule(leaf, what its module
+    sowed under that name this step, summed over the mesh)`` — an update
+    that is no gradient's, such as the balancing rule that moves a selection
+    bias by the step's assignment counts (``models/window_moe.py``)."""
     new_params, new_momentum = update_fn_for_config(state.config)(
         state.params, state.momentum, grads, state.config, step=state.step
     )
@@ -159,6 +163,28 @@ def _update(model, state: TrainState, grads):
         new_params = jax.tree_util.tree_map_with_path(
             lambda path, new, old: old if path[-1].key in frozen else new,
             new_params, state.params)
+    rules = getattr(model, "param_rules", None)
+    if rules:
+        if sown is None:
+            raise ValueError(
+                f"{type(model).__name__} moves {sorted(rules)} by what its "
+                "layers count in a step: it needs the step that returns "
+                "the counts (not the loss-scaled one)")
+
+        def ruled(path, leaf):
+            if path[-1].key not in rules:
+                return leaf
+            sown_name, rule = rules[path[-1].key]
+            counted = sown
+            for key in path[:-1]:
+                counted = counted[key.key]
+            counted = counted[sown_name][0]
+            if axis_names:
+                counted = lax.psum(counted, axis_names)
+            return rule(leaf, counted)
+
+        with jax.named_scope("moe.balance"):
+            new_params = jax.tree_util.tree_map_with_path(ruled, new_params)
     return new_params, new_momentum
 
 
@@ -175,7 +201,7 @@ def _lm_step_impl(model, state: TrainState, tokens, targets, *, axis_names,
     if axis_names:
         grads = lax.pmean(grads, axis_names)
         loss = lax.pmean(loss, axis_names)
-    new_params, new_momentum = _update(model, state, grads)
+    new_params, new_momentum = _update(model, state, grads, sown, axis_names)
     new_state = state.replace(
         params=new_params, momentum=new_momentum, step=state.step + 1
     )

@@ -1,5 +1,6 @@
 """AOT Mosaic compiles of the backward flash kernels at the cells' real
-shapes for a described ``v5e:2x2`` device — no chip.
+shapes, and of the windowed kernels, forward and backward, at the window
+cell's, for a described ``v5e:2x2`` device — no chip.
 
 What interpret mode cannot show: that the fused kernel's resident dq, its
 ``(1, L, D)`` output block and its ``vmem_limit_bytes`` are legal and fit at
@@ -84,3 +85,43 @@ def test_backward_kernels_compile_for_v5e(one_chip, name):
                for base in ("fused", "dq", "dkv")}
     assert kernels == {"fused": which == "fused", "dq": which == "split",
                        "dkv": which == "split"}
+
+
+#: (L, H, Hkv, D, window, fused backward?): the window cell's two attention
+#: shapes, and the windowed dQ + dK/dV split (no cell's shape reaches it: the
+#: chooser is overridden) at a length whose band is cut at both ends.
+WINDOW_SHAPES = {
+    "trinity_window_layer": (16384, 32, 4, 128, 2048, True),
+    "trinity_full_layer": (16384, 32, 4, 128, None, True),
+    "windowed_split": (4096, 8, 1, 128, 1024, False),
+}
+
+
+@pytest.mark.parametrize("name", WINDOW_SHAPES)
+def test_windowed_kernels_compile_for_v5e(one_chip, monkeypatch, name):
+    """Forward and backward through ``flash_self_attention``: the band's
+    grids, the index maps clamped on both sides and the ``_w<window>`` names
+    pass Mosaic, and the fused kernel's VMEM fits at L 16 384."""
+    import re
+
+    from distributed_machine_learning_tpu.ops.pallas import flash_attention
+
+    L, H, Hkv, D, window, fused = WINDOW_SHAPES[name]
+    if not fused:
+        monkeypatch.setattr(flash_attention, "_bwd_fused", lambda *_: False)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention.flash_self_attention(
+            q, k, v, window=window).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((1, L, h, D), jnp.bfloat16,
+                                 sharding=one_chip) for h in (H, Hkv, Hkv)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    suffix = "" if window is None else f"_w{window}"
+    backward = ({"flash_bwd_fused"} if fused
+                else {"flash_bwd_dq", "flash_bwd_dkv"})
+    kernels = re.findall(
+        r"(?<![A-Za-z_])flash_(?:fwd|bwd_[a-z]+)(?:_w\d+)?", text)
+    assert set(kernels) == {
+        base + suffix for base in {"flash_fwd"} | backward}

@@ -243,6 +243,8 @@ CELL_SHAPES = {
     "sc2_3b_dp_s1024": (8, 1024, 24, 2, 128, 128),
     "q3next_a3b_dp_s8192": (1, 8192, 16, 2, 256, 256),
     "kanana2_a3b_dp_s8192": (1, 8192, 32, 32, 192, 128),
+    # its full-attention layer; the window layers: tests/test_window_moe.py
+    "trinity_mini_dp_s16384": (1, 16384, 32, 4, 128, 128),
 }
 
 
