@@ -263,16 +263,20 @@ def make_parser():
                         "'flash', 3d and flat fsdp resolve 'auto' to "
                         "dense, ulysses owns its attention")
     p.add_argument("--remat", action="store_true",
-                   help="jax.checkpoint each transformer block: activation "
-                        "memory drops ~n_layers-fold for ~33%% more FLOPs "
-                        "— the long-context enabler (models/transformer.py)")
+                   help="recompute activations in the backward pass, as "
+                        "--remat-policy says: activation memory drops up "
+                        "to ~n_layers-fold for up to ~33%% more FLOPs — "
+                        "the long-context enabler (models/transformer.py)")
     p.add_argument("--remat-policy", dest="remat_policy", default="mlp",
                    choices=["mlp", "block"],
                    help="with --remat: 'mlp' checkpoints only the LN2+MLP "
                         "sub-layer (attention residuals incl. flash "
                         "out+lse stay saved — backward never re-runs the "
                         "O(L^2) attention forward); 'block' is whole-block "
-                        "remat, the maximal-memory-savings fallback")
+                        "remat, the maximal-memory-savings fallback: "
+                        "everything of the block is made again in backward "
+                        "except the flash kernel's out+lse, kept beside the "
+                        "block's input (nothing more on the dense path)")
     return p
 
 

@@ -53,6 +53,7 @@ from jax import lax
 from distributed_machine_learning_tpu.models.transformer import (
     _repeat_kv,
     apply_rope,
+    remat_whole_block,
 )
 from distributed_machine_learning_tpu.ops.delta_rule import gated_delta_rule
 from distributed_machine_learning_tpu.ops.grouped import (
@@ -538,7 +539,10 @@ class HybridMoELM(nn.Module):
     """Causal LM: tokens [B, L] → logits [B, L, vocab] (module docstring).
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``TransformerLM``'s (``"mlp"``: norm 2 +
-    MoE recomputed in the backward pass; ``"block"``: the whole block)."""
+    MoE recomputed in the backward pass; ``"block"``: the whole block, but
+    for the flash kernel's ``(out, lse)``, which a full-attention layer on
+    the kernel keeps — ``models/transformer.py::whole_block_policy``; a
+    DeltaNet layer keeps its input alone)."""
 
     sizes: HybridMoESizes
     attn_impl: str = "dense"
@@ -572,7 +576,8 @@ class HybridMoELM(nn.Module):
         x = nn.Embed(m.vocab_size, m.d_model, dtype=dt,
                      embedding_init=_INIT, name="embed")(tokens)
         whole_block = self.remat and self.remat_policy == "block"
-        block_cls = nn.remat(HybridBlock) if whole_block else HybridBlock
+        block_cls = (remat_whole_block(HybridBlock) if whole_block
+                     else HybridBlock)
         for i in range(m.n_layers):
             x = block_cls(
                 sizes=m,

@@ -47,7 +47,10 @@ from distributed_machine_learning_tpu.models.hybrid_moe import (
     _dense,
     routing_counts,
 )
-from distributed_machine_learning_tpu.models.transformer import apply_rope
+from distributed_machine_learning_tpu.models.transformer import (
+    apply_rope,
+    remat_whole_block,
+)
 from distributed_machine_learning_tpu.ops.ring_attention import (
     dense_self_attention,
 )
@@ -259,7 +262,8 @@ class MLAMoELM(nn.Module):
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``HybridMoELM``'s (``"mlp"``: norm 2 +
     feed-forward recomputed in the backward pass; ``"block"``: the whole
-    block)."""
+    block but for the flash kernel's ``(out, lse)``, kept where the kernel
+    runs — ``models/transformer.py::whole_block_policy``)."""
 
     sizes: MLAMoESizes
     attn_impl: str = "dense"
@@ -291,7 +295,8 @@ class MLAMoELM(nn.Module):
         x = nn.Embed(m.vocab_size, m.d_model, dtype=dt,
                      embedding_init=_INIT, name="embed")(tokens)
         whole_block = self.remat and self.remat_policy == "block"
-        block_cls = nn.remat(MLABlock) if whole_block else MLABlock
+        block_cls = (remat_whole_block(MLABlock) if whole_block
+                     else MLABlock)
         for i in range(m.n_layers):
             x = block_cls(
                 sizes=m, dense=i < m.first_k_dense,

@@ -659,6 +659,30 @@ def _mlp_sublayer(mdl: "Block", h: jax.Array) -> jax.Array:
     return h
 
 
+def whole_block_policy():
+    """What whole-block recomputation keeps beside the block's input: the
+    flash kernel's two tagged outputs (``FLASH_RESIDUAL_NAMES``: ``out``,
+    bf16 ``[B·H, L, d_v]``, and the float32 ``lse`` ``[B·H, 1, L]``), so
+    the backward pass makes the block again without running the O(L²)
+    forward kernel a second time.  The tags exist only where that kernel
+    runs: a block on the dense attention path has no tagged value, keeps
+    nothing and is the program it was.  What it costs in bytes a layer:
+    ``Block``'s docstring (Trinity-Mini at 16 384 tokens: 194 MiB where
+    the input alone is 64; StarCoder2-3B at 4096: 48 MiB where 24)."""
+    from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
+        FLASH_RESIDUAL_NAMES,
+    )
+
+    return jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUAL_NAMES)
+
+
+def remat_whole_block(block_cls):
+    """``remat_policy="block"`` of every LM here: ``block_cls`` lifted
+    through ``nn.remat`` under :func:`whole_block_policy`."""
+    return nn.remat(block_cls, policy=whole_block_policy())
+
+
 class Block(nn.Module):
     """Pre-LN transformer block.  ``mlp_factory`` swaps the feed-forward
     sub-layer (e.g. for a routed MoE MLP — ``models/moe.py``) while the
@@ -668,11 +692,14 @@ class Block(nn.Module):
     sub-layer is checkpointed; the attention path's residuals — including
     the flash kernel's saved ``(out, lse)`` (O(L·D), cheap) — stay
     resident, so the backward pass never re-runs the O(L²) attention
-    forward.  Whole-block remat re-runs everything (flash forward
-    included) in backward, about a third more work than the model's
-    FLOPs at long context; this policy converts most of that recompute
-    back into real tokens/sec at the cost of ~6·L·E saved activation
-    bytes per layer instead of ~1·L·E."""
+    forward, at ~6·L·E saved activation bytes per layer.  Whole-block
+    remat (``remat_whole_block``) makes everything of the block again in
+    backward EXCEPT that same pair: the norms, projections, rotation and
+    the feed-forward run a second time (O(L) each), the flash forward
+    kernel does not, at ``L·(E + H·d_v)·2 + 4·H·L`` saved bytes per layer
+    — the block's input, the kernel's ``out`` and its float32 ``lse`` —
+    where a block that kept its input alone held ``L·E·2``.  On the dense
+    attention path nothing is tagged and the input alone is kept."""
 
     n_heads: int
     d_ff: int
@@ -788,7 +815,10 @@ class TransformerLM(nn.Module):
     #     out+lse) stay saved, so backward never re-runs the O(L²)
     #     attention forward.  ~6·L·E saved bytes/layer.
     #   "block" — whole-block jax.checkpoint (the maximal-savings
-    #     fallback, ~1·L·E bytes/layer): use when "mlp" does not fit.
+    #     fallback): everything of the block is made again in backward
+    #     except the flash kernel's out+lse, kept beside the block's
+    #     input (~2·L·E bytes/layer with the kernel, ~1·L·E on the dense
+    #     path: whole_block_policy).  Use when "mlp" does not fit.
     remat_policy: str = "mlp"
     # What a configuration file states beside the sizes (cli.lm
     # --model-config: rope_theta, norm_epsilon); the defaults are the
@@ -860,7 +890,7 @@ class TransformerLM(nn.Module):
             )
         rematting = self.remat and not self.decode
         whole_block = rematting and self.remat_policy == "block"
-        block_cls = nn.remat(Block) if whole_block else Block
+        block_cls = remat_whole_block(Block) if whole_block else Block
         remat_mlp = rematting and self.remat_policy == "mlp"
         for i in range(self.n_layers):
             x = block_cls(

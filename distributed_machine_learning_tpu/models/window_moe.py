@@ -58,6 +58,9 @@ from distributed_machine_learning_tpu.models.hybrid_moe import (
     routing_counts,
 )
 from distributed_machine_learning_tpu.models.mla_moe import DenseMLP
+from distributed_machine_learning_tpu.models.transformer import (
+    remat_whole_block,
+)
 
 LAYER_TYPES = ("sliding_attention", "full_attention")
 
@@ -214,7 +217,8 @@ class WindowMoELM(nn.Module):
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``HybridMoELM``'s (``"mlp"``: norm 3,
     the feed-forward and norm 4 recomputed in the backward pass; ``"block"``:
-    the whole block)."""
+    the whole block but for the flash kernel's ``(out, lse)``, kept where
+    the kernel runs — ``models/transformer.py::whole_block_policy``)."""
 
     sizes: WindowMoESizes
     attn_impl: str = "dense"
@@ -259,7 +263,8 @@ class WindowMoELM(nn.Module):
         if m.embed_scale != 1.0:  # in float32: √2048 is no bf16 number
             x = (x.astype(jnp.float32) * m.embed_scale).astype(dt)
         whole_block = self.remat and self.remat_policy == "block"
-        block_cls = nn.remat(WindowMoEBlock) if whole_block else WindowMoEBlock
+        block_cls = (remat_whole_block(WindowMoEBlock) if whole_block
+                     else WindowMoEBlock)
         for i, layer_type in enumerate(m.layer_types):
             x = block_cls(
                 sizes=m, layer_type=layer_type, dense=i < m.n_dense,

@@ -16,6 +16,8 @@ the TPU AOT target name the ops identically):
 - :func:`all_reduces_from_hlo`, :func:`grad_sync_bytes` — every
   all-reduce of a train step, its bytes and whether it is asynchronous
   (``train/lm_step.py``'s two ``grad_sync_*`` gauges).
+- :func:`flash_calls_from_hlo` — the flash-attention kernel calls the
+  compiler kept, forward and backward (its ``flash_*_calls`` gauges).
 
 A schedule is not a device timeline: these prove what the executable
 *orders* under a collective, while what is really hidden is a chip's
@@ -334,3 +336,29 @@ def grad_sync_bytes(rows: list[dict]) -> dict:
         "grad_sync_bytes": sum(r["bytes"] for r in rows),
         "grad_sync_async_bytes": sum(r["bytes"] for r in rows if r["async"]),
     }
+
+
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+# ``op_name="jit(step)/…/transpose(jvp(flash_bwd_fused_w2048))/pallas_call"``:
+# a Pallas kernel's ``name=`` is the scope its call sits in, inside whatever
+# transformations traced it.
+_PALLAS_SCOPE_RE = re.compile(r'op_name="(?:[^"]*/)?([^"/]+)/pallas_call"')
+
+
+def flash_calls_from_hlo(hlo_text: str) -> dict:
+    """``{"flash_fwd_calls", "flash_bwd_calls"}``: the module's Mosaic
+    custom calls whose kernel name starts with ``flash_fwd`` / ``flash_bwd``
+    (``ops/pallas/flash_attention.py``; a split backward counts its two
+    kernels).  What the compiler kept, not what was asked for: a block
+    recomputed without the kernel's ``(out, lse)`` shows each layer's
+    forward call twice.  Counted in the text — a call in a loop's body is
+    one; off the TPU the kernels are interpreted and both read 0."""
+    calls = {"flash_fwd_calls": 0, "flash_bwd_calls": 0}
+    for line in hlo_text.splitlines():
+        scope = _MOSAIC_CALL in line and _PALLAS_SCOPE_RE.search(line)
+        if not scope:
+            continue
+        kernel = re.sub(r"\w+\(|\)", "", scope.group(1))
+        for kind in ("fwd", "bwd"):
+            calls[f"flash_{kind}_calls"] += kernel.startswith(f"flash_{kind}")
+    return calls

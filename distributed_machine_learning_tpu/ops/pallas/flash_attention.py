@@ -77,6 +77,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 # Interpret-mode selection is the shared knob of ops/pallas/common.py
@@ -89,6 +90,10 @@ from distributed_machine_learning_tpu.ops.pallas.common import (
 )
 
 NEG_INF = -1e30
+#: ``jax.ad_checkpoint.checkpoint_name`` tags of the forward kernel's two
+#: outputs, the O(L²) part of the backward's residuals at O(L) bytes: what a
+#: recomputed block keeps (``models/transformer.py::whole_block_policy``).
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 _LANES = 128  # VMEM lane width: m/l scratch is (block_q, _LANES)
 # The kernels run the softmax in BASE 2: scores are pre-scaled by
 # log2(e) so every exp becomes a bare exp2.  m, l's log-offset, and the
@@ -969,6 +974,9 @@ def _flash_core_fwd(q, k, v, window):
         _fold(q), _fold(k), _fold(v), bq, bk, kv_groups=_kv_groups(q, k, v),
         window=window,
     )
+    # The identity outside a ``jax.checkpoint``; q, k, v carry no tag (a
+    # norm, a projection and the rotation make them again, O(L)).
+    out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUAL_NAMES)
     return _unfold(out, B, H), (q, k, v, out, lse)
 
 

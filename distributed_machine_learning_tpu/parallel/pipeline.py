@@ -45,7 +45,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributed_machine_learning_tpu.models.transformer import Block, TransformerLM
+from distributed_machine_learning_tpu.models.transformer import (
+    Block,
+    TransformerLM,
+    whole_block_policy,
+)
 from distributed_machine_learning_tpu.train.losses import lm_cross_entropy
 from distributed_machine_learning_tpu.train.optimizers import (
     moment_layout as _moment_layout,
@@ -141,7 +145,9 @@ def _apply_local_span(block: Block, stacked_local, x, positions,
                       remat: bool = False):
     """Run this device's span of layers over x via lax.scan.
 
-    ``remat=True`` wraps each layer application in ``jax.checkpoint``:
+    ``remat=True`` wraps each layer application in ``jax.checkpoint``
+    under the LMs' whole-block policy (``whole_block_policy``: the flash
+    kernel's ``(out, lse)`` are kept, everything else is made again):
     the backward pipeline then recomputes block activations instead of
     holding every (tick × layer) activation live — the memory term that
     otherwise scales with microbatch count under grad-of-scan."""
@@ -150,7 +156,7 @@ def _apply_local_span(block: Block, stacked_local, x, positions,
         return block.apply({"params": layer_params}, h, positions)
 
     if remat:
-        apply_layer = jax.checkpoint(apply_layer)
+        apply_layer = jax.checkpoint(apply_layer, policy=whole_block_policy())
 
     def body(h, layer_params):
         return apply_layer(layer_params, h), None

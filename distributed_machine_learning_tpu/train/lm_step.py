@@ -357,10 +357,12 @@ class _StepWithSyncGauges:
     """``step(state, tokens, targets)`` that, at its first call under an
     installed ``Telemetry``, reads the compiled step's own HLO text once
     and writes the gauges ``grad_sync_bytes`` (bytes a chip all-reduces a
-    step: the gradients, the loss, a model's counts) and
+    step: the gradients, the loss, a model's counts),
     ``grad_sync_async_bytes`` (those that go through asynchronous
-    collectives).  Getting at the text compiles the step a second time
-    (the persistent cache serves it), so nothing is read without a
+    collectives) and ``flash_fwd_calls`` / ``flash_bwd_calls`` (the flash
+    kernel calls the compiler kept: equal unless a recomputed block runs a
+    forward kernel twice).  Getting at the text compiles the step a second
+    time (the persistent cache serves it), so nothing is read without a
     ``Telemetry``: an unobserved run pays one pointer test a step."""
 
     def __init__(self, jitted):
@@ -379,12 +381,15 @@ class _StepWithSyncGauges:
     def _publish(self, *args) -> None:
         from distributed_machine_learning_tpu.ops.hlo import (
             all_reduces_from_hlo,
+            flash_calls_from_hlo,
             grad_sync_bytes,
         )
 
         text = self._jitted.lower(*args).compile().as_text()
         registry = self._get_telemetry().registry
-        for name, value in grad_sync_bytes(all_reduces_from_hlo(text)).items():
+        gauges = {**grad_sync_bytes(all_reduces_from_hlo(text)),
+                  **flash_calls_from_hlo(text)}
+        for name, value in gauges.items():
             registry.gauge(name).set(value)
 
     def __getattr__(self, name):  # lower, trace, ...: the jitted step's own

@@ -1,6 +1,7 @@
 """AOT Mosaic compiles of the backward flash kernels at the cells' real
-shapes, and of the windowed kernels, forward and backward, at the window
-cell's, for a described ``v5e:2x2`` device — no chip.
+shapes, of the windowed kernels, forward and backward, at the window
+cell's, and of a small LM step whose flash calls the ``flash_*_calls``
+gauges count, for a described ``v5e:2x2`` device — no chip.
 
 What interpret mode cannot show: that the fused kernel's resident dq, its
 ``(1, L, D)`` output block and its ``vmem_limit_bytes`` are legal and fit at
@@ -36,8 +37,8 @@ SHAPES = {
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip to compile for, Mosaic kernels compiled (not
+def v5e():
+    """A described v5e:2x2 to compile for, Mosaic kernels compiled (not
     interpreted) and the persistent cache off (a described-device entry
     cannot be read back without a chip, and warns)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -56,10 +57,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
     mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.mark.parametrize("name", SHAPES)
@@ -125,3 +131,48 @@ def test_windowed_kernels_compile_for_v5e(one_chip, monkeypatch, name):
         r"(?<![A-Za-z_])flash_(?:fwd|bwd_[a-z]+)(?:_w\d+)?", text)
     assert set(kernels) == {
         base + suffix for base in {"flash_fwd"} | backward}
+
+
+@pytest.mark.parametrize("remat", [None, "mlp", "block"], ids=str)
+def test_a_compiled_step_publishes_its_flash_calls(v5e, remat, tmp_path):
+    """``flash_fwd_calls`` == ``flash_bwd_calls`` == the layers that run the
+    kernel, whatever is recomputed: what the compiler kept of a four-chip
+    data-parallel step, read from its HLO text under an installed
+    ``Telemetry`` (the kernels are Mosaic calls only in a program compiled
+    for the TPU; nothing here runs)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_machine_learning_tpu.models.transformer import (
+        TransformerLM,
+    )
+    from distributed_machine_learning_tpu.telemetry import (
+        Telemetry,
+        set_telemetry,
+    )
+    from distributed_machine_learning_tpu.train import lm_step
+
+    layers, seq = 3, 512
+    model = TransformerLM(
+        vocab_size=256, d_model=256, n_layers=layers, n_heads=2,
+        n_kv_heads=1, attn_impl="flash", compute_dtype=jnp.bfloat16,
+        remat=remat is not None, remat_policy=remat or "mlp")
+    mesh = Mesh(np.array(v5e.devices).reshape(4, 1), ("batch", "seq"))
+    rep = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: lm_step.init_lm_state(model)))
+    tokens = jax.ShapeDtypeStruct(
+        (4, seq), jnp.int32, sharding=NamedSharding(mesh, P("batch", "seq")))
+    step = lm_step.make_lm_train_step(model, mesh=mesh)
+    telemetry = Telemetry(str(tmp_path), fsync=False)
+    previous = set_telemetry(telemetry)
+    try:
+        step._publish(state, tokens, tokens)
+    finally:
+        set_telemetry(previous)
+        telemetry.close()
+    gauges = {g["name"]: g["value"]
+              for g in telemetry.registry.snapshot()["gauges"]}
+    assert gauges["flash_fwd_calls"] == gauges["flash_bwd_calls"] == layers
+    assert gauges["grad_sync_bytes"] > 0

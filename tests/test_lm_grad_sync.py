@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from distributed_machine_learning_tpu.ops.hlo import (
     all_reduces_from_hlo,
+    flash_calls_from_hlo,
     grad_sync_bytes,
 )
 from distributed_machine_learning_tpu.models import hybrid_moe as hm
@@ -345,6 +346,45 @@ def test_walker_places_a_loop_bodys_all_reduce_nowhere_in_the_schedule():
         "  ROOT %mul = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n",
         others))
     assert (row["bytes"], row["async"], row["position"]) == (1024, False, None)
+
+
+def _mosaic_call(name: str, scope: str) -> str:
+    """A Pallas kernel as XLA:TPU spells it: the kernel's ``name=`` is the
+    scope of its call, inside whatever transformations traced it."""
+    return (f"  %{name} = (bf16[4,1024,128]{{2,1,0:T(8,128)(2,1)}}, "
+            "f32[4,1,1024]{2,1,0:T(1,128)}) custom-call(%p0, %p0, %p0), "
+            'custom_call_target="tpu_custom_call", '
+            "frontend_attributes={kernel_metadata={}}, "
+            f'metadata={{op_name="jit(step)/jit(shmap_body)/{scope}/'
+            'pallas_call" stack_frame_id=11}, '
+            'backend_config={"custom_call_config":{"body":"TUzvUg"}}\n')
+
+
+@pytest.mark.parametrize("scopes, expected", [
+    # one full and one window layer, nothing recomputed
+    (["jvp(flash_fwd)", "jvp(flash_fwd_w2048)",
+      "transpose(jvp(flash_bwd_fused_w2048))",
+      "transpose(jvp(flash_bwd_fused))"], (2, 2)),
+    # a block recomputed with its kernel: the forward call a second time
+    (["checkpoint/flash_fwd_qk192v128", "rematted_computation/"
+      "jvp(flash_fwd_qk192v128)",
+      "transpose(jvp(flash_bwd_fused_qk192v128))"], (2, 1)),
+    # the split backward is two kernels; other kernels are not counted
+    (["jvp(flash_fwd)", "transpose(jvp(flash_bwd_dq))",
+      "transpose(jvp(flash_bwd_dkv))", "gdn_state_fwd", "ragged_dot"],
+     (1, 2)),
+    ([], (0, 0)),
+])
+def test_walker_counts_the_flash_kernels_calls(scopes, expected):
+    text = _module(
+        "".join(_mosaic_call(f"call.{i}", scope)
+                for i, scope in enumerate(scopes))
+        # not a Mosaic call, whatever its scope says
+        + '  %cc = f32[256]{0} custom-call(%p1), custom_call_target="Sharding"'
+        ', metadata={op_name="jit(step)/flash_fwd/pallas_call"}\n'
+        "  ROOT %mul = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n")
+    assert flash_calls_from_hlo(text) == dict(
+        zip(("flash_fwd_calls", "flash_bwd_calls"), expected))
 
 
 def test_the_audits_defaults_are_the_four_chip_cells_sizes():
