@@ -31,6 +31,12 @@ layer (SURVEY.md §1) — this is one shared core with the sync strategy as a
 plug-in.
 """
 
+import time as _time
+
+#: ``perf_counter`` at the package's first import, read before any other
+#: import of it: the zero of the start-up record (``telemetry/startup.py``).
+IMPORT_STARTED = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from distributed_machine_learning_tpu import utils  # noqa: F401

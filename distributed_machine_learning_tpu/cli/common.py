@@ -29,6 +29,7 @@ from distributed_machine_learning_tpu.runtime.distributed import (
     initialize_from_flags,
 )
 from distributed_machine_learning_tpu.runtime.mesh import make_mesh, replicate
+from distributed_machine_learning_tpu.telemetry import startup
 from distributed_machine_learning_tpu.train.loop import evaluate, train_epoch
 from distributed_machine_learning_tpu.train.sgd import SGDConfig
 from distributed_machine_learning_tpu.train.state import TrainState
@@ -472,15 +473,19 @@ def init_model_and_state(model, seed: int = SEED, config: SGDConfig | None = Non
     """Initialize once from the shared seed → identical weights everywhere,
     the property the reference gets by seeding every rank before building
     the model (``part2/2a/main.py:199``, SURVEY.md §2.5)."""
-    rng = jax.random.PRNGKey(seed)
-    init_rng, state_rng = jax.random.split(rng)
-    variables = model.init(init_rng, jax.numpy.zeros((1, 32, 32, 3)), train=False)
-    return TrainState.create(
-        params=variables["params"],
-        batch_stats=variables.get("batch_stats"),
-        rng=state_rng,
-        config=config,
-    )
+    with startup.span("startup.build.init_state"):
+        rng = jax.random.PRNGKey(seed)
+        init_rng, state_rng = jax.random.split(rng)
+        variables = model.init(init_rng, jax.numpy.zeros((1, 32, 32, 3)),
+                               train=False)
+        state = TrainState.create(
+            params=variables["params"],
+            batch_stats=variables.get("batch_stats"),
+            rng=state_rng,
+            config=config,
+        )
+        startup.record().note(params=startup.tree_size(state.params))
+    return state
 
 
 def run_part(
@@ -498,44 +503,49 @@ def run_part(
         configure_compile_cache,
     )
     from distributed_machine_learning_tpu.runtime.faults import FaultEvents
-
-    configure_compile_cache()
-    # Streaming mode: rows hit the disk as they land (rank-0 gated,
-    # periodic fsync) instead of only at exit — a crash keeps history.
-    # Append only when this run CONTINUES prior work (--resume): a
-    # restart then extends the survivor rows.  A fresh run truncates,
-    # the historical semantics — appending would silently mix
-    # unrelated runs in one file.
-    metrics = (
-        MetricsLogger(path=args.metrics_file,
-                      flush_every=getattr(args, "telemetry_flush_every", 20),
-                      append=bool(args.resume))
-        if args.metrics_file else None
-    )
     from distributed_machine_learning_tpu.telemetry import (
         set_telemetry,
         telemetry_from_flags,
     )
 
-    telemetry = telemetry_from_flags(args)
-    prev_telemetry = None
-    if telemetry is not None:
-        # Installed process-wide so the deep layers (loader queue gauge,
-        # retry counters, checkpoint spans, FaultEvents mirror,
-        # supervisor restart spans) see it without signature threading.
-        prev_telemetry = set_telemetry(telemetry)
-        from distributed_machine_learning_tpu.models.vgg import _cfg
-        from distributed_machine_learning_tpu.utils.flops import (
-            vgg_train_flops_per_image,
+    record = startup.record()
+    record.imports_done()
+    with record.span("startup.runtime"):
+        configure_compile_cache()
+        # Streaming mode: rows hit the disk as they land (rank-0 gated,
+        # periodic fsync) instead of only at exit — a crash keeps history.
+        # Append only when this run CONTINUES prior work (--resume): a
+        # restart then extends the survivor rows.  A fresh run truncates,
+        # the historical semantics — appending would silently mix
+        # unrelated runs in one file.
+        metrics = (
+            MetricsLogger(
+                path=args.metrics_file,
+                flush_every=getattr(args, "telemetry_flush_every", 20),
+                append=bool(args.resume))
+            if args.metrics_file else None
         )
-
-        if args.model.upper() in _cfg:
-            # MFU cost model (utils/flops.py); non-VGG models log
-            # throughput without MFU rather than against a wrong model.
-            telemetry.flops_per_example = vgg_train_flops_per_image(
-                _cfg[args.model.upper()]
+        telemetry = telemetry_from_flags(args)
+        prev_telemetry = None
+        if telemetry is not None:
+            # Installed process-wide so the deep layers (loader queue gauge,
+            # retry counters, checkpoint spans, FaultEvents mirror,
+            # supervisor restart spans) see it without signature threading.
+            prev_telemetry = set_telemetry(telemetry)
+            from distributed_machine_learning_tpu.models.vgg import _cfg
+            from distributed_machine_learning_tpu.utils.flops import (
+                vgg_train_flops_per_image,
             )
-    ctx = initialize_from_flags(args.master_ip, args.rank, args.num_nodes)
+
+            if args.model.upper() in _cfg:
+                # MFU cost model (utils/flops.py); non-VGG models log
+                # throughput without MFU rather than against a wrong model.
+                telemetry.flops_per_example = vgg_train_flops_per_image(
+                    _cfg[args.model.upper()]
+                )
+        ctx = initialize_from_flags(args.master_ip, args.rank, args.num_nodes)
+        n_devices = jax.device_count()  # the first touch takes the chip
+        record.note(devices=n_devices)
     preemption = None
     watchdog = None
     ckpt_writer = None
@@ -544,72 +554,75 @@ def run_part(
     events = FaultEvents()
     show_resilience = False
     try:
-        distributed = strategy_name != "none"
-        mesh = make_mesh() if distributed else None
-        world = mesh.shape["batch"] if mesh is not None else 1
-        # The two Pallas paths a part can select (int8 is the only
-        # codec with kernels, AdamW the only fused update).
-        fused_codec = (
-            strategy_name == "ring"
-            and getattr(args, "ring_codec_impl", "xla") == "pallas"
-            and getattr(args, "ring_compress", "none") == "int8"
-        )
-        fused_update = (getattr(args, "fused_update", False)
-                        and args.optimizer == "adamw")
-        # Reference banner (part2/2a/main.py:200-203) + the device.
-        rank0_print(
-            f"strategy={strategy_name} world_size={world} "
-            f"devices={jax.device_count()} processes={jax.process_count()} "
-            + device_banner(fused_codec or fused_update)
-        )
-
-        compute_dtype = jnp.bfloat16 if args.compute_dtype == "bfloat16" else jnp.float32
-        model = get_model(args.model, use_bn=use_bn,
-                          compute_dtype=compute_dtype)
-        from distributed_machine_learning_tpu.train.optimizers import (
-            get_optimizer,
-        )
-
-        opt_config = get_optimizer(args.optimizer)[0]()
-        if getattr(args, "fused_update", False):
-            from distributed_machine_learning_tpu.train.adamw import (
-                AdamWConfig,
+        with record.span("startup.build", parallel=strategy_name,
+                         devices=n_devices):
+            distributed = strategy_name != "none"
+            mesh = make_mesh() if distributed else None
+            world = mesh.shape["batch"] if mesh is not None else 1
+            # The two Pallas paths a part can select (int8 is the only
+            # codec with kernels, AdamW the only fused update).
+            fused_codec = (
+                strategy_name == "ring"
+                and getattr(args, "ring_codec_impl", "xla") == "pallas"
+                and getattr(args, "ring_compress", "none") == "int8"
+            )
+            fused_update = (getattr(args, "fused_update", False)
+                            and args.optimizer == "adamw")
+            # Reference banner (part2/2a/main.py:200-203) + the device.
+            rank0_print(
+                f"strategy={strategy_name} world_size={world} "
+                f"devices={n_devices} processes={jax.process_count()} "
+                + device_banner(fused_codec or fused_update)
             )
 
-            if isinstance(opt_config, AdamWConfig):
-                import dataclasses
+            compute_dtype = (jnp.bfloat16 if args.compute_dtype == "bfloat16"
+                             else jnp.float32)
+            model = get_model(args.model, use_bn=use_bn,
+                              compute_dtype=compute_dtype)
+            from distributed_machine_learning_tpu.train.optimizers import (
+                get_optimizer,
+            )
 
-                opt_config = dataclasses.replace(opt_config, fused=True)
-            else:
-                rank0_print(
-                    "WARNING: --fused-update applies to --optimizer adamw "
-                    f"only; {args.optimizer!r} runs its reference update."
+            opt_config = get_optimizer(args.optimizer)[0]()
+            if getattr(args, "fused_update", False):
+                from distributed_machine_learning_tpu.train.adamw import (
+                    AdamWConfig,
                 )
-        state = init_model_and_state(model, config=opt_config)
 
-        # Unsynced-BN quirk mode (reference part3 parity: per-node running
-        # stats — part3/model.py:24, group25.pdf p.3-4).  Decided BEFORE
-        # --resume so the checkpoint-restore template carries the stacked
-        # [world, C] stats layout a quirk-mode checkpoint was saved with.
-        unsync_bn = bool(getattr(args, "unsync_bn", False))
-        if unsync_bn and mesh is None:
-            rank0_print("WARNING: --unsync-bn has no effect on the "
-                        "single-device part1 path (one device, one set of "
-                        "stats).")
-            unsync_bn = False
-        if unsync_bn and not state.batch_stats:
-            unsync_bn = False  # BN-free model: nothing to (un)sync
-        from distributed_machine_learning_tpu.train.step import (
-            broadcast_bn_stats,
-        )
+                if isinstance(opt_config, AdamWConfig):
+                    import dataclasses
 
-        def _maybe_stack(st):
-            return broadcast_bn_stats(st, world) if unsync_bn else st
+                    opt_config = dataclasses.replace(opt_config, fused=True)
+                else:
+                    rank0_print(
+                        "WARNING: --fused-update applies to --optimizer adamw "
+                        f"only; {args.optimizer!r} runs its reference update."
+                    )
+            state = init_model_and_state(model, config=opt_config)
 
-        def _replicate(st):
-            return replicate(st, mesh) if mesh is not None else st
+            # Unsynced-BN quirk mode (reference part3 parity: per-node running
+            # stats — part3/model.py:24, group25.pdf p.3-4).  Decided BEFORE
+            # --resume so the checkpoint-restore template carries the stacked
+            # [world, C] stats layout a quirk-mode checkpoint was saved with.
+            unsync_bn = bool(getattr(args, "unsync_bn", False))
+            if unsync_bn and mesh is None:
+                rank0_print("WARNING: --unsync-bn has no effect on the "
+                            "single-device part1 path (one device, one set of "
+                            "stats).")
+                unsync_bn = False
+            if unsync_bn and not state.batch_stats:
+                unsync_bn = False  # BN-free model: nothing to (un)sync
+            from distributed_machine_learning_tpu.train.step import (
+                broadcast_bn_stats,
+            )
 
-        state = _replicate(_maybe_stack(state))
+            def _maybe_stack(st):
+                return broadcast_bn_stats(st, world) if unsync_bn else st
+
+            def _replicate(st):
+                return replicate(st, mesh) if mesh is not None else st
+
+            state = _replicate(_maybe_stack(state))
 
         def restore_latest(fresh_state):
             """State from the newest complete checkpoint in --ckpt-dir
@@ -734,7 +747,8 @@ def run_part(
             return state
 
         if args.resume:
-            state = restore_latest(state)
+            with record.span("startup.resume"):
+                state = restore_latest(state)
         strategy_kwargs = dict(strategy_kwargs or {})
         ring_compress = getattr(args, "ring_compress", "none")
         if args.wire_dtype:
@@ -841,86 +855,91 @@ def run_part(
                 telemetry.step_counters["fused_codec_steps"] = 1
             if fused_update:
                 telemetry.step_counters["fused_update_steps"] = 1
-        train_step = make_train_step(
-            model, strategy, mesh=mesh,
-            schedule=make_schedule(
-                args, state.config.learning_rate,
-                start_step=int(jax.device_get(state.step)),
-            ),
-            clip_norm=args.clip_norm,
-            accum_steps=args.grad_accum,
-            optimizer=args.optimizer,
-            sync_bn=not unsync_bn,
-            local_loss=bool(getattr(args, "local_loss", False))
-            and mesh is not None,
-            guard_nonfinite=bool(getattr(args, "guard_nonfinite", False)),
-        )
-        eval_step = make_eval_step(model)
-        if unsync_bn and state.batch_stats:
-            # Quirk-mode stats are [world, *S]-stacked; the single-device
-            # eval step can't consume them — evaluate with device 0's row
-            # (each reference node evaluates with its own stats; rank 0's
-            # is the one whose prints we surface).
-            base_eval = eval_step
-
-            def eval_step(params, stats, images, labels):
-                stats0 = jax.tree_util.tree_map(lambda s: s[0], stats)
-                return base_eval(params, stats0, images, labels)
-        if args.dist_eval and mesh is None:
-            rank0_print(
-                "WARNING: --dist-eval has no effect for the single-device "
-                "part1 path (no mesh to shard over); evaluating on one "
-                "device."
+        with record.span("startup.build", parallel=strategy_name,
+                         devices=n_devices):
+            train_step = make_train_step(
+                model, strategy, mesh=mesh,
+                schedule=make_schedule(
+                    args, state.config.learning_rate,
+                    start_step=int(jax.device_get(state.step)),
+                ),
+                clip_norm=args.clip_norm,
+                accum_steps=args.grad_accum,
+                optimizer=args.optimizer,
+                sync_bn=not unsync_bn,
+                local_loss=bool(getattr(args, "local_loss", False))
+                and mesh is not None,
+                guard_nonfinite=bool(getattr(args, "guard_nonfinite", False)),
             )
-        if args.dist_eval and mesh is not None:
-            # Sharded eval for world-size-divisible batches; the single
-            # device step covers the test set's short final batch (the
-            # reference instead evaluates everything on every rank —
-            # SURVEY.md §3.5).
-            # sync_bn=False makes the sharded eval read each device's own
-            # row of quirk-mode stacked stats (make_eval_step docstring).
-            dist_eval, single_eval = (
-                make_eval_step(model, mesh=mesh, sync_bn=not unsync_bn),
-                eval_step,
-            )
+            eval_step = make_eval_step(model)
+            if unsync_bn and state.batch_stats:
+                # Quirk-mode stats are [world, *S]-stacked; the single-device
+                # eval step can't consume them — evaluate with device 0's row
+                # (each reference node evaluates with its own stats; rank 0's
+                # is the one whose prints we surface).
+                base_eval = eval_step
 
-            def eval_step(params, stats, images, labels):
-                fn = dist_eval if len(labels) % world == 0 else single_eval
-                return fn(params, stats, images, labels)
+                def eval_step(params, stats, images, labels):
+                    stats0 = jax.tree_util.tree_map(lambda s: s[0], stats)
+                    return base_eval(params, stats0, images, labels)
+            if args.dist_eval and mesh is None:
+                rank0_print(
+                    "WARNING: --dist-eval has no effect for the single-device "
+                    "part1 path (no mesh to shard over); evaluating on one "
+                    "device."
+                )
+            if args.dist_eval and mesh is not None:
+                # Sharded eval for world-size-divisible batches; the single
+                # device step covers the test set's short final batch (the
+                # reference instead evaluates everything on every rank —
+                # SURVEY.md §3.5).
+                # sync_bn=False makes the sharded eval read each device's own
+                # row of quirk-mode stacked stats (make_eval_step docstring).
+                dist_eval, single_eval = (
+                    make_eval_step(model, mesh=mesh, sync_bn=not unsync_bn),
+                    eval_step,
+                )
 
-        # Never the network: the dataset is on disk under --data-root or
-        # it is the seeded stand-in, so a run reads nothing from outside.
-        train_set = load_cifar10(args.data_root, train=True, download=False)
-        test_set = load_cifar10(args.data_root, train=False, download=False)
-        if train_set.synthetic:
-            rank0_print("WARNING: CIFAR-10 not found on disk — using the "
-                        "deterministic synthetic stand-in dataset.")
+                def eval_step(params, stats, images, labels):
+                    fn = dist_eval if len(labels) % world == 0 else single_eval
+                    return fn(params, stats, images, labels)
 
-        if args.batch_size is not None:
-            per_rank_batch = args.batch_size
+        with record.span("startup.data"):
+            # Never the network: the dataset is on disk under --data-root or
+            # it is the seeded stand-in, so a run reads nothing from outside.
+            train_set = load_cifar10(args.data_root, train=True,
+                                     download=False)
+            test_set = load_cifar10(args.data_root, train=False,
+                                    download=False)
+            if train_set.synthetic:
+                rank0_print("WARNING: CIFAR-10 not found on disk — using "
+                            "the deterministic synthetic stand-in dataset.")
 
-        loader_cls, dist_loader_cls = BatchLoader, DistributedBatchLoader
-        loader_choice = getattr(args, "loader", "auto")
-        if loader_choice in ("auto", "native"):
-            from distributed_machine_learning_tpu.data.native_loader import (
-                NativeBatchLoader,
-                NativeDistributedBatchLoader,
-                native_available,
-                native_unavailable_reason,
-            )
+            if args.batch_size is not None:
+                per_rank_batch = args.batch_size
 
-            if native_available():
-                loader_cls, dist_loader_cls = (
+            loader_cls, dist_loader_cls = BatchLoader, DistributedBatchLoader
+            loader_choice = getattr(args, "loader", "auto")
+            if loader_choice in ("auto", "native"):
+                from distributed_machine_learning_tpu.data.native_loader import (  # noqa: E501
                     NativeBatchLoader,
                     NativeDistributedBatchLoader,
+                    native_available,
+                    native_unavailable_reason,
                 )
-            elif loader_choice == "native":
-                raise RuntimeError(native_unavailable_reason())
-            else:
-                rank0_print(
-                    f"native loader unavailable, using python loader "
-                    f"({native_unavailable_reason()})"
-                )
+
+                if native_available():
+                    loader_cls, dist_loader_cls = (
+                        NativeBatchLoader,
+                        NativeDistributedBatchLoader,
+                    )
+                elif loader_choice == "native":
+                    raise RuntimeError(native_unavailable_reason())
+                else:
+                    rank0_print(
+                        f"native loader unavailable, using python loader "
+                        f"({native_unavailable_reason()})"
+                    )
 
         place = (lambda i, l: shard_batch(mesh, i, l)) if mesh is not None else None
         from distributed_machine_learning_tpu.runtime.faults import (

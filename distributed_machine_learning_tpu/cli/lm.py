@@ -855,30 +855,35 @@ def main(argv=None) -> RunResult:
     from distributed_machine_learning_tpu.runtime.compile_cache import (
         configure_compile_cache,
     )
-
-    configure_compile_cache()
-    parser = make_parser()
-    args = parser.parse_args(argv)
-    if args.telemetry_flush_every < 1:
-        # Same parse-time validation the CNN parts get from parse_flags.
-        parser.error(
-            f"--telemetry-flush-every must be >= 1, got "
-            f"{args.telemetry_flush_every}"
-        )
     from distributed_machine_learning_tpu.telemetry import (
         set_telemetry,
+        startup,
         telemetry_from_flags,
     )
 
-    telemetry = telemetry_from_flags(args)
-    prev_telemetry = None
-    if telemetry is not None:
-        prev_telemetry = set_telemetry(telemetry)
-    ctx = initialize_from_flags(args.master_ip, args.rank, args.num_nodes)
+    record = startup.record()
+    record.imports_done()
+    with record.span("startup.runtime"):
+        configure_compile_cache()
+        parser = make_parser()
+        args = parser.parse_args(argv)
+        if args.telemetry_flush_every < 1:
+            # Same parse-time validation the CNN parts get from parse_flags.
+            parser.error(
+                f"--telemetry-flush-every must be >= 1, got "
+                f"{args.telemetry_flush_every}"
+            )
+        telemetry = telemetry_from_flags(args)
+        prev_telemetry = None
+        if telemetry is not None:
+            prev_telemetry = set_telemetry(telemetry)
+        ctx = initialize_from_flags(args.master_ip, args.rank, args.num_nodes)
+        n_devices = jax.device_count()  # the first touch takes the chip
+        record.note(devices=n_devices)
     try:
         read_model_config(args)  # the sizes the banner and the data use
         rank0_print(
-            f"lm parallel={args.parallel} devices={jax.device_count()} "
+            f"lm parallel={args.parallel} devices={n_devices} "
             f"d_model={args.d_model} layers={args.n_layers} "
             f"seq_len={args.seq_len} batch={args.batch_size} "
             # --attn auto/flash may dispatch the Pallas flash kernels.
@@ -893,50 +898,52 @@ def main(argv=None) -> RunResult:
         will_eval = bool(args.eval_batches)
         corpus = None
         eval_corpus = None
-        if args.data_dir is not None:
-            from distributed_machine_learning_tpu.data.text import (
-                VOCAB_SIZE,
-                load_corpus,
-            )
-
-            corpus = load_corpus(args.data_dir)
-            if args.vocab < VOCAB_SIZE:
-                rank0_print(
-                    f"--data-dir is byte-level: vocab {args.vocab} -> "
-                    f"{VOCAB_SIZE} (256 bytes + BOS)"
-                )
-                args.vocab = VOCAB_SIZE
-            if will_eval:
+        with record.span("startup.data"):
+            if args.data_dir is not None:
                 from distributed_machine_learning_tpu.data.text import (
-                    split_corpus,
+                    VOCAB_SIZE,
+                    load_corpus,
                 )
 
-                corpus, eval_corpus = split_corpus(
-                    corpus, eval_frac=0.1,
-                    min_eval_tokens=args.seq_len + 1,
-                )
-                if len(eval_corpus) == len(corpus):
-                    # split_corpus's documented degrade path: don't let
-                    # training-set perplexity masquerade as held-out.
+                corpus = load_corpus(args.data_dir)
+                if args.vocab < VOCAB_SIZE:
                     rank0_print(
-                        "WARNING: corpus too small to hold out an eval "
-                        "slice — eval will run on in-distribution "
-                        "training windows"
+                        f"--data-dir is byte-level: vocab {args.vocab} -> "
+                        f"{VOCAB_SIZE} (256 bytes + BOS)"
                     )
+                    args.vocab = VOCAB_SIZE
+                if will_eval:
+                    from distributed_machine_learning_tpu.data.text import (
+                        split_corpus,
+                    )
+
+                    corpus, eval_corpus = split_corpus(
+                        corpus, eval_frac=0.1,
+                        min_eval_tokens=args.seq_len + 1,
+                    )
+                    if len(eval_corpus) == len(corpus):
+                        # split_corpus's documented degrade path: don't let
+                        # training-set perplexity masquerade as held-out.
+                        rank0_print(
+                            "WARNING: corpus too small to hold out an eval "
+                            "slice — eval will run on in-distribution "
+                            "training windows"
+                        )
+                        rank0_print(f"corpus: {len(corpus)} tokens from "
+                                    f"{args.data_dir}")
+                    else:
+                        rank0_print(
+                            f"corpus: {len(corpus)} train tokens from "
+                            f"{args.data_dir}, {len(eval_corpus)} held-out "
+                            "eval tokens"
+                        )
+                else:
                     rank0_print(
                         f"corpus: {len(corpus)} tokens from {args.data_dir}"
                     )
-                else:
-                    rank0_print(
-                        f"corpus: {len(corpus)} train tokens from "
-                        f"{args.data_dir}, {len(eval_corpus)} held-out "
-                        "eval tokens"
-                    )
-            else:
-                rank0_print(
-                    f"corpus: {len(corpus)} tokens from {args.data_dir}"
-                )
-        step, state, place, model, params_fn = build(args)
+        with record.span("startup.build", parallel=args.parallel,
+                         devices=n_devices):
+            step, state, place, model, params_fn = build(args)
         if telemetry is not None:
             # MFU cost model: ~6·P/token + attention term
             # (utils/flops.py).  Parameter count from the state when it
@@ -1093,7 +1100,8 @@ def main(argv=None) -> RunResult:
             return state
 
         if args.resume:
-            state = _resume(state)
+            with record.span("startup.resume"):
+                state = _resume(state)
 
         def run_once(s):
             """Train + final save; the unit a supervised restart retries.

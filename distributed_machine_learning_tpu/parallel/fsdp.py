@@ -159,21 +159,24 @@ def shard_fsdp_state(
             "ZeRO-3/FSDP cannot shard LARS (per-layer norms are not "
             "sliceable); use sgd or adamw"
         )
-    flat, mom_flat, unravel, n_elems = flatten_padded(
-        state, mesh.shape[axis_name]
-    )
-    sharding = NamedSharding(mesh, P(axis_name))
-    replicated = NamedSharding(mesh, P())
-    fsdp_state = FSDPState(
-        param_shards=jax.device_put(flat, sharding),
-        momentum_shards=jax.tree_util.tree_map(
-            lambda a: jax.device_put(a, sharding), mom_flat
-        ),
-        batch_stats=jax.device_put(state.batch_stats, replicated),
-        step=jax.device_put(state.step, replicated),
-        rng=jax.device_put(state.rng, replicated),
-        config=state.config,
-    )
+    from distributed_machine_learning_tpu.telemetry import startup
+
+    with startup.place_state(state, mesh):
+        flat, mom_flat, unravel, n_elems = flatten_padded(
+            state, mesh.shape[axis_name]
+        )
+        sharding = NamedSharding(mesh, P(axis_name))
+        replicated = NamedSharding(mesh, P())
+        fsdp_state = FSDPState(
+            param_shards=jax.device_put(flat, sharding),
+            momentum_shards=jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, sharding), mom_flat
+            ),
+            batch_stats=jax.device_put(state.batch_stats, replicated),
+            step=jax.device_put(state.step, replicated),
+            rng=jax.device_put(state.rng, replicated),
+            config=state.config,
+        )
     return fsdp_state, unravel, n_elems
 
 

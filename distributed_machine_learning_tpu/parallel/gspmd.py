@@ -60,9 +60,12 @@ def state_shardings(state: TrainState, mesh: Mesh, spec_for: SpecFor) -> TrainSt
 
 def shard_state(state: TrainState, mesh: Mesh, spec_for: SpecFor) -> TrainState:
     """Place a host/replicated TrainState into the rule table's layout."""
-    return jax.tree_util.tree_map(
-        jax.device_put, state, state_shardings(state, mesh, spec_for)
-    )
+    from distributed_machine_learning_tpu.telemetry import startup
+
+    with startup.place_state(state, mesh):
+        return jax.tree_util.tree_map(
+            jax.device_put, state, state_shardings(state, mesh, spec_for)
+        )
 
 
 def make_cached_sharded_step(impl, mesh: Mesh, spec_for: SpecFor, batch_sharding):

@@ -214,9 +214,12 @@ def shard_3d_state(
     the 3-D layout.  ``zero1_dp=True`` additionally shards the optimizer
     moments 1/dp over the data axis (pass the same flag to
     ``make_3d_lm_train_step``)."""
-    return jax.tree_util.tree_map(
-        jax.device_put, state, _state_shardings_3d(state, mesh, zero1_dp)
-    )
+    from distributed_machine_learning_tpu.telemetry import startup
+
+    with startup.place_state(state, mesh):
+        return jax.tree_util.tree_map(
+            jax.device_put, state, _state_shardings_3d(state, mesh, zero1_dp)
+        )
 
 
 def shard_3d_batch(mesh: Mesh, tokens_mb, targets_mb):

@@ -553,11 +553,15 @@ def shard_pp_state(
 ) -> TrainState:
     """Place a pipeline TrainState: blocks sharded over stages, rest
     replicated."""
+    from distributed_machine_learning_tpu.telemetry import startup
+
     spec_state = _state_specs(pipe_axis, state.params, state.momentum)
     spec_state = spec_state.replace(config=state.config)
-    return jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), state, spec_state
-    )
+    with startup.place_state(state, mesh):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+            state, spec_state,
+        )
 
 
 def microbatch(tokens, targets, num_microbatches: int):

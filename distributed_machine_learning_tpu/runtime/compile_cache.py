@@ -7,6 +7,11 @@ fixed directory inside the checkout, derived from this file's location
 so every process of a checkout agrees on it.  The chip tool keeps only
 its output directory between calls, so a run that should reuse compiled
 programs points the variable there from outside.
+
+It also registers the process's one ``jax.monitoring`` listener
+(``telemetry/startup.py::listen_to_jax``): every entry point comes through
+here before it compiles, so what JAX traced, lowered, compiled or fetched
+from this cache is counted from the first program on.
 """
 
 from __future__ import annotations
@@ -21,6 +26,12 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 def configure_compile_cache() -> str:
     """Apply the rule above; returns the cache directory in use."""
     import jax
+
+    from distributed_machine_learning_tpu.telemetry.startup import (
+        listen_to_jax,
+    )
+
+    listen_to_jax()
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not placed:

@@ -136,7 +136,10 @@ def replicate(tree, mesh: Mesh):
     differently-placed inputs and compiles the whole program again —
     and a device-0-COMMITTED state next to mesh-sharded batches is a
     hard error."""
-    return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
+    from distributed_machine_learning_tpu.telemetry import startup
+
+    with startup.place_state(tree, mesh):
+        return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
 
 
 def ensure_host_devices(n: int = 8) -> None:

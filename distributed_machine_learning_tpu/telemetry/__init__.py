@@ -19,6 +19,13 @@ Three pieces, one facade:
       registry.json   final registry snapshot (counters, quantiles)
       metrics.prom    Prometheus textfile export of the final values
 
+A fourth piece needs no directory and is always on: the process's
+start-up record (``telemetry/startup.py`` — ``startup.*`` spans from the
+package's first import to the first loss, JAX's trace / lower / compile /
+cache events as ``jax_*_total`` counters by ``phase``), which an installed
+``Telemetry`` absorbs into ``trace.json`` / ``registry.json`` /
+``metrics.prom``.
+
 Everything is OFF by default: ``get_telemetry()`` returns ``None``
 unless a CLI installed an instance (``--telemetry-dir``), and every
 integration point guards with ``if tel is not None`` — the hot loop
@@ -66,6 +73,7 @@ from distributed_machine_learning_tpu.telemetry.aggregator import (
     publish_rollup,
     serving_stage_samples,
 )
+from distributed_machine_learning_tpu.telemetry import startup
 from distributed_machine_learning_tpu.telemetry.slo import (
     SLOEngine,
     SLOSpec,
@@ -212,6 +220,9 @@ class Telemetry:
         # just accumulates it).  Empty by default: one dict iteration
         # per step when telemetry is on, nothing when off.
         self.step_counters: dict[str, float] = {}
+        #: How many of the start-up record's closed spans the tracer holds
+        #: (``startup.py::StartupRecord.follow``).
+        self.startup_spans = 0
         self._closed = False
 
     def _artifact(self, name: str) -> str:
@@ -270,7 +281,8 @@ class Telemetry:
         with open(tmp, "w") as f:
             json.dump(self.registry.snapshot(), f, indent=1)
         os.replace(tmp, snap_path)
-        write_prometheus(self._artifact(PROM_FILE), self.registry)
+        write_prometheus(self._artifact(PROM_FILE), self.registry,
+                         fsync=self.metrics.fsync)
 
     def close(self) -> None:
         if self._closed:
@@ -298,10 +310,16 @@ def get_telemetry() -> Telemetry | None:
 
 def set_telemetry(tel: Telemetry | None) -> Telemetry | None:
     """Install ``tel`` process-wide (None uninstalls); returns the
-    previous instance so scoped users can restore it."""
+    previous instance so scoped users can restore it.  The installed
+    instance absorbs the process's start-up record (``startup.py``): the
+    closed ``startup.*`` spans it does not hold yet, with their own
+    timestamps, then those that close while it stays installed; its
+    registry exports the record's ``jax_*_total`` counters with its own
+    from then on."""
     global _active
     prev = _active
     _active = tel
+    startup.record().follow(tel)
     return prev
 
 

@@ -172,6 +172,7 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._instruments: dict[tuple, object] = {}
+        self._adopted: list[MetricsRegistry] = []
 
     def _get(self, cls, name: str, labels: dict, **kw):
         key = (cls.__name__, name, _label_key(labels))
@@ -195,13 +196,29 @@ class MetricsRegistry:
         return self._get(Histogram, name, labels, buckets=buckets)
 
     # -- export ----------------------------------------------------------
+    def adopt(self, other: "MetricsRegistry") -> None:
+        """Export ``other``'s instruments with this registry's from now on
+        (again is a no-op): the instruments stay ``other``'s, so one
+        increment serves both, and where both hold a series ``other``'s is
+        the one exported.  An installed ``Telemetry``'s registry adopts the
+        process's start-up record's (``startup.py``): on a resume its
+        ``jax_*_total`` are this process's, not the prior attempt's."""
+        if other is not self and other not in self._adopted:
+            self._adopted.append(other)
+
+    def _exported(self) -> list:
+        with self._lock:
+            instruments = dict(self._instruments)
+        for other in self._adopted:
+            with other._lock:
+                instruments.update(other._instruments)
+        return list(instruments.values())
+
     def snapshot(self) -> dict:
         """JSON-ready dump of every instrument (quantiles included for
         histograms) — the ``registry.json`` payload."""
         out: dict = {"counters": [], "gauges": [], "histograms": []}
-        with self._lock:
-            instruments = list(self._instruments.values())
-        for inst in instruments:
+        for inst in self._exported():
             entry: dict = {"name": inst.name, "labels": dict(inst.labels)}
             if isinstance(inst, Counter):
                 entry["value"] = inst.value
@@ -242,10 +259,8 @@ class MetricsRegistry:
                    + "}" if pairs else "")
             return f"{name}{lab} {value}"
 
-        with self._lock:
-            instruments = list(self._instruments.values())
         families: dict[str, tuple[str, list]] = {}
-        for inst in instruments:
+        for inst in self._exported():
             kind = ("counter" if isinstance(inst, Counter)
                     else "gauge" if isinstance(inst, Gauge)
                     else "histogram")

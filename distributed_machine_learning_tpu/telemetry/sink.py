@@ -175,15 +175,18 @@ def read_jsonl(path: str | os.PathLike, tolerate_truncation: bool = True
     return rows
 
 
-def write_prometheus(path: str | os.PathLike, registry) -> None:
+def write_prometheus(path: str | os.PathLike, registry,
+                     fsync: bool = True) -> None:
     """Atomic-rename write of ``registry.to_prometheus()`` — the
     node-exporter textfile-collector contract (a scraper must never see
-    a half-written file)."""
+    a half-written file).  ``fsync=False`` (as ``JsonlSink.fsync``) keeps
+    the rename and drops the fsync."""
     path = os.fspath(path)
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
         f.write(registry.to_prometheus())
         f.flush()
-        os.fsync(f.fileno())
+        if fsync:
+            os.fsync(f.fileno())
     os.replace(tmp, path)

@@ -385,7 +385,12 @@ class _StepWithSyncGauges:
             grad_sync_bytes,
         )
 
-        text = self._jitted.lower(*args).compile().as_text()
+        from distributed_machine_learning_tpu.telemetry import startup
+
+        # Inside the process's first step: the second lowering has a span
+        # and a counter phase of its own there.
+        with startup.span("startup.hlo_gauges"):
+            text = self._jitted.lower(*args).compile().as_text()
         registry = self._get_telemetry().registry
         gauges = {**grad_sync_bytes(all_reduces_from_hlo(text)),
                   **flash_calls_from_hlo(text)}
@@ -547,11 +552,16 @@ def init_lm_state(model, seed: int = 69143, batch: int = 1, seq_len: int = 8,
     optional optimizer config (default SGD parity; pass ``AdamWConfig()``
     for the LM-standard AdamW — the step dispatches on the config type).
     """
+    from distributed_machine_learning_tpu.telemetry import startup
+
     dense = model.clone(attn_impl="dense") if model.attn_impl != "dense" else model
-    rng = jax.random.PRNGKey(seed)
-    init_rng, state_rng = jax.random.split(rng)
-    tokens = jnp.zeros((batch, seq_len), jnp.int32)
-    variables = dense.init(init_rng, tokens, train=False)
-    return TrainState.create(
-        params=variables["params"], rng=state_rng, config=config
-    )
+    with startup.span("startup.build.init_state"):
+        rng = jax.random.PRNGKey(seed)
+        init_rng, state_rng = jax.random.split(rng)
+        tokens = jnp.zeros((batch, seq_len), jnp.int32)
+        variables = dense.init(init_rng, tokens, train=False)
+        state = TrainState.create(
+            params=variables["params"], rng=state_rng, config=config
+        )
+        startup.record().note(params=startup.tree_size(state.params))
+    return state

@@ -12,13 +12,14 @@ enqueue latency, not the step.
 from __future__ import annotations
 
 import collections
+import functools
 import time
 from typing import Iterable
 
 import jax
 import numpy as np
 
-from distributed_machine_learning_tpu.telemetry import get_telemetry
+from distributed_machine_learning_tpu.telemetry import get_telemetry, startup
 from distributed_machine_learning_tpu.train.state import TrainState
 from distributed_machine_learning_tpu.utils import profiling
 from distributed_machine_learning_tpu.utils.flops import mfu
@@ -67,7 +68,7 @@ def _print_loss(batch_no: int, loss) -> None:
 #: One iteration's phases, each declared once: the name on the profiler's
 #: clock (``utils/profiling.annotate``) -> the ``SpanTracer`` span and the
 #: step-row field.  The last two exist only with a ``Telemetry`` and are
-#: made from the same two ``perf_counter`` reads.
+#: made from the two ``perf_counter`` reads of the bracket.
 _PHASES = {
     "train.data_wait": ("data_wait", "data_wait_s"),
     "train.place_batch": ("place_batch", "place_s"),
@@ -76,30 +77,27 @@ _PHASES = {
 }
 
 
-class _Timed:
-    """A phase bracket with telemetry on: the profiler annotation, and
-    inside it the two clock reads kept in ``times[name]``."""
-
-    __slots__ = ("_ann", "_name", "_times", "_t0")
-
-    def __init__(self, ann, name: str, times: dict):
-        self._ann, self._name, self._times = ann, name, times
-
-    def __enter__(self):
-        self._ann.__enter__()
-        self._t0 = time.perf_counter()
-
-    def __exit__(self, *exc):
-        self._times[self._name] = (self._t0, time.perf_counter())
-        return self._ann.__exit__(*exc)
-
-
 def _phase(name: str, rec: "_LoopTelemetry | None"):
     """The one bracket of a phase.  Always a profiler annotation (a flag
-    test without a profiler session); with telemetry also the host-clock
-    reads its tracer span and row field come from."""
-    ann = profiling.annotate(name)
-    return ann if rec is None else _Timed(ann, name, rec.times)
+    test without a profiler session); with telemetry the bracket that also
+    reads the host clock (``profiling.Timed``, the start-up spans' too),
+    from which the phase's tracer span and row field come."""
+    if rec is None:
+        return profiling.annotate(name)
+    return profiling.Timed(name, rec.phase_done)
+
+
+def _as_an_epoch(fn):
+    """``train_epoch`` inside the start-up record's ``epoch()``: the JAX
+    counters are copied as it begins, and what they count inside it, past
+    the process's first step, is labelled ``train``."""
+
+    @functools.wraps(fn)
+    def train_epoch(*args, **kwargs):
+        with startup.record().epoch():
+            return fn(*args, **kwargs)
+
+    return train_epoch
 
 
 class _LoopTelemetry:
@@ -129,6 +127,9 @@ class _LoopTelemetry:
         # (start, phases' seconds, batch) of the iteration whose period
         # is still open: it ends where the next one starts.
         self._open = None
+
+    def phase_done(self, name: str, t0: float, t1: float) -> None:
+        self.times[name] = (t0, t1)
 
     def host_batch(self, images, labels) -> None:
         """Batch geometry BEFORE placement (sharding may hide it), and
@@ -214,6 +215,12 @@ class _LoopTelemetry:
             t_prev, children_prev, batch_prev = self._open
             tr.complete("train_step", t_prev, t_start, step=batch_prev)
             row["loop_self_s"] = (t_start - t_prev) - children_prev
+        else:
+            # An epoch's first row; the process's first carries the
+            # start-up line as an object.
+            summary = startup.record().pop_summary()
+            if summary is not None:
+                row["startup"] = summary
         self._open = (t_start, children, batch_idx)
         n_examples, n_tokens, h2d_bytes = self._geometry.popleft()
         data_wait_s = row["data_wait_s"]
@@ -292,6 +299,7 @@ class _LoopTelemetry:
                 step=self._open[2])
 
 
+@_as_an_epoch
 def train_epoch(
     train_step,
     state: TrainState,
@@ -394,6 +402,17 @@ def train_epoch(
     ``state.step`` before the loop.  When None (the default) every
     telemetry branch is a single pointer test: no clock reads, no
     device reads or waits, no syscalls beyond today's loop.
+
+    The process's FIRST step ends its start-up record
+    (``telemetry/startup.py``): the timer's two clock reads around
+    iteration 0 become the span ``startup.first_step`` (the step's trace,
+    lowering, compile or cache load, and first execution), the record
+    closes, rank 0 prints the start-up line, and under a ``Telemetry`` the
+    first step row carries it as the field ``startup``.  Every epoch runs
+    inside the record's ``epoch()`` (``_as_an_epoch``): the ``jax_*_total``
+    counters are copied as it begins and count under ``phase="train"``
+    past the first step.  No iteration after the first reads a clock or
+    tests anything for it.
     """
     timer = timer or IterationTimer(skip_first=1)
     tel = telemetry if telemetry is not None else get_telemetry()
@@ -434,6 +453,11 @@ def train_epoch(
         scale_before = getattr(state, "loss_scale", None)
         if scale_before is not None:
             scale_before = float(scale_before)
+    # The process's first step ends its start-up record
+    # (``startup.first_step``) through the timer's own two clock reads; from
+    # the second iteration on ``lap`` is the timer's ``stop`` again.
+    timer_stop = timer.stop
+    lap = startup.record().first_step_stop(timer) or timer_stop
     batch_idx = 0
     while batch is not None:
         with profiling.annotate("train.step", step_num=batch_idx):
@@ -452,7 +476,7 @@ def train_epoch(
                     jax.block_until_ready(batch)
                     rec.batch_ready(loss if ahead else None)
                 loss = jax.block_until_ready(loss)
-            iter_time = timer.stop()
+            iter_time = lap()
             with profiling.annotate("train.bookkeeping"):
                 # One host sync serves both the skip accounting and the
                 # until_step check below — these reads serialize
@@ -496,6 +520,7 @@ def train_epoch(
                     break
                 batch = next_batch(batch_idx + 1)
         batch_idx += 1
+        lap = timer_stop
     if tel is not None:
         rec.close()
     rank0_print(timer.summary())  # part1/main.py:57-58

@@ -15,6 +15,8 @@ The reference's observability is a hand-rolled wall-clock harness
   ``train_epoch`` brackets each iteration (``train.step``) and its phases
   (``train.data_wait`` … ``train.bookkeeping``) with it, so they show up
   as named host spans beside the device's operations in the trace.
+- :class:`Timed` — that annotation plus the two host-clock reads of a
+  tracer span: the one bracket of the loop's phases and the start-up spans.
 """
 
 from __future__ import annotations
@@ -61,6 +63,29 @@ def annotate(name: str, step_num: int | None = None):
     if step_num is not None:
         return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
     return jax.profiler.TraceAnnotation(name)
+
+
+class Timed:
+    """The one span bracket, on both clocks: the profiler annotation
+    ``name`` (:func:`annotate`) and, inside it, the two ``perf_counter``
+    reads a ``SpanTracer`` span is made from, handed to ``done(name, t0,
+    t1)`` as the block ends (also when it raises).  ``train_epoch``'s
+    phases under a ``Telemetry`` and the start-up record's spans
+    (``telemetry/startup.py``) are both this.  As the annotation starts
+    at construction, make it in the ``with`` line itself."""
+
+    __slots__ = ("_ann", "_name", "_done", "_t0")
+
+    def __init__(self, name: str, done):
+        self._ann, self._name, self._done = annotate(name), name, done
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._done(self._name, self._t0, time.perf_counter())
+        return self._ann.__exit__(*exc)
 
 
 @dataclass

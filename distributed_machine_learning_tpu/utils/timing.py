@@ -60,6 +60,13 @@ class IterationTimer:
     def start(self) -> None:
         self._start = time.perf_counter()
 
+    @property
+    def started(self) -> float:
+        """The ``perf_counter`` reading of the newest :meth:`start`: with
+        what :meth:`stop` returns, the iteration as a span, at no further
+        clock read (``telemetry/startup.py``'s ``startup.first_step``)."""
+        return self._start
+
     def stop(self) -> float:
         """Stop the clock; returns this iteration's time (always), and
         accumulates it unless it is among the first `skip_first` iters."""

@@ -173,6 +173,54 @@ def test_prometheus_labeled_round_trip_with_escaping(tmp_path):
     assert (tmp_path / "m.prom").read_text() == text
 
 
+@pytest.mark.parametrize("fsync", [True, False])
+def test_registry_export_fsyncs_only_when_asked(tmp_path, monkeypatch,
+                                                fsync):
+    """``Telemetry(fsync=False)`` (the benchmark's traced window) stopped
+    the rows' and the trace's fsync while every registry export still
+    fsynced ``metrics.prom``: the flag now reaches ``write_prometheus``
+    (whose own default stays durable), and the file is whole either way."""
+    synced = []
+    monkeypatch.setattr(os, "fsync", lambda fd: synced.append(fd))
+    tel = Telemetry(tmp_path, fsync=fsync, enabled=True)
+    tel.registry.counter("steps_total").inc(3)
+    tel.flush()
+    tel.close()
+    assert bool(synced) is fsync
+    assert "steps_total 3" in (tmp_path / "metrics.prom").read_text()
+    assert not (tmp_path / "metrics.prom.tmp").exists()
+
+
+def test_an_adopted_registry_is_exported_live_and_wins_a_shared_series():
+    """``MetricsRegistry.adopt``: the other registry's instruments are
+    exported with this one's as they stand at each export (no copy, no
+    second increment), once however often adopted; a series both hold —
+    a resume rehydrated the prior attempt's — is exported once, the
+    adopted registry's."""
+    own, other = MetricsRegistry(), MetricsRegistry()
+    own.counter("steps_total").inc(3)
+    own.counter("jax_programs_total", phase="startup").inc(40)  # rehydrated
+    other.counter("jax_programs_total", phase="startup").inc(2)
+    own.adopt(other)
+    own.adopt(other)
+    own.adopt(own)
+    other.counter("jax_programs_total", phase="train").inc()  # made later
+    other.gauge("process_age_at_import_s").set(1.5)
+    snap = own.snapshot()
+    assert {(c["name"], c["labels"].get("phase")): c["value"]
+            for c in snap["counters"]} == {
+        ("steps_total", None): 3, ("jax_programs_total", "startup"): 2,
+        ("jax_programs_total", "train"): 1}
+    assert len(snap["counters"]) == 3
+    assert [g["value"] for g in snap["gauges"]] == [1.5]
+    text = own.to_prometheus()
+    assert text.count("# TYPE jax_programs_total counter") == 1
+    assert text.count('jax_programs_total{phase="startup"}') == 1
+    assert 'jax_programs_total{phase="startup"} 2' in text
+    assert other.snapshot()["counters"] == [
+        c for c in snap["counters"] if c["name"] != "steps_total"]
+
+
 def test_prometheus_histogram_bucket_round_trip():
     """Labeled histograms: bucket bounds strictly ascending with +Inf
     last, cumulative counts non-decreasing and ending at _count, _sum
