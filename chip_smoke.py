@@ -60,12 +60,16 @@ KERNEL_SHAPES = dict(
     matmul=(8, 2048, 8192),               # decode rows × d_model × d_ff
     codec_len=1_600_000,                  # a VGG-11 ring chunk at world 4
     delta_len=1000, delta_heads=4,        # 16 chunks, the last one padded
+    prepare_len=8192, prepare_heads=32,   # the hybrid cell's DeltaNet layer
     ring_seq_per_chip=1024, ring_batch=1,
 )
 # On the MXU both kernel and reference multiply in bf16, so the f32
 # tolerances of the CPU parity tests do not apply; these are the bf16 /
 # int8 ones the repo states (tests/test_quant.py, test_decode_attention).
 BF16_TOL = 2e-2
+# Float32 against float32 where both sides ask for full precision: a single
+# bf16 pass in place of a float32 product reads 4e-3.
+F32_TOL = 1e-5
 INT8_KV_TOL = 5e-2
 
 
@@ -411,8 +415,9 @@ def phase_kernels(shapes=KERNEL_SHAPES, kernels: str = "compiled") -> None:
                  "build")
     print("[chip_smoke]   ring_codec: 5 seams bit-equal to the XLA build")
 
-    # gdn_state_fwd / gdn_state_bwd: at head widths of 128 the delta rule's
-    # dispatch takes them on a TPU (and the scan anywhere else).
+    # gdn_prepare_* / gdn_state_*: at head widths of 128 the delta rule's
+    # dispatch takes the kernels on a TPU (and the mapped functions and the
+    # scan anywhere else).
     from distributed_machine_learning_tpu.ops import delta_rule
 
     Td, Hd = shapes["delta_len"], shapes["delta_heads"]
@@ -440,6 +445,54 @@ def phase_kernels(shapes=KERNEL_SHAPES, kernels: str = "compiled") -> None:
                           jax.tree_util.tree_leaves(want)):
         scale = float(jnp.abs(w).max())
         _close(f"gated_delta_rule[{name}]", g / scale, w / scale, BF16_TOL)
+
+    # gdn_prepare_fwd / gdn_prepare_bwd alone, against the batched
+    # solve_triangular and jax.vjp of it: W and the cotangents of q, k, v
+    # round to bf16 on both sides; U and the cotangents of g and β are
+    # float32, so they show the precision of the inverse and of the products
+    # with it.  Interpret mode computes float32 products exactly and cannot
+    # show a bf16 pass Mosaic put in their place.
+    from distributed_machine_learning_tpu.ops.pallas import gdn_prepare
+
+    Tp, Hp = shapes["prepare_len"], shapes["prepare_heads"]
+    qp, kp = (unit(normal((1, Tp, Hp, 128), jnp.float32)) for _ in range(2))
+    prepare_args = ((qp * 128 ** -0.5).astype(bf16), kp.astype(bf16),
+                    normal((1, Tp, Hp, 128)),
+                    -0.1 * jax.nn.softplus(normal((1, Tp, Hp), jnp.float32)),
+                    jax.nn.sigmoid(normal((1, Tp, Hp), jnp.float32)))
+    fold = lambda a: a.reshape(a.shape[0], -1, *a.shape[3:])
+    solve = lambda *a: delta_rule._prepare(*a, delta_rule.CHUNK)
+    tiles = lambda *a: map(fold, delta_rule._tiles(*a, delta_rule.CHUNK))
+    want = _exact(jax.jit(lambda *a: solve(*a)[:2]), *prepare_args)
+    got = _run_kernel(
+        "gdn_prepare_fwd",
+        lambda *a: gdn_prepare.prepare_fwd(*tiles(*a))[:2],
+        prepare_args, kernels)
+    for name, g, w, tol in zip(("W", "U"), got, want, (BF16_TOL, F32_TOL)):
+        scale = float(jnp.abs(w).max())
+        _close(f"gdn_prepare_fwd[{name}]", g.astype(jnp.float32) / scale,
+               fold(w).astype(jnp.float32) / scale, tol)
+
+    cotangents = tuple(normal(a.shape, a.dtype)
+                       for a in jax.eval_shape(solve, *prepare_args))
+
+    def from_tiles(args, grads):
+        made, undo = jax.vjp(
+            lambda *a: delta_rule._tiles(*a, delta_rule.CHUNK), *args)
+        return undo(tuple(g.reshape(t.shape) for g, t in zip(grads, made)))
+
+    want = _exact(jax.jit(lambda args, cts: jax.vjp(solve, *args)[1](cts)),
+                  prepare_args, cotangents)
+    got = _run_kernel(
+        "gdn_prepare_bwd",
+        lambda args, cts: from_tiles(args, gdn_prepare.prepare_bwd(
+            *tiles(*args), *map(fold, cts))),
+        (prepare_args, cotangents), kernels)
+    for name, g, w in zip(("dq", "dk", "dv", "dg", "dbeta"), got, want):
+        scale = float(jnp.abs(w.astype(jnp.float32)).max())
+        _close(f"gdn_prepare_bwd[{name}]", g.astype(jnp.float32) / scale,
+               w.astype(jnp.float32) / scale,
+               F32_TOL if w.dtype == jnp.float32 else BF16_TOL)
 
     # ring_flash_attention: a ring needs more than one chip.
     n = jax.device_count()

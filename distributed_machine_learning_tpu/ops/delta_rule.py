@@ -17,16 +17,20 @@ step at a time — what the tests compare against.
 (Yang et al., "Gated Delta Networks", arXiv:2412.06464, §3.3; the WY/UT
 form), in three parts.
 
-**Preparation** (``_prepare``; XLA, batched over all chunks).  With ``γ_i =
-Σ_{t<=i} g_t`` inside a chunk and ``S₀`` the state entering it, the
-corrections ``u_i = Δ_i`` of a chunk solve one unit lower-triangular system::
+**Preparation** — everything local to one chunk of one head
+(``ops/pallas/gdn_prepare.py``: ``prepare_chunk``).  With ``γ_i = Σ_{t<=i}
+g_t`` inside a chunk and ``S₀`` the state entering it, the corrections ``u_i
+= Δ_i`` of a chunk solve one unit lower-triangular system::
 
     (I + tril(β_i · k_i·k_j · exp(γ_i − γ_j), −1)) [W | U] = [β k exp(γ) | β v]
     u = U − W S₀
 
-so the triangular solves of all chunks run at once, and with them ``attn =
-tril(q_i·k_j · exp(γ_i − γ_j))``, ``q_in = q exp(γ)``, ``k_out = k exp(γ_C −
-γ)`` and ``d = exp(γ_C)``.
+whose inverse ``T`` is made in blocks (substitution inside 16 × 16 diagonal
+blocks, the rest by ``T₂₁ = −T₂₂ A₂₁ T₁₁``), so ``W = T (β k exp(γ))``, ``U =
+T (β v)``, and with them ``attn = tril(q_i·k_j · exp(γ_i − γ_j))``, ``q_in = q
+exp(γ)``, ``k_out = k exp(γ_C − γ)`` and ``d = exp(γ_C)``.  :func:`_prepare`
+is the same with ``jax.scipy``'s ``solve_triangular``, batched over all
+chunks: what the tests compare the blocks with.
 
 **The state pass** — all that crosses chunks, one chunk after the other::
 
@@ -43,21 +47,27 @@ first with the cotangent ``dS`` of the state as its carry::
 
 and ``dU = du``, ``dW = −du S₀ᵀ``, ``dk_out = u dS₁ᵀ``, ``dq_in = do S₀ᵀ``,
 ``dattn = do uᵀ``, ``dd = ⟨dS₁, S₀⟩`` in the same step.  The backward pass
-keeps the five inputs only: it makes the preparation again (its own
-gradient is ``jax.vjp`` of ``_prepare``) and the forward states again.
+keeps the five inputs only: it makes the preparation and the forward states
+again, and the preparation's own reverse is written by hand too
+(``prepare_chunk_bwd``: ``dA = −strict(Tᵀ dW Wᵀ + Tᵀ dU Uᵀ)``, never through
+the inversion).
 
-The state pass has two implementations of one arithmetic
-(``ops/pallas/gdn_state.py``: ``fwd_step``, ``read_out``, ``bwd_step``), and
+Preparation and state pass each have two implementations of one arithmetic
+(``gdn_prepare.py``: ``prepare_chunk``, ``prepare_chunk_bwd``;
+``gdn_state.py``: ``fwd_step``, ``read_out``, ``bwd_step``), and
 :func:`state_pass` says which a call gets, from what it can see: Pallas
-kernels that hold the state in VMEM for the whole pass (``gdn_state_fwd``,
-``gdn_state_bwd``) where the head widths are multiples of 128, the chunk is
-64 and the platform is a TPU; otherwise ``lax.scan`` over the same step
-functions, mapped over batch and heads (test widths, CPU runs: the Pallas
-interpreter walks a 128-step grid slowly).
+kernels (``gdn_prepare_fwd``, ``gdn_prepare_bwd``: a chunk of a block of
+heads visits VMEM once; ``gdn_state_fwd``, ``gdn_state_bwd``: the state
+stays in VMEM for the whole pass) where the head widths are multiples of
+128, the chunk is 64 and the platform is a TPU; otherwise the same functions
+mapped over chunks, batch and heads in XLA, the state pass as a ``lax.scan``
+(test widths, CPU runs: the Pallas interpreter walks a 128-step grid
+slowly).
 
-The triangular system, the decays and the state are float32; the matmuls
-take their operands in the inputs' dtype (bf16 on the MXU) and accumulate in
-float32.  A length that is no multiple of the chunk is padded with steps
+The triangular system, the decays and the state are float32 (``γ`` summed
+in two parts, so that ``exp(γ_i − γ_j)`` stays float32-exact where the decay
+vanishes and ``γ`` runs to the hundreds); the matmuls take their operands in
+the inputs' dtype (bf16 on the MXU) and accumulate in float32.  A length that is no multiple of the chunk is padded with steps
 that leave the state alone (``β = 0, g = 0``).
 """
 
@@ -69,7 +79,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_machine_learning_tpu.ops.pallas import gdn_state
+from distributed_machine_learning_tpu.ops.pallas import gdn_prepare, gdn_state
 from distributed_machine_learning_tpu.ops.pallas.common import interpret
 
 CHUNK = 64
@@ -98,10 +108,11 @@ def gated_delta_rule_recurrent(q, k, v, g, beta):
 
 
 def state_pass(dk: int, dv: int, chunk: int, platform: str) -> str:
-    """Which implementation of the state pass a call gets: ``"kernel"``
-    (``ops/pallas/gdn_state.py``'s ``pallas_call``s) or ``"scan"``
-    (``lax.scan`` over the same step functions).  The kernels tile the state
-    in (8, 128) registers and 128-wide MXU passes and were written for the
+    """Which implementation of the preparation and of the state pass a call
+    gets: ``"kernel"`` (the ``pallas_call``s of ``ops/pallas/gdn_prepare.py``
+    and ``gdn_state.py``) or ``"scan"`` (the same chunk and step functions
+    mapped in XLA, the state pass under ``lax.scan``).  The kernels tile in
+    (8, 128) registers and 128-wide MXU passes and were written for the
     chunk of 64; anywhere but on a TPU Pallas interprets, a grid step at a
     time."""
     fits = dk % 128 == 0 and dv % 128 == 0 and chunk == 64
@@ -125,8 +136,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
 
 @partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _chunked(q, k, v, g, beta, chunk):
-    W, U, attn, q_in, k_out, d = _prepare(q, k, v, g, beta, chunk)
-    o = _pass(_kind(W, U), W, U, k_out, d, q_in, attn)  # [nc, B, H, C, dv]
+    kind = _kind(q, v, chunk)
+    tiles = _tiles(q, k, v, g, beta, chunk)
+    W, U, attn, q_in, k_out, d = _prepared(kind, tiles)
+    o = _pass(kind, W, U, k_out, d, q_in, attn)          # [nc, B, H, C, dv]
     B, H, T = q.shape[0], q.shape[2], q.shape[1]
     o = jnp.moveaxis(o, (0, 2), (1, 3))                  # [B, nc, C, H, dv]
     return o.reshape(B, -1, H, o.shape[-1])[:, :T]
@@ -141,13 +154,15 @@ def _chunked_bwd(chunk, inputs, do):
     # made: without the barrier it keeps that (0.7 GB a layer) instead.  The
     # barrier also ties the inputs to ``do``: nothing starts before it.
     inputs, do = lax.optimization_barrier((inputs, do))
-    (W, U, attn, q_in, k_out, d), prepare_vjp = jax.vjp(
-        partial(_prepare, chunk=chunk), *inputs)
-    kind = _kind(W, U)
+    kind = _kind(inputs[0], inputs[2], chunk)
+    # ``from_tiles`` undoes the chunking, the padding and the casts.
+    tiles, from_tiles = jax.vjp(partial(_tiles, chunk=chunk), *inputs)
+    W, U, attn, q_in, k_out, d = _prepared(kind, tiles)
     S, u = _pass(kind, W, U, k_out, d)
     dU, dW, dk_out, dq_in, dattn, dd = _reverse_pass(
         kind, S, u, W, k_out, q_in, attn, _chunks(do, chunk), d)
-    return prepare_vjp((dW, dU, dattn, dq_in, dk_out, dd))
+    return from_tiles(_prepared(
+        kind, tiles, (dW, dU, dattn, dq_in, dk_out, dd)))
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
@@ -164,8 +179,17 @@ def _chunks(a, chunk):
     return jnp.moveaxis(a, (1, 3), (0, 2))
 
 
+def _tiles(q, k, v, g, beta, chunk):
+    """The five inputs as the preparation kernels take them: ``q, k, v``
+    [nc, B, H, C, d], ``g`` and ``beta`` float32 rows, [nc, B, H, 1, C]."""
+    row = lambda a: _chunks(a.astype(jnp.float32), chunk)[..., None, :]
+    return (*(_chunks(a, chunk) for a in (q, k, v)), row(g), row(beta))
+
+
 def _prepare(q, k, v, g, beta, chunk):
-    """Everything chunk-local, batched over the chunks: ``W`` [nc, B, H, C,
+    """The preparation with ``solve_triangular``, batched over the chunks —
+    no call takes it since ``gdn_prepare.prepare_chunk``; it is what the
+    tests hold that to.  ``W`` [nc, B, H, C,
     dk], ``U`` float32 [nc, B, H, C, dv], ``attn`` [nc, B, H, C, C],
     ``q_in``, ``k_out`` [nc, B, H, C, dk] of the module docstring, and
     ``d`` float32 [nc, B, H, 1, dv]: a head's number as a row of equal
@@ -203,10 +227,23 @@ def _prepare(q, k, v, g, beta, chunk):
     return W, U, attn, q_in, k_out, d
 
 
-def _kind(W, U):
-    chunk, dk = W.shape[-2:]
-    return state_pass(dk, U.shape[-1], chunk,
+def _kind(q, v, chunk):
+    return state_pass(q.shape[-1], v.shape[-1], chunk,
                       "cpu" if interpret() else "tpu")
+
+
+def _prepared(kind, tiles, cotangents=None):
+    """The chunk-local preparation of ``_tiles``' arrays: ``W, U, attn,
+    q_in, k_out, d`` over [nc, B, H, ...].  With the ``cotangents`` of those
+    six: the cotangents of the tiles."""
+    reverse = cotangents is not None
+    if kind == "kernel":
+        kernels = (gdn_prepare.prepare_bwd if reverse
+                   else gdn_prepare.prepare_fwd)
+        return _through_kernels(kernels, tiles + (cotangents or ()))
+    chunk_fn = (gdn_prepare.prepare_chunk_bwd if reverse
+                else gdn_prepare.prepare_chunk)
+    return jax.vmap(_heads(chunk_fn))(*tiles, *(cotangents or ()))
 
 
 def _pass(kind, W, U, k_out, d, q_in=None, attn=None):

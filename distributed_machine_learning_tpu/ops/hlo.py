@@ -18,6 +18,9 @@ the TPU AOT target name the ops identically):
   (``train/lm_step.py``'s two ``grad_sync_*`` gauges).
 - :func:`flash_calls_from_hlo` — the flash-attention kernel calls the
   compiler kept, forward and backward (its ``flash_*_calls`` gauges).
+- :func:`gdn_prepare_calls_from_hlo` — the delta rule's preparation
+  kernels likewise (its ``gdn_prepare_*_calls`` gauges);
+  :func:`kernel_calls_from_hlo` is both in one reading.
 
 A schedule is not a device timeline: these prove what the executable
 *orders* under a collective, while what is really hidden is a chip's
@@ -345,6 +348,31 @@ _MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 _PALLAS_SCOPE_RE = re.compile(r'op_name="(?:[^"]*/)?([^"/]+)/pallas_call"')
 
 
+def _mosaic_kernels(hlo_text: str):
+    """The kernel name (``name=``) of every Mosaic custom call in the text,
+    in order, less the transformations that traced it."""
+    for line in hlo_text.splitlines():
+        scope = _MOSAIC_CALL in line and _PALLAS_SCOPE_RE.search(line)
+        if scope:
+            yield re.sub(r"\w+\(|\)", "", scope.group(1))
+
+
+_FLASH = ("flash_fwd", "flash_bwd")
+_GDN_PREPARE = ("gdn_prepare_fwd", "gdn_prepare_bwd")
+
+
+def _calls_by_prefix(hlo_text: str, prefixes) -> dict:
+    kernels = list(_mosaic_kernels(hlo_text))
+    return {f"{prefix}_calls": sum(k.startswith(prefix) for k in kernels)
+            for prefix in prefixes}
+
+
+def kernel_calls_from_hlo(hlo_text: str) -> dict:
+    """:func:`flash_calls_from_hlo` and :func:`gdn_prepare_calls_from_hlo`
+    in one reading of the text: what a step publishes."""
+    return _calls_by_prefix(hlo_text, _FLASH + _GDN_PREPARE)
+
+
 def flash_calls_from_hlo(hlo_text: str) -> dict:
     """``{"flash_fwd_calls", "flash_bwd_calls"}``: the module's Mosaic
     custom calls whose kernel name starts with ``flash_fwd`` / ``flash_bwd``
@@ -353,12 +381,13 @@ def flash_calls_from_hlo(hlo_text: str) -> dict:
     recomputed without the kernel's ``(out, lse)`` shows each layer's
     forward call twice.  Counted in the text — a call in a loop's body is
     one; off the TPU the kernels are interpreted and both read 0."""
-    calls = {"flash_fwd_calls": 0, "flash_bwd_calls": 0}
-    for line in hlo_text.splitlines():
-        scope = _MOSAIC_CALL in line and _PALLAS_SCOPE_RE.search(line)
-        if not scope:
-            continue
-        kernel = re.sub(r"\w+\(|\)", "", scope.group(1))
-        for kind in ("fwd", "bwd"):
-            calls[f"flash_{kind}_calls"] += kernel.startswith(f"flash_{kind}")
-    return calls
+    return _calls_by_prefix(hlo_text, _FLASH)
+
+
+def gdn_prepare_calls_from_hlo(hlo_text: str) -> dict:
+    """``{"gdn_prepare_fwd_calls", "gdn_prepare_bwd_calls"}``: the delta
+    rule's preparation kernels (``ops/pallas/gdn_prepare.py``) among the
+    module's Mosaic calls — two and one a DeltaNet layer of a train step
+    (forward, made again; the reverse).  0 and 0 in a step with such
+    layers: ``ops/delta_rule.py::state_pass`` said ``"scan"``."""
+    return _calls_by_prefix(hlo_text, _GDN_PREPARE)

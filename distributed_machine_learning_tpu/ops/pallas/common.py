@@ -55,6 +55,18 @@ _interpret = interpret
 #: VMEM lane width — the last dim of every kernel tile.
 LANES = 128
 
+#: ``lax.dot_general`` dimension numbers of two matrices.
+NN = (((1,), (0,)), ((), ()))  # a · b
+TN = (((0,), (0,)), ((), ()))  # aᵀ · b
+NT = (((1,), (1,)), ((), ()))  # a · bᵀ
+
+
+def dot(a, b, dims):
+    """A product that accumulates in float32 whatever its operands are."""
+    import jax.numpy as jnp
+
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
 
 def padded_lane_rows(length: int, row_quantum: int) -> int:
     """Rows of a ``[rows, LANES]`` view of a flat ``[length]`` vector,

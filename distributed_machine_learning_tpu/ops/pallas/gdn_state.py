@@ -44,10 +44,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
 from distributed_machine_learning_tpu.ops.pallas.common import (
+    NN,
+    NT,
+    TN,
+    dot,
     interpret,
     pick_block,
     pltpu,
@@ -60,29 +63,20 @@ from distributed_machine_learning_tpu.ops.pallas.common import (
 #: §6, PR 30); the block only sets how many grid steps pay the step overhead.
 HEAD_BLOCK = 16
 
-_NN = (((1,), (0,)), ((), ()))  # a · b
-_TN = (((0,), (0,)), ((), ()))  # aᵀ · b
-_NT = (((1,), (1,)), ((), ()))  # a · bᵀ
-
-
-def _dot(a, b, dims):
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
-
-
 def fwd_step(S, W, U, k_out, d):
     """One head, one chunk.  ``S``: the state entering, float32 [dk, dv];
     ``W`` [C, dk], ``k_out`` [C, dk] in the operands' dtype; ``U`` float32
     [C, dv]; ``d`` float32 [1, dv], a row of equal lanes.  Returns the state
     leaving and ``u`` in the operands' dtype."""
     dt = W.dtype
-    u = (U - _dot(W, S.astype(dt), _NN)).astype(dt)
-    return S * d + _dot(k_out, u, _TN), u
+    u = (U - dot(W, S.astype(dt), NN)).astype(dt)
+    return S * d + dot(k_out, u, TN), u
 
 
 def read_out(S, u, q_in, attn):
     """``o = q_in·S + attn·u`` of the chunk that ``S`` enters: float32
     [C, dv]."""
-    return _dot(q_in, S.astype(u.dtype), _NN) + _dot(attn, u, _NN)
+    return dot(q_in, S.astype(u.dtype), NN) + dot(attn, u, NN)
 
 
 def bwd_step(dS, S, u, W, k_out, q_in, attn, do, d):
@@ -94,15 +88,15 @@ def bwd_step(dS, S, u, W, k_out, q_in, attn, do, d):
     q_in, attn`` and of ``d`` as the row of lanes it came as."""
     dt = W.dtype
     S_in, dS_in = S.astype(dt), dS.astype(dt)
-    dU = _dot(attn, do, _TN) + _dot(k_out, dS_in, _NN)
+    dU = dot(attn, do, TN) + dot(k_out, dS_in, NN)
     du = dU.astype(dt)
-    dS_new = dS * d + _dot(q_in, do, _TN) - _dot(W, du, _TN)
+    dS_new = dS * d + dot(q_in, do, TN) - dot(W, du, TN)
     return dS_new, (
         dU,
-        (-_dot(du, S_in, _NT)).astype(dt),
-        _dot(u, dS_in, _NT).astype(dt),
-        _dot(do, S_in, _NT).astype(dt),
-        _dot(do, u, _NT).astype(dt),
+        (-dot(du, S_in, NT)).astype(dt),
+        dot(u, dS_in, NT).astype(dt),
+        dot(do, S_in, NT).astype(dt),
+        dot(do, u, NT).astype(dt),
         jnp.sum(dS * S, axis=0, keepdims=True),  # each lane's own
     )
 

@@ -359,9 +359,12 @@ class _StepWithSyncGauges:
     and writes the gauges ``grad_sync_bytes`` (bytes a chip all-reduces a
     step: the gradients, the loss, a model's counts),
     ``grad_sync_async_bytes`` (those that go through asynchronous
-    collectives) and ``flash_fwd_calls`` / ``flash_bwd_calls`` (the flash
+    collectives), ``flash_fwd_calls`` / ``flash_bwd_calls`` (the flash
     kernel calls the compiler kept: equal unless a recomputed block runs a
-    forward kernel twice).  Getting at the text compiles the step a second
+    forward kernel twice) and ``gdn_prepare_fwd_calls`` /
+    ``gdn_prepare_bwd_calls`` (the delta rule's preparation kernels: two and
+    one a DeltaNet layer; 0 where ``ops/delta_rule.py::state_pass`` said
+    ``"scan"``).  Getting at the text compiles the step a second
     time (the persistent cache serves it), so nothing is read without a
     ``Telemetry``: an unobserved run pays one pointer test a step."""
 
@@ -379,11 +382,7 @@ class _StepWithSyncGauges:
         return self._jitted(state, tokens, targets)
 
     def _publish(self, *args) -> None:
-        from distributed_machine_learning_tpu.ops.hlo import (
-            all_reduces_from_hlo,
-            flash_calls_from_hlo,
-            grad_sync_bytes,
-        )
+        from distributed_machine_learning_tpu.ops import hlo
 
         from distributed_machine_learning_tpu.telemetry import startup
 
@@ -392,8 +391,9 @@ class _StepWithSyncGauges:
         with startup.span("startup.hlo_gauges"):
             text = self._jitted.lower(*args).compile().as_text()
         registry = self._get_telemetry().registry
-        gauges = {**grad_sync_bytes(all_reduces_from_hlo(text)),
-                  **flash_calls_from_hlo(text)}
+        gauges = {
+            **hlo.grad_sync_bytes(hlo.all_reduces_from_hlo(text)),
+            **hlo.kernel_calls_from_hlo(text)}
         for name, value in gauges.items():
             registry.gauge(name).set(value)
 

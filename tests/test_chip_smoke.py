@@ -73,6 +73,7 @@ def test_kernels_phase_tiny(smoke, devices):
         dict(heads=4, kv_heads=2, head_dim=32, cache_len=128, cache_batch=1,
              cache_pos=100, page_block=16, pages=20, page_positions=(3, 40),
              matmul=(8, 64, 128), codec_len=1237, delta_len=70, delta_heads=1,
+             prepare_len=128, prepare_heads=2,
              ring_seq_per_chip=16, ring_batch=1),
         kernels="interpreted",
     )

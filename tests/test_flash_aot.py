@@ -1,7 +1,8 @@
 """AOT Mosaic compiles of the backward flash kernels at the cells' real
 shapes, of the windowed kernels, forward and backward, at the window
-cell's, and of a small LM step whose flash calls the ``flash_*_calls``
-gauges count, for a described ``v5e:2x2`` device — no chip.
+cell's, of the gated delta rule's gradient at the hybrid cell's, and of a
+small LM step whose flash calls the ``flash_*_calls`` gauges count, for a
+described ``v5e:2x2`` device — no chip.
 
 What interpret mode cannot show: that the fused kernel's resident dq, its
 ``(1, L, D)`` output block and its ``vmem_limit_bytes`` are legal and fit at
@@ -131,6 +132,40 @@ def test_windowed_kernels_compile_for_v5e(one_chip, monkeypatch, name):
         r"(?<![A-Za-z_])flash_(?:fwd|bwd_[a-z]+)(?:_w\d+)?", text)
     assert set(kernels) == {
         base + suffix for base in {"flash_fwd"} | backward}
+
+
+def test_the_delta_rules_gradient_compiles_for_v5e(one_chip, monkeypatch):
+    """``jax.grad`` of ``gated_delta_rule`` at ``q3next_a3b_dp_s8192``'s
+    shape: the preparation kernels' blocks, their float32 contractions and
+    what a grid step keeps in VMEM pass Mosaic, and the compiled text holds
+    the preparation twice (forward, made again), its reverse once, the state
+    kernels likewise and no triangular solve."""
+    from distributed_machine_learning_tpu.ops import delta_rule, hlo
+    from distributed_machine_learning_tpu.ops.pallas import (
+        common,
+        gdn_prepare,
+        gdn_state,
+    )
+
+    for module in (common, gdn_state, gdn_prepare, delta_rule):
+        monkeypatch.setattr(module, "interpret", lambda: False)
+    wide = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    number = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32,
+                                  sharding=one_chip)
+    # A loss whose gradient needs the output: a plain sum's would let the
+    # compiler drop the forward pass.
+    loss = lambda *a: jnp.sum(jnp.square(
+        delta_rule.gated_delta_rule(*a).astype(jnp.float32)))
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        wide, wide, wide, number, number).compile().as_text()
+    kernels = list(hlo._mosaic_kernels(text))
+    assert {name: kernels.count(name) for name in set(kernels)} == {
+        "gdn_prepare_fwd": 2, "gdn_prepare_bwd": 1,
+        "gdn_state_fwd": 2, "gdn_state_bwd": 1}
+    assert hlo.gdn_prepare_calls_from_hlo(text) == {
+        "gdn_prepare_fwd_calls": 2, "gdn_prepare_bwd_calls": 1}
+    assert "triangular" not in text.lower()
 
 
 @pytest.mark.parametrize("remat", [None, "mlp", "block"], ids=str)

@@ -17,7 +17,9 @@ from jax.sharding import PartitionSpec as P
 from distributed_machine_learning_tpu.ops.hlo import (
     all_reduces_from_hlo,
     flash_calls_from_hlo,
+    gdn_prepare_calls_from_hlo,
     grad_sync_bytes,
+    kernel_calls_from_hlo,
 )
 from distributed_machine_learning_tpu.models import hybrid_moe as hm
 from distributed_machine_learning_tpu.models.transformer import TransformerLM
@@ -385,6 +387,39 @@ def test_walker_counts_the_flash_kernels_calls(scopes, expected):
         "  ROOT %mul = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n")
     assert flash_calls_from_hlo(text) == dict(
         zip(("flash_fwd_calls", "flash_bwd_calls"), expected))
+
+
+@pytest.mark.parametrize("scopes, solve, expected", [
+    # one DeltaNet layer of a train step: forward, made again, the reverse
+    (["gdn_prepare_fwd", "gdn_state_fwd", "jvp(flash_fwd)",
+      "gdn_prepare_fwd", "gdn_state_fwd", "gdn_state_bwd",
+      "gdn_prepare_bwd"], False, (2, 1)),
+    # inside a recomputed block the names keep their transformations
+    (["checkpoint/gdn_prepare_fwd", "rematted_computation/gdn_prepare_fwd",
+      "transpose(jvp(gdn_prepare_bwd))"], False, (2, 1)),
+    # no kernel (the dispatch said "scan", or an older program's solve)
+    (["jvp(flash_fwd)", "transpose(jvp(flash_bwd_fused))"], True, (0, 0)),
+])
+def test_walker_counts_the_preparation_kernels_calls(scopes, solve, expected):
+    text = _module(
+        "".join(_mosaic_call(f"call.{i}", scope)
+                for i, scope in enumerate(scopes))
+        + ("  %ts = f32[128,1,32,1,64,64]{5,4,3,2,1,0} custom-call(%p0), "
+           'custom_call_target="InvertDiagBlocksLowerTriangular", '
+           'metadata={op_name="jit(step)/triangular_solve"}\n'
+           if solve else "")
+        # not a Mosaic call, whatever its scope says
+        + '  %cc = f32[256]{0} custom-call(%p1), custom_call_target="Sharding"'
+        ', metadata={op_name="jit(step)/gdn_prepare_fwd/pallas_call"}\n'
+        "  ROOT %mul = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n")
+    assert gdn_prepare_calls_from_hlo(text) == dict(
+        zip(("gdn_prepare_fwd_calls", "gdn_prepare_bwd_calls"), expected))
+    # the two readers share the text and do not count each other's kernels
+    assert flash_calls_from_hlo(text)["flash_fwd_calls"] == sum(
+        "flash_fwd" in scope for scope in scopes)
+    # what a step publishes: both, from one reading of the text
+    assert kernel_calls_from_hlo(text) == {
+        **flash_calls_from_hlo(text), **gdn_prepare_calls_from_hlo(text)}
 
 
 def test_the_audits_defaults_are_the_four_chip_cells_sizes():
