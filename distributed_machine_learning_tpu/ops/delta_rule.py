@@ -65,8 +65,9 @@ first with the cotangent ``dS`` of the state as its carry::
 
 and ``dU = du``, ``dW = −du S₀ᵀ``, ``dk_out = u dS₁ᵀ``, ``dq_in = do S₀ᵀ``,
 ``dattn = do uᵀ``, ``dd = ⟨dS₁, S₀⟩`` in the same step.  The backward pass
-keeps the five inputs only: it makes the preparation and the forward states
-again, and the preparation's own reverse is written by hand too
+keeps the preparation's six arrays and the five inputs in their chunks; it
+makes the forward states again, and the preparation's own reverse is written
+by hand too
 (``prepare_chunk_bwd``: ``dA = −strict(Tᵀ dW Wᵀ + Tᵀ dU Uᵀ)``, never through
 the inversion); with a decay a channel it yields ``dg`` a channel.
 
@@ -153,48 +154,48 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     :func:`gated_delta_rule_recurrent` (``g`` [B, T, H] or [B, T, H, dk]);
     returns [B, T, H, dv] in ``v.dtype``.
 
-    The backward pass keeps the five inputs and computes the preparation
-    and the forward states again, as a flash-attention kernel recomputes
-    its probabilities: the chunk-local systems, their solutions and the
-    per-chunk states are float32 arrays of ``[T/C, B, H, C, dk + dv]`` and
-    ``[T/C, B, H, dk, dv]`` — some 2 GB a layer at 8192 tokens and 32 heads
-    of 128, against 0.2 GB of inputs — and cost a few small matmuls a chunk
-    to make again."""
-    return _chunked(q, k, v, g, beta, chunk)
+    The backward pass keeps the five inputs in their chunks and the six
+    arrays the preparation made of them — ``W, U, attn, q_in, k_out, d``,
+    0.35 GiB a layer at 8192 tokens and 32 heads of 128 — so each call
+    prepares once.  It makes again only what the state pass wrote: the
+    state entering each chunk (float32 ``[T/C, B, H, dk, dv]``) and ``u``,
+    0.38 GiB more, one pass of a few small matmuls a chunk.  A block
+    recomputed as a whole (``--remat --remat-policy block``) keeps none of
+    it and prepares twice."""
+    f32 = jnp.float32
+    return _chunked(q, k, v, g.astype(f32), beta.astype(f32), chunk,
+                    _kind(q, v, g, chunk))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _chunked(q, k, v, g, beta, chunk):
-    kind = _kind(q, v, g, chunk)
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _chunked(q, k, v, g, beta, chunk, kind):
+    return _forward(q, k, v, g, beta, chunk, kind)[0]
+
+
+def _forward(q, k, v, g, beta, chunk, kind):
+    """The rule's output, and what its backward pass keeps: the five tiles
+    and the six arrays the preparation made of them."""
     tiles = _tiles(q, k, v, g, beta, chunk)
-    W, U, attn, q_in, k_out, d = _prepared(kind, tiles)
+    prepared = W, U, attn, q_in, k_out, d = _prepared(kind, tiles)
     o = _pass(kind, W, U, k_out, d, q_in, attn)          # [nc, B, H, C, dv]
     B, H, T = q.shape[0], q.shape[2], q.shape[1]
     o = jnp.moveaxis(o, (0, 2), (1, 3))                  # [B, nc, C, H, dv]
-    return o.reshape(B, -1, H, o.shape[-1])[:, :T]
+    return o.reshape(B, -1, H, o.shape[-1])[:, :T], (tiles, prepared)
 
 
-def _chunked_fwd(q, k, v, g, beta, chunk):
-    return _chunked(q, k, v, g, beta, chunk), (q, k, v, g, beta)
-
-
-def _chunked_bwd(chunk, inputs, do):
-    # What is made again here is, to the compiler, what the forward pass
-    # made: without the barrier it keeps that (0.7 GB a layer) instead.  The
-    # barrier also ties the inputs to ``do``: nothing starts before it.
-    inputs, do = lax.optimization_barrier((inputs, do))
-    kind = _kind(inputs[0], inputs[2], inputs[3], chunk)
-    # ``from_tiles`` undoes the chunking, the padding and the casts.
-    tiles, from_tiles = jax.vjp(partial(_tiles, chunk=chunk), *inputs)
-    W, U, attn, q_in, k_out, d = _prepared(kind, tiles)
+def _chunked_bwd(chunk, kind, kept, do):
+    tiles, (W, U, attn, q_in, k_out, d) = kept
     S, u = _pass(kind, W, U, k_out, d)
     dU, dW, dk_out, dq_in, dattn, dd = _reverse_pass(
         kind, S, u, W, k_out, q_in, attn, _chunks(do, chunk), d)
+    # The transpose of ``_tiles`` undoes the chunking and the padding.
+    from_tiles = jax.linear_transpose(
+        partial(_tiles, chunk=chunk), *_untiled(tiles, do.shape[1], kind))
     return from_tiles(_prepared(
         kind, tiles, (dW, dU, dattn, dq_in, dk_out, dd)))
 
 
-_chunked.defvjp(_chunked_fwd, _chunked_bwd)
+_chunked.defvjp(_forward, _chunked_bwd)
 
 
 def _chunks(a, chunk):
@@ -225,6 +226,17 @@ def _tiles(q, k, v, g, beta, chunk):
     tile = lambda a: _chunks(a.astype(jnp.float32), chunk)
     return (*(_chunks(a, chunk) for a in (q, k, v)),
             tile(g) if g.ndim == 4 else row(g), row(beta))
+
+
+def _untiled(tiles, T, kind):
+    """Shape and dtype of the five inputs of ``T`` steps that ``_tiles``
+    made ``tiles`` of (``g`` and ``beta`` float32, as the rule takes
+    them)."""
+    B, H = tiles[0].shape[1:3]
+    like = lambda a, *width: jax.ShapeDtypeStruct((B, T, H, *width), a.dtype)
+    q, k, v, g, beta = tiles
+    return (like(q, q.shape[-1]), like(k, k.shape[-1]), like(v, v.shape[-1]),
+            like(g, g.shape[-1]) if kind.per_channel else like(g), like(beta))
 
 
 def _prepare(q, k, v, g, beta, chunk):

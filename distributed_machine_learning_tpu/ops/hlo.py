@@ -18,8 +18,9 @@ the TPU AOT target name the ops identically):
   (``train/lm_step.py``'s two ``grad_sync_*`` gauges).
 - :func:`flash_calls_from_hlo` — the flash-attention kernel calls the
   compiler kept, forward and backward (its ``flash_*_calls`` gauges).
-- :func:`gdn_prepare_calls_from_hlo` — the delta rule's preparation
-  kernels likewise (its ``gdn_prepare_*_calls`` gauges);
+- :func:`prepare_calls_from_hlo` — the delta rule's preparation kernels
+  likewise, a decay a head and a decay a channel (its
+  ``gdn_prepare_*_calls`` and ``kda_prepare_*_calls`` gauges);
   :func:`kernel_calls_from_hlo` is both in one reading.
 
 A schedule is not a device timeline: these prove what the executable
@@ -358,7 +359,8 @@ def _mosaic_kernels(hlo_text: str):
 
 
 _FLASH = ("flash_fwd", "flash_bwd")
-_GDN_PREPARE = ("gdn_prepare_fwd", "gdn_prepare_bwd")
+_PREPARE = ("gdn_prepare_fwd", "gdn_prepare_bwd",
+            "kda_prepare_fwd", "kda_prepare_bwd")
 
 
 def _calls_by_prefix(hlo_text: str, prefixes) -> dict:
@@ -368,9 +370,9 @@ def _calls_by_prefix(hlo_text: str, prefixes) -> dict:
 
 
 def kernel_calls_from_hlo(hlo_text: str) -> dict:
-    """:func:`flash_calls_from_hlo` and :func:`gdn_prepare_calls_from_hlo`
-    in one reading of the text: what a step publishes."""
-    return _calls_by_prefix(hlo_text, _FLASH + _GDN_PREPARE)
+    """:func:`flash_calls_from_hlo` and :func:`prepare_calls_from_hlo` in
+    one reading of the text: what a step publishes."""
+    return _calls_by_prefix(hlo_text, _FLASH + _PREPARE)
 
 
 def flash_calls_from_hlo(hlo_text: str) -> dict:
@@ -384,10 +386,13 @@ def flash_calls_from_hlo(hlo_text: str) -> dict:
     return _calls_by_prefix(hlo_text, _FLASH)
 
 
-def gdn_prepare_calls_from_hlo(hlo_text: str) -> dict:
-    """``{"gdn_prepare_fwd_calls", "gdn_prepare_bwd_calls"}``: the delta
-    rule's preparation kernels (``ops/pallas/gdn_prepare.py``) among the
-    module's Mosaic calls — two and one a DeltaNet layer of a train step
-    (forward, made again; the reverse).  0 and 0 in a step with such
-    layers: ``ops/delta_rule.py::state_pass`` said ``"scan"``."""
-    return _calls_by_prefix(hlo_text, _GDN_PREPARE)
+def prepare_calls_from_hlo(hlo_text: str) -> dict:
+    """``{"gdn_prepare_fwd_calls", "gdn_prepare_bwd_calls",
+    "kda_prepare_fwd_calls", "kda_prepare_bwd_calls"}``: the delta rule's
+    preparation kernels (``ops/pallas/gdn_prepare.py``, a decay a head;
+    ``ops/pallas/kda_prepare.py``, a decay a key channel) among the module's
+    Mosaic calls — one and one a delta-rule layer of a train step (the
+    forward pass's preparation, kept for the backward pass; the reverse),
+    two and one where a block is recomputed as a whole.  0 in a step with
+    such layers: ``ops/delta_rule.py::state_pass`` said ``"scan"``."""
+    return _calls_by_prefix(hlo_text, _PREPARE)

@@ -362,8 +362,10 @@ class _StepWithSyncGauges:
     collectives), ``flash_fwd_calls`` / ``flash_bwd_calls`` (the flash
     kernel calls the compiler kept: equal unless a recomputed block runs a
     forward kernel twice) and ``gdn_prepare_fwd_calls`` /
-    ``gdn_prepare_bwd_calls`` (the delta rule's preparation kernels: two and
-    one a DeltaNet layer; 0 where ``ops/delta_rule.py::state_pass`` said
+    ``gdn_prepare_bwd_calls``, ``kda_prepare_fwd_calls`` /
+    ``kda_prepare_bwd_calls`` (the delta rule's preparation kernels, a decay
+    a head or a channel: one and one a layer, two and one in a block
+    recomputed as a whole; 0 where ``ops/delta_rule.py::state_pass`` said
     ``"scan"``).  Getting at the text compiles the step a second
     time (the persistent cache serves it), so nothing is read without a
     ``Telemetry``: an unobserved run pays one pointer test a step."""

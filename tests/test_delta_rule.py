@@ -3,6 +3,7 @@ recurrence it restates, forward and gradient."""
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 
 import jax
@@ -18,7 +19,7 @@ from distributed_machine_learning_tpu.ops.delta_rule import (
     gated_delta_rule_recurrent,
     state_pass,
 )
-from distributed_machine_learning_tpu.ops.pallas import gdn_prepare
+from distributed_machine_learning_tpu.ops.pallas import gdn_prepare, kda_prepare
 
 #: float32 on both sides; the two differ by the order of their sums and by
 #: the triangular solve's own rounding (64 steps of forward substitution).
@@ -160,10 +161,10 @@ def test_state_pass_is_chosen_from_shapes_and_platform(
 def test_the_preparation_follows_the_state_pass(
         monkeypatch, dk, dv, chunk, platform, kind):
     """One rule for both: where ``state_pass`` says ``"kernel"`` the traced
-    gradient holds the preparation kernel twice (forward, made again), its
-    reverse once and the state kernels likewise; elsewhere no kernel at all.
-    Traced only, on the platform the row names (anything but a TPU
-    interprets)."""
+    gradient holds the preparation kernel once (the backward pass takes the
+    forward pass's), its reverse once, the state kernels twice (forward,
+    made again) and once; elsewhere no kernel at all.  Traced only, on the
+    platform the row names (anything but a TPU interprets)."""
     monkeypatch.setattr(delta_rule, "interpret", lambda: platform != "tpu")
     args = _inputs(0, 2 * chunk, B=1, H=1, dk=dk, dv=dv)
     loss = lambda *a: gated_delta_rule(*a, chunk=chunk).sum()
@@ -172,13 +173,13 @@ def test_the_preparation_follows_the_state_pass(
         "gdn_prepare_fwd", "gdn_prepare_bwd", "gdn_state_fwd",
         "gdn_state_bwd")}
     assert list(calls.values()) == (
-        [2, 1, 2, 1] if kind == "kernel" else [0, 0, 0, 0])
+        [1, 1, 2, 1] if kind == "kernel" else [0, 0, 0, 0])
 
 
 def test_the_dispatch_asks_state_pass(monkeypatch):
-    """What ``state_pass`` says is what runs: the forward and the backward
-    pass each ask it with the call's own widths, and here — no TPU — with
-    ``"cpu"``."""
+    """What ``state_pass`` says is what runs: the call asks it with its own
+    widths, and here — no TPU — with ``"cpu"``; the backward pass takes
+    what the forward pass was told."""
     asked = []
 
     def record(*a):
@@ -189,6 +190,40 @@ def test_the_dispatch_asks_state_pass(monkeypatch):
     _value_and_grads(gated_delta_rule, _inputs(0, 70))
     assert asked and set(asked) == {(16, 24, CHUNK, "cpu")}
     assert state_pass(*asked[0]) == "scan"
+
+
+@pytest.mark.parametrize("per_channel", [False, True],
+                         ids=["scalar", "per_channel"])
+def test_the_scan_prepares_once_a_gradient(monkeypatch, per_channel):
+    """The XLA path keeps the preparation as the kernels do: under
+    ``jax.grad`` the chunk function is traced once forward and its reverse
+    once, a decay a head or a channel, and the gradient is the
+    recurrence's at a length no multiple of the chunk."""
+    module = kda_prepare if per_channel else gdn_prepare
+    traced = collections.Counter()
+
+    def counting(name):
+        chunk_fn = getattr(module, name)
+
+        def counted(*a, **kw):
+            traced[name] += 1
+            return chunk_fn(*a, **kw)
+        return counted
+
+    for name in ("prepare_chunk", "prepare_chunk_bwd"):
+        monkeypatch.setattr(module, name, counting(name))
+    q, k, v, g, beta = _inputs(15, 100)  # padded to 128
+    if per_channel:
+        g = -jax.nn.softplus(jax.random.normal(jax.random.PRNGKey(16),
+                                               q.shape))
+    args = q, k, v, g, beta
+    w = jax.random.normal(jax.random.PRNGKey(9), v.shape)
+    grads = [jax.grad(lambda *a: (f(*a) * w).sum(), argnums=range(5))(*args)
+             for f in (gated_delta_rule, gated_delta_rule_recurrent)]
+    assert traced == {"prepare_chunk": 1, "prepare_chunk_bwd": 1}
+    for name, a, b in zip("q k v g beta".split(), *grads):
+        assert a.shape == b.shape and jnp.isfinite(a).all(), name
+        assert _rel(a, b) < TOL, name
 
 
 @pytest.mark.parametrize("T", [2 * CHUNK, 150])
@@ -246,13 +281,16 @@ def test_padding_steps_are_inert_through_the_kernels(through_kernels):
 
 
 def test_backward_pass_keeps_the_five_inputs_only(through_kernels):
-    """The residuals of the forward pass are its inputs: what the backward
-    pass needs of the chunks it makes again."""
+    """Of the five inputs the forward pass keeps their tiles, and beside
+    them the preparation's six arrays — nothing the state pass wrote: the
+    per-chunk states and ``u`` are what the backward pass makes again."""
     args = _inputs(8, 2 * CHUNK, **WIDE)
     _, vjp = jax.vjp(gated_delta_rule, *args)
     kept = sorted(a.shape for a in jax.tree_util.tree_leaves(vjp)
                   if hasattr(a, "shape"))
-    assert kept == sorted(a.shape for a in args)
+    tiles = delta_rule._tiles(*args, CHUNK)
+    prepared = delta_rule._prepare(*args, CHUNK)
+    assert kept == sorted(a.shape for a in tiles + prepared)
 
 
 def _recurrence_in_float64(q, k, v, g, beta, w):

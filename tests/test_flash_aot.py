@@ -138,8 +138,9 @@ def test_the_delta_rules_gradient_compiles_for_v5e(one_chip, monkeypatch):
     """``jax.grad`` of ``gated_delta_rule`` at ``q3next_a3b_dp_s8192``'s
     shape: the preparation kernels' blocks, their float32 contractions and
     what a grid step keeps in VMEM pass Mosaic, and the compiled text holds
-    the preparation twice (forward, made again), its reverse once, the state
-    kernels likewise and no triangular solve."""
+    the preparation once (the forward pass's, kept for the backward pass),
+    its reverse once, the state kernels twice (forward, made again) and
+    once, and no triangular solve."""
     from distributed_machine_learning_tpu.ops import delta_rule, hlo
     from distributed_machine_learning_tpu.ops.pallas import (
         common,
@@ -161,10 +162,11 @@ def test_the_delta_rules_gradient_compiles_for_v5e(one_chip, monkeypatch):
         wide, wide, wide, number, number).compile().as_text()
     kernels = list(hlo._mosaic_kernels(text))
     assert {name: kernels.count(name) for name in set(kernels)} == {
-        "gdn_prepare_fwd": 2, "gdn_prepare_bwd": 1,
+        "gdn_prepare_fwd": 1, "gdn_prepare_bwd": 1,
         "gdn_state_fwd": 2, "gdn_state_bwd": 1}
-    assert hlo.gdn_prepare_calls_from_hlo(text) == {
-        "gdn_prepare_fwd_calls": 2, "gdn_prepare_bwd_calls": 1}
+    assert hlo.prepare_calls_from_hlo(text) == {
+        "gdn_prepare_fwd_calls": 1, "gdn_prepare_bwd_calls": 1,
+        "kda_prepare_fwd_calls": 0, "kda_prepare_bwd_calls": 0}
     assert "triangular" not in text.lower()
 
 
@@ -176,7 +178,7 @@ def test_the_per_channel_rules_gradient_compiles_for_v5e(one_chip,
     what a grid step of ``kda_prepare``'s head blocks keeps in VMEM pass
     Mosaic (at 8 reverse heads a step they do not: 31.3 MiB of 16), and the
     compiled text holds the per-channel kernels as the scalar rule holds
-    its own — preparation twice, reverse once — and none of the scalar
+    its own — preparation once, reverse once — and none of the scalar
     ones."""
     from distributed_machine_learning_tpu.ops import delta_rule, hlo
     from distributed_machine_learning_tpu.ops.pallas import (
@@ -200,8 +202,11 @@ def test_the_per_channel_rules_gradient_compiles_for_v5e(one_chip,
         wide, wide, wide, decay, number).compile().as_text()
     kernels = list(hlo._mosaic_kernels(text))
     assert {name: kernels.count(name) for name in set(kernels)} == {
-        "kda_prepare_fwd": 2, "kda_prepare_bwd": 1,
+        "kda_prepare_fwd": 1, "kda_prepare_bwd": 1,
         "kda_state_fwd": 2, "kda_state_bwd": 1}
+    assert hlo.prepare_calls_from_hlo(text) == {
+        "gdn_prepare_fwd_calls": 0, "gdn_prepare_bwd_calls": 0,
+        "kda_prepare_fwd_calls": 1, "kda_prepare_bwd_calls": 1}
     assert "triangular" not in text.lower()
 
 

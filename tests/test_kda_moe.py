@@ -6,6 +6,7 @@ chip's share identity of the expert layer, and the model on the normal path:
 
 from __future__ import annotations
 
+import collections
 import json
 
 import jax
@@ -187,6 +188,47 @@ def test_the_kernels_give_the_scan_s_numbers(tiny, monkeypatch):
                     jax.tree_util.tree_leaves(tiny["grads"])):
         if b.any():
             assert _off(a, b) < TOL
+
+
+@pytest.mark.parametrize("remat, forward", [(False, 1), (True, 2)],
+                         ids=["kept", "block_recomputed"])
+def test_a_recomputed_block_prepares_again(monkeypatch, remat, forward):
+    """The rule's forward pass keeps its preparation for the backward pass:
+    the gradient's program holds ``kda_prepare_fwd`` once a KDA layer.  A
+    block recomputed as a whole (``remat_policy="block"``) keeps none of it
+    and makes it again with the block — twice a layer —, and the reverse
+    is there once a layer either way.  Traced only, the kernels steered to
+    (interpreted: the heads are 16 wide)."""
+    from distributed_machine_learning_tpu.train.losses import lm_cross_entropy
+
+    monkeypatch.setattr(delta_rule, "state_pass", lambda *a: "kernel")
+    sizes = km.KDAMoESizes.from_config(TINY)
+    model = km.KDAMoELM(sizes, remat=remat, remat_policy="block")
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 64), 0, 97)
+    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    program = jax.make_jaxpr(jax.grad(lambda p: lm_cross_entropy(
+        model.apply({"params": p}, tokens), tokens)))(params)
+    calls = collections.Counter(_kernel_calls(program.jaxpr))
+    layers = sizes.layer_kinds.count("kda")
+    assert layers == 4
+    assert calls["kda_prepare_fwd"] == forward * layers
+    assert calls["kda_prepare_bwd"] == layers
+
+
+def _kernel_calls(jaxpr):
+    """The kernel name of every ``pallas_call`` the program makes, a
+    sub-program shared by several calls (a jitted kernel) counted at each."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += _kernel_calls(sub)
+    return names
 
 
 def test_latent_attention_without_rotation_is_position_free():

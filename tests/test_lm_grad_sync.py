@@ -17,9 +17,9 @@ from jax.sharding import PartitionSpec as P
 from distributed_machine_learning_tpu.ops.hlo import (
     all_reduces_from_hlo,
     flash_calls_from_hlo,
-    gdn_prepare_calls_from_hlo,
     grad_sync_bytes,
     kernel_calls_from_hlo,
+    prepare_calls_from_hlo,
 )
 from distributed_machine_learning_tpu.models import hybrid_moe as hm
 from distributed_machine_learning_tpu.models.transformer import TransformerLM
@@ -390,15 +390,30 @@ def test_walker_counts_the_flash_kernels_calls(scopes, expected):
 
 
 @pytest.mark.parametrize("scopes, solve, expected", [
-    # one DeltaNet layer of a train step: forward, made again, the reverse
+    # one DeltaNet layer of a train step as it was: forward, made again, the
+    # reverse
     (["gdn_prepare_fwd", "gdn_state_fwd", "jvp(flash_fwd)",
       "gdn_prepare_fwd", "gdn_state_fwd", "gdn_state_bwd",
-      "gdn_prepare_bwd"], False, (2, 1)),
+      "gdn_prepare_bwd"], False, (2, 1, 0, 0)),
     # inside a recomputed block the names keep their transformations
     (["checkpoint/gdn_prepare_fwd", "rematted_computation/gdn_prepare_fwd",
-      "transpose(jvp(gdn_prepare_bwd))"], False, (2, 1)),
+      "transpose(jvp(gdn_prepare_bwd))"], False, (2, 1, 0, 0)),
     # no kernel (the dispatch said "scan", or an older program's solve)
-    (["jvp(flash_fwd)", "transpose(jvp(flash_bwd_fused))"], True, (0, 0)),
+    (["jvp(flash_fwd)", "transpose(jvp(flash_bwd_fused))"], True,
+     (0, 0, 0, 0)),
+    # one DeltaNet layer that keeps its preparation: forward once, reverse
+    (["gdn_prepare_fwd", "gdn_state_fwd", "jvp(flash_fwd)",
+      "gdn_state_fwd", "gdn_state_bwd", "gdn_prepare_bwd"], False,
+     (1, 1, 0, 0)),
+    # a KDA layer (a decay a channel) that keeps its preparation, beside a
+    # DeltaNet layer; the state kernels are not counted
+    (["jvp(kda_prepare_fwd)", "kda_state_fwd", "gdn_prepare_fwd",
+      "kda_state_fwd", "kda_state_bwd", "transpose(jvp(kda_prepare_bwd))",
+      "gdn_prepare_bwd"], False, (1, 1, 1, 1)),
+    # four KDA layers in recomputed blocks: the block makes each again
+    (["checkpoint/kda_prepare_fwd"] * 4
+     + ["rematted_computation/kda_prepare_fwd", "kda_prepare_bwd"] * 4,
+     False, (0, 0, 8, 4)),
 ])
 def test_walker_counts_the_preparation_kernels_calls(scopes, solve, expected):
     text = _module(
@@ -412,14 +427,15 @@ def test_walker_counts_the_preparation_kernels_calls(scopes, solve, expected):
         + '  %cc = f32[256]{0} custom-call(%p1), custom_call_target="Sharding"'
         ', metadata={op_name="jit(step)/gdn_prepare_fwd/pallas_call"}\n'
         "  ROOT %mul = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n")
-    assert gdn_prepare_calls_from_hlo(text) == dict(
-        zip(("gdn_prepare_fwd_calls", "gdn_prepare_bwd_calls"), expected))
+    assert prepare_calls_from_hlo(text) == dict(
+        zip(("gdn_prepare_fwd_calls", "gdn_prepare_bwd_calls",
+             "kda_prepare_fwd_calls", "kda_prepare_bwd_calls"), expected))
     # the two readers share the text and do not count each other's kernels
     assert flash_calls_from_hlo(text)["flash_fwd_calls"] == sum(
         "flash_fwd" in scope for scope in scopes)
     # what a step publishes: both, from one reading of the text
     assert kernel_calls_from_hlo(text) == {
-        **flash_calls_from_hlo(text), **gdn_prepare_calls_from_hlo(text)}
+        **flash_calls_from_hlo(text), **prepare_calls_from_hlo(text)}
 
 
 def test_the_audits_defaults_are_the_four_chip_cells_sizes():
