@@ -330,6 +330,7 @@ CONFIG_MODELS = {
     "deepseek_v3": ("mla_moe", "MLAMoELM", "MLAMoESizes"),
     "afmoe": ("window_moe", "WindowMoELM", "WindowMoESizes"),
     "kimi_linear": ("kda_moe", "KDAMoELM", "KDAMoESizes"),
+    "smallthinker": ("preroute_moe", "PrerouteMoELM", "PrerouteMoESizes"),
 }
 
 

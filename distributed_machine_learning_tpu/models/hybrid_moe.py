@@ -79,6 +79,11 @@ ASSIGNMENTS = "assignments"
 #: What a delta-rule layer whose decay is a channel's sows: the largest
 #: log-decay a channel runs through inside one chunk of the rule.
 DECAY_SPAN = "kda_span"
+#: What a sparse layer whose experts are ReGLU sows: the share of its
+#: computed rows' hidden units that the ReLU gate passes.
+REGLU_ACTIVE = "reglu_active"
+#: An expert's gate activation, by the name a configuration gives it.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 _INIT = nn.initializers.normal(stddev=0.02)  # the family's initializer_range
 
@@ -221,7 +226,8 @@ class GatedAttention(nn.Module):
     causal attention — through a sliding ``window`` of that many keys, the
     query's own among them, where one is given — with each key/value head
     serving ``n_heads / n_kv_heads`` query heads; ``out = (attn ⊙
-    σ(gate))·W_o``."""
+    σ(gate))·W_o``.  ``gated=False``: ``q = x·W_q`` and ``out = attn·W_o``;
+    ``qk_norm=False``: ``q`` and ``k`` are not normed."""
 
     n_heads: int
     n_kv_heads: int
@@ -233,22 +239,28 @@ class GatedAttention(nn.Module):
     compute_dtype: Any
     window: int | None = None
     zero_centred_norm: bool = True
+    gated: bool = True
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, x, positions):
         B, T, D = x.shape
         dt = self.compute_dtype
         H, Hkv, dh = self.n_heads, self.n_kv_heads, self.head_dim
-        q_gate = _dense(2 * H * dh, dt, "q_proj")(x)
-        q = q_gate[..., :H * dh].reshape(B, T, H, dh)
-        gate = q_gate[..., H * dh:]
+        if self.gated:
+            q_gate = _dense(2 * H * dh, dt, "q_proj")(x)
+            q = q_gate[..., :H * dh].reshape(B, T, H, dh)
+            gate = q_gate[..., H * dh:]
+        else:
+            q = _dense(H * dh, dt, "q_proj")(x).reshape(B, T, H, dh)
         k = _dense(Hkv * dh, dt, "k_proj")(x).reshape(B, T, Hkv, dh)
         v = _dense(Hkv * dh, dt, "v_proj")(x).reshape(B, T, Hkv, dh)
         with jax.named_scope("attn.qk"):
-            q = RMSNorm(self.eps, dt, self.zero_centred_norm,
-                        name="q_norm")(q)
-            k = RMSNorm(self.eps, dt, self.zero_centred_norm,
-                        name="k_norm")(k)
+            if self.qk_norm:
+                q = RMSNorm(self.eps, dt, self.zero_centred_norm,
+                            name="q_norm")(q)
+                k = RMSNorm(self.eps, dt, self.zero_centred_norm,
+                            name="k_norm")(k)
             if self.rotary_dim:
                 q = apply_rope(q, positions, self.rope_base, self.rotary_dim)
                 k = apply_rope(k, positions, self.rope_base, self.rotary_dim)
@@ -265,8 +277,10 @@ class GatedAttention(nn.Module):
                 out = dense_self_attention(
                     q, _repeat_kv(k, rep), _repeat_kv(v, rep), positions,
                     window=self.window)
-        with jax.named_scope("attn.gate"):
-            out = _sigmoid_gated(out.reshape(B, T, H * dh), gate)
+        out = out.reshape(B, T, H * dh)
+        if self.gated:
+            with jax.named_scope("attn.gate"):
+                out = _sigmoid_gated(out, gate)
         with jax.named_scope("attn.out"):
             return _dense(D, dt, "o_proj")(out)
 
@@ -293,11 +307,16 @@ class SparseMoE(nn.Module):
     (``e_score_correction_bias``: in the parameter tree, no gradient, named
     in the model's ``frozen_params``) that picks and never weighs; ``p̃_e =
     routed_scale · p_e / Σ_{j∈S} p_j`` (no division unless
-    ``norm_topk_prob``); expert ``e`` is ``W_down(SiLU(W_gate x) ⊙ W_up x)``;
+    ``norm_topk_prob``); expert ``e`` is ``W_down(act(W_gate x) ⊙ W_up x)``,
+    ``act`` the ``activation`` named (SiLU, or ReLU: ReGLU experts, which
+    also count the hidden units their gate passes);
     ``y = Σ_{e ∈ S ∩ held} p̃_e·E_e(x) + g·E_shared(x)``, ``g = σ(x·w_s)``
-    with ``shared_gate``, else one.  With ``balance_bias`` the layer also
-    hands out ``c``, this step's assignments to each of the ``router_width``
-    experts, for the rule that moves ``b`` (:func:`balanced_bias`)."""
+    with ``shared_gate``, else one; ``shared_d_ff = 0``: no shared expert.
+    ``route_from``: the router reads that tensor (of ``x``'s shape) in place
+    of ``x``, and the experts still transform ``x``.  With ``balance_bias``
+    the layer also hands out ``c``, this step's assignments to each of the
+    ``router_width`` experts, for the rule that moves ``b``
+    (:func:`balanced_bias`)."""
 
     router_width: int
     held_experts: tuple  # (first, count)
@@ -312,9 +331,10 @@ class SparseMoE(nn.Module):
     shared_gate: bool = True
     balance_bias: bool = False
     bias_init: Any = _selection_bias_init
+    activation: str = "silu"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
         B, T, D = x.shape
         dt = self.compute_dtype
         N, k = B * T, self.experts_per_token
@@ -324,6 +344,7 @@ class SparseMoE(nn.Module):
                 f"held_experts {self.held_experts} must be a non-empty "
                 f"range inside the router's {self.router_width} experts")
         tokens = x.reshape(N, D)
+        router_in = tokens if route_from is None else route_from.reshape(N, D)
         w_gate = self.param("w_gate", _INIT, (held, D, self.d_ff))
         w_up = self.param("w_up", _INIT, (held, D, self.d_ff))
         w_down = self.param("w_down", _INIT, (held, self.d_ff, D))
@@ -333,7 +354,7 @@ class SparseMoE(nn.Module):
             logits = nn.Dense(
                 self.router_width, use_bias=False, dtype=jnp.float32,
                 kernel_init=_INIT, precision=lax.Precision.HIGHEST,
-                name="router")(tokens.astype(jnp.float32))
+                name="router")(router_in.astype(jnp.float32))
             if self.score_func not in ("softmax", "sigmoid"):
                 raise ValueError(
                     f"score_func must be 'softmax' or 'sigmoid', got "
@@ -360,16 +381,23 @@ class SparseMoE(nn.Module):
         # balanced share times HELD_ROWS_FACTOR, in whole 128-row tiles.
         share = N * k * held / self.router_width
         capacity = min(N * k, 128 * math.ceil(HELD_ROWS_FACTOR * share / 128))
+        reglu = self.activation == "relu"
         with jax.named_scope("moe.experts"):
-            y, (sizes, dropped) = grouped_expert_mlp(
+            y, (sizes, dropped, *active) = grouped_expert_mlp(
                 tokens.astype(dt), expert_idx, weights, w_up, w_down,
-                w_gate=w_gate, activation=jax.nn.silu, first_held=first,
-                capacity=capacity, return_counts=True)
+                w_gate=w_gate, activation=ACTIVATIONS[self.activation],
+                first_held=first, capacity=capacity, return_counts=True,
+                return_active=reglu)
         sizes = sizes.astype(jnp.float32)
         self.sow(STATS_COLLECTION, "layer", jnp.stack([
             jnp.sum(sizes),
             jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1.0),
             dropped.astype(jnp.float32)]))
+        if reglu:
+            self.sow(STATS_COLLECTION, REGLU_ACTIVE, active[0].astype(
+                jnp.float32) / jnp.maximum(jnp.sum(sizes) * self.d_ff, 1.0))
+        if not self.shared_d_ff:
+            return y.reshape(B, T, D)
         with jax.named_scope("moe.shared"):
             h = (jax.nn.silu(_dense(self.shared_d_ff, dt, "shared_gate_proj")(
                 tokens)) * _dense(self.shared_d_ff, dt, "shared_up_proj")(tokens))
@@ -403,7 +431,8 @@ def routing_counts(sown) -> dict:
     from a model that sows its attention layers' ``[active, causal,
     windowed]`` tile counts, active over causal tiles and the windowed
     calls; from a model that sows its KDA layers' chunk decay spans, the
-    largest."""
+    largest; from ReGLU expert layers, the share of their computed hidden
+    units that the gate passes (the mean over the layers)."""
     by_name: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
         name = [k.key for k in path if hasattr(k, "key")][-1]
@@ -424,6 +453,9 @@ def routing_counts(sown) -> dict:
         counts["attn_window_calls"] = windowed
     if DECAY_SPAN in by_name:
         counts["kda_decay_span"] = jnp.stack(by_name[DECAY_SPAN]).max()
+    if REGLU_ACTIVE in by_name:
+        counts["moe_reglu_active_share"] = jnp.stack(
+            by_name[REGLU_ACTIVE]).mean()
     return counts
 
 

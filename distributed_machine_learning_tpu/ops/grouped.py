@@ -252,6 +252,7 @@ def grouped_expert_mlp(
     w_in_scale: jax.Array | None = None,
     w_out_scale: jax.Array | None = None,
     return_counts: bool = False,
+    return_active: bool = False,
 ):
     """Routed expert MLP over ``[N, D]`` token rows: the part of
     ``Σ_j weights[n, j] · Expert_{expert_idx[n, j]}(tokens[n])`` that the
@@ -276,7 +277,10 @@ def grouped_expert_mlp(
     the experts expects ``N·k·E_local/E`` rows and may bound the buffer
     near that; assignments past the bound are dropped from the last
     groups and COUNTED: ``return_counts=True`` also returns
-    ``(group_sizes [E_local] int32 as computed, n_dropped int32)``.
+    ``(group_sizes [E_local] int32 as computed, n_dropped int32)``, and
+    with ``return_active=True`` (a gated MLP) a third count: the entries of
+    ``x·w_gate`` above zero over the rows computed — the hidden units a
+    ReLU gate passes.
 
     ``w_in_scale``/``w_out_scale`` ([E, F] / [E, D] f32): weight-only
     int8 expert serving — ``w_in``/``w_out`` are then int8 and the
@@ -325,11 +329,20 @@ def grouped_expert_mlp(
     if w_gate is None:
         h = activation(h)
     else:
-        h = activation(project(xs, w_gate, None, None)) * h
+        g = project(xs, w_gate, None, None)
+        h = activation(g) * h
     ys = project(h, w_out, w_out_scale, b_out)
     y = _combine_rows(ys, weights, slot_of_assignment, token_of_slot,
                       weight_of_slot)
-    return (y, (sizes, n_dropped)) if return_counts else y
+    if not return_counts:
+        return y
+    if not return_active:
+        return y, (sizes, n_dropped)
+    if w_gate is None:
+        raise ValueError("return_active counts a gated MLP's gate units")
+    computed = (jnp.arange(rows) < ends[-1])[:, None]
+    active = jnp.sum((g > 0) & computed, dtype=jnp.int32)
+    return y, (sizes, n_dropped, active)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
