@@ -49,6 +49,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_machine_learning_tpu.models.transformer import (
     _repeat_kv,
@@ -57,6 +58,7 @@ from distributed_machine_learning_tpu.models.transformer import (
 )
 from distributed_machine_learning_tpu.ops.delta_rule import gated_delta_rule
 from distributed_machine_learning_tpu.ops.grouped import (
+    ROUTE_NAMES,
     grouped_expert_mlp,
     route_topk,
     selection_moved_share,
@@ -65,6 +67,15 @@ from distributed_machine_learning_tpu.ops.ring_attention import (
     dense_self_attention,
 )
 
+#: ``jax.ad_checkpoint.checkpoint_name`` tags of what a recomputed block
+#: keeps (``models/transformer.py::whole_block_policy``) beside the flash
+#: kernel's and the grouped experts': the attention layer's and the
+#: feed-forward's outputs ``[B, L, D]``, which a norm after them reads (kept,
+#: they spare the output projections and the experts' combine), and a gated
+#: MLP's two pre-activations ``[N, d_ff]`` (the shared expert's here, the
+#: dense layer's in ``models/mla_moe.py::DenseMLP``).
+SUBLAYER_OUT_NAMES = ATTN_OUT, FFN_OUT = ("attn_out", "ffn_out")
+MLP_NAMES = ("mlp_gate", "mlp_up")
 #: The held experts' row buffer, as a multiple of the rows a balanced
 #: router sends here (tokens × experts a token × held ÷ router width).
 HELD_ROWS_FACTOR = 2.0
@@ -282,7 +293,7 @@ class GatedAttention(nn.Module):
             with jax.named_scope("attn.gate"):
                 out = _sigmoid_gated(out, gate)
         with jax.named_scope("attn.out"):
-            return _dense(D, dt, "o_proj")(out)
+            return checkpoint_name(_dense(D, dt, "o_proj")(out), ATTN_OUT)
 
 
 @jax.checkpoint
@@ -351,10 +362,10 @@ class SparseMoE(nn.Module):
         with jax.named_scope("moe.route"):
             # A float32 router for real: a TPU's default matmul precision
             # would round the operands to bf16 and move near-tied choices.
-            logits = nn.Dense(
+            logits = checkpoint_name(nn.Dense(
                 self.router_width, use_bias=False, dtype=jnp.float32,
                 kernel_init=_INIT, precision=lax.Precision.HIGHEST,
-                name="router")(router_in.astype(jnp.float32))
+                name="router")(router_in.astype(jnp.float32)), ROUTE_NAMES[0])
             if self.score_func not in ("softmax", "sigmoid"):
                 raise ValueError(
                     f"score_func must be 'softmax' or 'sigmoid', got "
@@ -399,8 +410,11 @@ class SparseMoE(nn.Module):
         if not self.shared_d_ff:
             return y.reshape(B, T, D)
         with jax.named_scope("moe.shared"):
-            h = (jax.nn.silu(_dense(self.shared_d_ff, dt, "shared_gate_proj")(
-                tokens)) * _dense(self.shared_d_ff, dt, "shared_up_proj")(tokens))
+            gate, up = MLP_NAMES
+            h = (jax.nn.silu(checkpoint_name(_dense(
+                self.shared_d_ff, dt, "shared_gate_proj")(tokens), gate))
+                 * checkpoint_name(_dense(
+                     self.shared_d_ff, dt, "shared_up_proj")(tokens), up))
             shared = _dense(D, dt, "shared_down_proj")(h)
             if self.shared_gate:
                 gate = jax.nn.sigmoid(_dense(1, dt, "shared_expert_gate")(
@@ -578,9 +592,10 @@ class HybridMoELM(nn.Module):
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``TransformerLM``'s (``"mlp"``: norm 2 +
     MoE recomputed in the backward pass; ``"block"``: the whole block, but
-    for the flash kernel's ``(out, lse)``, which a full-attention layer on
-    the kernel keeps — ``models/transformer.py::whole_block_policy``; a
-    DeltaNet layer keeps its input alone)."""
+    for what ``models/transformer.py::whole_block_policy`` keeps — the flash
+    kernel's inputs and outputs where a full-attention layer runs it, the
+    attention's output, the expert layer's routing, rows and
+    pre-activations, the shared expert's pre-activations)."""
 
     sizes: HybridMoESizes
     attn_impl: str = "dense"

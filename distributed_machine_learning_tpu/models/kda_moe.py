@@ -314,7 +314,9 @@ class KDAMoELM(nn.Module):
     the delta rule's state does not cross a sequence shard); ``remat`` /
     ``remat_policy`` as ``HybridMoELM``'s (``"mlp"``: norm 2 + feed-forward
     recomputed in the backward pass; ``"block"``: the whole block but for
-    the flash kernel's ``(out, lse)``, kept where the kernel runs)."""
+    what ``models/transformer.py::whole_block_policy`` keeps of what runs
+    here — the flash kernel's inputs and outputs, the expert layer's
+    routing, rows and pre-activations, the gated MLPs' pre-activations)."""
 
     sizes: KDAMoESizes
     attn_impl: str = "dense"

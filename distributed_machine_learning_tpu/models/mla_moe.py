@@ -37,9 +37,11 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_machine_learning_tpu.models.hybrid_moe import (
     _INIT,
+    MLP_NAMES,
     SELECTION_BIAS,
     STATS_COLLECTION,
     RMSNorm,
@@ -230,9 +232,11 @@ class DenseMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         dt = self.compute_dtype
+        gate, up = MLP_NAMES
         with jax.named_scope("mlp.dense"):
-            h = (jax.nn.silu(_dense(self.d_ff, dt, "gate_proj")(x))
-                 * _dense(self.d_ff, dt, "up_proj")(x))
+            h = (jax.nn.silu(checkpoint_name(
+                _dense(self.d_ff, dt, "gate_proj")(x), gate))
+                 * checkpoint_name(_dense(self.d_ff, dt, "up_proj")(x), up))
             return _dense(x.shape[-1], dt, "down_proj")(h)
 
 
@@ -278,8 +282,10 @@ class MLAMoELM(nn.Module):
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``HybridMoELM``'s (``"mlp"``: norm 2 +
     feed-forward recomputed in the backward pass; ``"block"``: the whole
-    block but for the flash kernel's ``(out, lse)``, kept where the kernel
-    runs — ``models/transformer.py::whole_block_policy``)."""
+    block but for what ``models/transformer.py::whole_block_policy`` keeps
+    of what runs here — the flash kernel's inputs and outputs, the expert
+    layer's routing, rows and pre-activations, the gated MLPs'
+    pre-activations)."""
 
     sizes: MLAMoESizes
     attn_impl: str = "dense"

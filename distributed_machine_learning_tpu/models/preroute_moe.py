@@ -181,9 +181,10 @@ class PrerouteMoELM(nn.Module):
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``HybridMoELM``'s (``"mlp"``: norm 2 and
     the sparse layer recomputed in the backward pass, ``r`` kept; ``"block"``:
-    the whole block, routing among it, but for the flash kernel's ``(out,
-    lse)``, kept where the kernel runs —
-    ``models/transformer.py::whole_block_policy``)."""
+    the whole block, routing among it, but for what
+    ``models/transformer.py::whole_block_policy`` keeps — the flash kernel's
+    inputs and outputs, the attention's output, the router's logits, the
+    sort's integers, the dispatched rows and the two pre-activations)."""
 
     sizes: PrerouteMoESizes
     attn_impl: str = "dense"

@@ -661,20 +661,89 @@ def _mlp_sublayer(mdl: "Block", h: jax.Array) -> jax.Array:
 
 def whole_block_policy():
     """What whole-block recomputation keeps beside the block's input: the
-    flash kernel's two tagged outputs (``FLASH_RESIDUAL_NAMES``: ``out``,
-    bf16 ``[B·H, L, d_v]``, and the float32 ``lse`` ``[B·H, 1, L]``), so
-    the backward pass makes the block again without running the O(L²)
-    forward kernel a second time.  The tags exist only where that kernel
-    runs: a block on the dense attention path has no tagged value, keeps
-    nothing and is the program it was.  What it costs in bytes a layer:
-    ``Block``'s docstring (Trinity-Mini at 16 384 tokens: 194 MiB where
-    the input alone is 64; StarCoder2-3B at 4096: 48 MiB where 24)."""
+    values whose remaking costs most a byte, each a ``checkpoint_name`` tag
+    (listed below), so that the backward pass makes the rest of the
+    block again around them:
+
+    - the flash kernel's three inputs as it takes them (normed, rotated,
+      folded to ``[B·H, L, D]``, K/V unrepeated) and its two outputs (``out``
+      bf16 ``[B·H, L, d_v]``, the float32 ``lse`` ``[B·H, 1, L]``): no O(L²)
+      forward kernel, projection, rotation or fold runs a second time;
+    - the attention layer's and, under a sandwich norm, the feed-forward's
+      outputs ``[B, L, D]``, which the norms after them read;
+    - the grouped expert layer's routing (the router's float32 logits, a
+      selection bias's chosen experts, the sort's integers, the rows'
+      weights), its dispatched rows ``[rows, D]`` and its two
+      pre-activations ``[rows, F]``: no sort, gather or up-projection runs
+      again, the down-projection does (the combine's reverse reads its
+      output);
+    - a gated MLP's two pre-activations (the shared expert's, the dense
+      layer's).
+
+    A tag exists only where what it names runs — the flash names on the
+    flash path, the expert names on ``grouped_expert_mlp``'s — so which of
+    them a block keeps follows from what the block runs; a block with none
+    keeps its input alone.  What it costs in bytes a layer: ``Block``'s
+    docstring; what a step keeps, by tag: the ``remat_kept_bytes`` gauge
+    (:func:`kept_bytes`)."""
+    from distributed_machine_learning_tpu.models.hybrid_moe import (
+        MLP_NAMES,
+        SUBLAYER_OUT_NAMES,
+    )
+    from distributed_machine_learning_tpu.ops.grouped import (
+        EXPERT_ROUTING_NAMES,
+        EXPERT_ROW_NAMES,
+        ROUTE_NAMES,
+    )
     from distributed_machine_learning_tpu.ops.pallas.flash_attention import (
+        FLASH_INPUT_NAMES,
         FLASH_RESIDUAL_NAMES,
     )
 
     return jax.checkpoint_policies.save_only_these_names(
-        *FLASH_RESIDUAL_NAMES)
+        *FLASH_INPUT_NAMES, *FLASH_RESIDUAL_NAMES, *SUBLAYER_OUT_NAMES,
+        *ROUTE_NAMES, *EXPERT_ROUTING_NAMES, *EXPERT_ROW_NAMES, *MLP_NAMES)
+
+
+#: What carries a tag on to its result where its one tagged operand is
+#: made into a residual: a SiLU's reverse reads ``σ(g)`` made in a jitted
+#: function of the tagged ``g``, a saved float passes ``reduce_precision``.
+_TAG_CARRIERS = ("name", "reduce_precision", "convert_element_type", "pjit",
+                 "jit")
+
+
+def kept_bytes(jaxpr) -> dict:
+    """``{tag: bytes}`` that a differentiated program's recomputed blocks
+    keep beside their inputs, from shapes: every operand of a
+    differentiated checkpoint (the backward pass's ``remat2``) that carries
+    a ``checkpoint_name`` tag, itself or as what a cast, ``reduce_precision``
+    or a jitted function made of one tagged value, summed over the program
+    (``jaxpr``: a step's, closed or not; its sub-programs are walked)."""
+    totals: dict = {}
+
+    def walk(jaxpr):
+        tags: dict = {}
+        for eqn in jaxpr.eqns:
+            operands = {tags[v] for v in eqn.invars
+                        if not hasattr(v, "val") and v in tags}
+            if eqn.primitive.name == "name":
+                operands = {eqn.params["name"]}
+            if eqn.primitive.name == "remat2" and eqn.params["differentiated"]:
+                for v in {v for v in eqn.invars if not hasattr(v, "val")}:
+                    if v in tags:
+                        totals[tags[v]] = totals.get(tags[v], 0) + (
+                            v.aval.size * v.aval.dtype.itemsize)
+            elif eqn.primitive.name in _TAG_CARRIERS and len(operands) == 1:
+                tags.update(dict.fromkeys(eqn.outvars, *operands))
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(getattr(jaxpr, "jaxpr", jaxpr))
+    return totals
 
 
 def remat_whole_block(block_cls):
@@ -694,12 +763,17 @@ class Block(nn.Module):
     resident, so the backward pass never re-runs the O(L²) attention
     forward, at ~6·L·E saved activation bytes per layer.  Whole-block
     remat (``remat_whole_block``) makes everything of the block again in
-    backward EXCEPT that same pair: the norms, projections, rotation and
-    the feed-forward run a second time (O(L) each), the flash forward
-    kernel does not, at ``L·(E + H·d_v)·2 + 4·H·L`` saved bytes per layer
-    — the block's input, the kernel's ``out`` and its float32 ``lse`` —
-    where a block that kept its input alone held ``L·E·2``.  On the dense
-    attention path nothing is tagged and the input alone is kept."""
+    backward EXCEPT what :func:`whole_block_policy` keeps; here (no tagged
+    output, no expert layer) the flash kernel's folded q, k, v and its
+    ``(out, lse)``: the norms, the output projection and the MLP run a
+    second time (O(L) each), the flash forward kernel, the rotation and the
+    folds do not, at ``L·(E + (2·H + 2·H_kv)·d)·2 + 4·H·L`` saved bytes
+    per layer where a block that kept its input alone held ``L·E·2``
+    (StarCoder2-3B at 4096 tokens: 76 MiB where 24).  In the MoE models'
+    blocks the kept set is wider (Trinity-Mini at 16 384 tokens: 866 MiB a
+    dense layer, 812 a sparse one, where the input alone is 64 and the
+    kernel's pair with it 194).  On the dense attention path no flash
+    value is tagged."""
 
     n_heads: int
     d_ff: int

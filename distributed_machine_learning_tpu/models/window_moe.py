@@ -44,8 +44,10 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_machine_learning_tpu.models.hybrid_moe import (
+    FFN_OUT,
     _INIT,
     ASSIGNMENTS,
     SELECTION_BIAS,
@@ -175,7 +177,7 @@ def _feed_forward(mdl: "WindowMoEBlock", a):
             routed_scale=m.routed_scale, shared_gate=False,
             balance_bias=m.balance_rate is not None,
             bias_init=nn.initializers.zeros, name="moe")(h)
-    return _norm(mdl, "post_mlp_layernorm")(h)
+    return _norm(mdl, "post_mlp_layernorm")(checkpoint_name(h, FFN_OUT))
 
 
 class WindowMoEBlock(nn.Module):
@@ -217,8 +219,11 @@ class WindowMoELM(nn.Module):
     Sequence-local attention only (``attn_impl`` ``"dense"`` or ``"flash"``);
     ``remat`` / ``remat_policy`` as ``HybridMoELM``'s (``"mlp"``: norm 3,
     the feed-forward and norm 4 recomputed in the backward pass; ``"block"``:
-    the whole block but for the flash kernel's ``(out, lse)``, kept where
-    the kernel runs — ``models/transformer.py::whole_block_policy``)."""
+    the whole block but for what
+    ``models/transformer.py::whole_block_policy`` keeps — the flash kernel's
+    inputs and outputs, both sub-layers' outputs, which the sandwich norms
+    read, the expert layer's routing, rows and pre-activations, the shared
+    expert's and the dense layer's pre-activations)."""
 
     sizes: WindowMoESizes
     attn_impl: str = "dense"

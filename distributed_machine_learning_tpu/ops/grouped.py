@@ -52,6 +52,26 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+#: ``jax.ad_checkpoint.checkpoint_name`` tags of :func:`grouped_expert_mlp`'s
+#: routing, which the reverses of the gathers and of ``ragged_dot`` read: the
+#: sorted assignment of each buffer slot, its token, each assignment's slot,
+#: the held groups' sizes, each row's expert and its weight.  With the chosen
+#: experts (:func:`route_topk`) what a recomputed block keeps of the sort
+#: (``models/transformer.py::whole_block_policy``).
+EXPERT_ROUTING_NAMES = (
+    "expert_slots", "expert_token_of_slot", "expert_slot_of_assignment",
+    "expert_sizes", "expert_eids", "expert_weight_of_slot")
+#: Tags of the dispatched rows ``[rows, D]`` and the two pre-activation
+#: outputs ``[rows, F]`` (``x·w_in``; ``x·w_gate`` of a gated MLP): the
+#: grouped matmuls' inputs and what the activation's reverse reads.
+EXPERT_ROW_NAMES = ("expert_rows", "expert_up", "expert_gate")
+#: Tags of the router's float32 logits ``[N, E]`` (``models/hybrid_moe.py::
+#: SparseMoE``) and of the chosen experts ``[N, k]`` where a selection bias
+#: chose them (:func:`route_topk`): what the scores' reverse reads, so that a
+#: recomputed block runs neither the router's matmul nor its top-k again.
+ROUTE_NAMES = ("route_logits", "route_choice")
 
 
 def sort_by_expert(expert_idx: jax.Array, n_experts: int):
@@ -134,6 +154,9 @@ def route_topk(probs: jax.Array, k: int, renormalize: bool = False, *,
         weights, expert_idx = lax.top_k(probs, k)
     else:
         _, expert_idx = lax.top_k(probs + bias, k)
+        # Tagged where the weights' reverse (a gather's) reads it: a
+        # recomputed block that keeps it selects nothing again.
+        expert_idx = checkpoint_name(expert_idx, ROUTE_NAMES[1])
         weights = jnp.take_along_axis(probs, expert_idx, axis=-1)
     if renormalize:
         # + 1e-20: k sigmoids may sum to nothing; a softmax's top-k sum
@@ -313,9 +336,17 @@ def grouped_expert_mlp(
     # Sorted expert of each row; a row past the held groups (no matmul
     # touches it, nothing reads it) borrows the last expert's bias and scale.
     eids = jnp.minimum(jnp.take(local, slots, axis=0), e_local - 1)
+    # The identity outside a ``jax.checkpoint``; inside a recomputed block
+    # what the backward pass reads instead of sorting and gathering again.
+    (slots, token_of_slot, slot_of_assignment, sizes, eids,
+     weight_of_slot) = map(checkpoint_name, (
+         slots, token_of_slot, slot_of_assignment, sizes, eids,
+         weight_of_slot), EXPERT_ROUTING_NAMES)
 
     dt = tokens.dtype
-    xs = _dispatch_rows(tokens, token_of_slot, slot_of_assignment)
+    rows_name, up_name, gate_name = EXPERT_ROW_NAMES
+    xs = checkpoint_name(
+        _dispatch_rows(tokens, token_of_slot, slot_of_assignment), rows_name)
 
     def project(x, w, scale, bias):
         y = lax.ragged_dot(x, w.astype(dt), sizes)
@@ -325,11 +356,11 @@ def grouped_expert_mlp(
             y = y + jnp.take(bias.astype(dt), eids, axis=0)
         return y
 
-    h = project(xs, w_in, w_in_scale, b_in)
+    h = checkpoint_name(project(xs, w_in, w_in_scale, b_in), up_name)
     if w_gate is None:
         h = activation(h)
     else:
-        g = project(xs, w_gate, None, None)
+        g = checkpoint_name(project(xs, w_gate, None, None), gate_name)
         h = activation(g) * h
     ys = project(h, w_out, w_out_scale, b_out)
     y = _combine_rows(ys, weights, slot_of_assignment, token_of_slot,

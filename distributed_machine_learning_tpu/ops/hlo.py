@@ -22,6 +22,8 @@ the TPU AOT target name the ops identically):
   likewise, a decay a head and a decay a channel (its
   ``gdn_prepare_*_calls`` and ``kda_prepare_*_calls`` gauges);
   :func:`kernel_calls_from_hlo` is both in one reading.
+- :func:`remat_instructions_from_hlo` — what XLA's own rematerialization
+  pass made again to fit the device (its ``xla_remat_instructions`` gauge).
 
 A schedule is not a device timeline: these prove what the executable
 *orders* under a collective, while what is really hidden is a chip's
@@ -396,3 +398,18 @@ def prepare_calls_from_hlo(hlo_text: str) -> dict:
     two and one where a block is recomputed as a whole.  0 in a step with
     such layers: ``ops/delta_rule.py::state_pass`` said ``"scan"``."""
     return _calls_by_prefix(hlo_text, _PREPARE)
+
+
+# ``%fusion.986.remat = …``, ``%gte.remat.16 = …``, ``%fusion.2026.remat3 =
+# …``: the instructions the rematerialization pass cloned.
+_REMAT_INSTRUCTION_RE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]*\.remat[\w.\-]* = ", re.MULTILINE)
+
+
+def remat_instructions_from_hlo(hlo_text: str) -> int:
+    """The instructions XLA's rematerialization pass added to the module: a
+    scheduled program that would not fit the device's memory as written
+    computes them a second time, each named after the one it copies with a
+    ``.remat`` suffix.  0 where the program fits as it is; JAX's own
+    recomputation (``jax.checkpoint``) is not counted."""
+    return len(_REMAT_INSTRUCTION_RE.findall(hlo_text))

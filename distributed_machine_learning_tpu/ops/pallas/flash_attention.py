@@ -91,9 +91,12 @@ from distributed_machine_learning_tpu.ops.pallas.common import (
 
 NEG_INF = -1e30
 #: ``jax.ad_checkpoint.checkpoint_name`` tags of the forward kernel's two
-#: outputs, the O(L²) part of the backward's residuals at O(L) bytes: what a
-#: recomputed block keeps (``models/transformer.py::whole_block_policy``).
+#: outputs, the O(L²) part of the backward's residuals at O(L) bytes, and of
+#: its three inputs as the kernels take them (normed, rotated, folded to
+#: ``[B·H, L, D]``, K/V unrepeated): what a recomputed block keeps
+#: (``models/transformer.py::whole_block_policy``).
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+FLASH_INPUT_NAMES = ("flash_q", "flash_k", "flash_v")
 _LANES = 128  # VMEM lane width: m/l scratch is (block_q, _LANES)
 # The kernels run the softmax in BASE 2: scores are pre-scaled by
 # log2(e) so every exp becomes a bare exp2.  m, l's log-offset, and the
@@ -970,30 +973,30 @@ def _flash_core(q, k, v, window):
 def _flash_core_fwd(q, k, v, window):
     B, L, H, D = q.shape
     bq, bk = _fwd_blocks(L)
-    out, lse = _flash_fwd(
-        _fold(q), _fold(k), _fold(v), bq, bk, kv_groups=_kv_groups(q, k, v),
-        window=window,
-    )
-    # The identity outside a ``jax.checkpoint``; q, k, v carry no tag (a
-    # norm, a projection and the rotation make them again, O(L)).
+    groups = _kv_groups(q, k, v)
+    # The identity outside a ``jax.checkpoint``.  The residuals are the
+    # folded inputs the kernels take, so a block recomputed around this call
+    # can keep them and the backward pass folds nothing again.
+    q, k, v = map(checkpoint_name, (_fold(q), _fold(k), _fold(v)),
+                  FLASH_INPUT_NAMES)
+    out, lse = _flash_fwd(q, k, v, bq, bk, kv_groups=groups, window=window)
     out, lse = map(checkpoint_name, (out, lse), FLASH_RESIDUAL_NAMES)
     return _unfold(out, B, H), (q, k, v, out, lse)
 
 
 def _flash_core_bwd(window, res, g):
-    q, k, v, out, lse = res  # out/lse already folded [BH, ...]
-    B, L, H, D = q.shape
-    groups = _kv_groups(q, k, v)
+    q, k, v, out, lse = res  # all folded [BH, L, ·], K/V unrepeated
+    B, L, H, _ = g.shape
+    D = q.shape[-1]
+    groups = q.shape[0] // k.shape[0]
     do = _fold(g)
     # Δ = rowsum(dO ∘ O): O(L·D) elementwise — XLA fuses it; no kernel
     # needed.
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )[:, None, :]  # [BH, 1, L] — exact, same layout as the saved lse
-    dq, dk, dv = _flash_bwd(
-        _fold(q), _fold(k), _fold(v), do, lse, delta, kv_groups=groups,
-        window=window,
-    )
+    dq, dk, dv = _flash_bwd(q, k, v, do, lse, delta, kv_groups=groups,
+                            window=window)
     dq = _unfold(dq, B, H)
     dk = _unfold(dk, B, H)  # [B, L, H, D] — per query head
     dv = _unfold(dv, B, H)

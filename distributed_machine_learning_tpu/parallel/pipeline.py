@@ -147,7 +147,10 @@ def _apply_local_span(block: Block, stacked_local, x, positions,
 
     ``remat=True`` wraps each layer application in ``jax.checkpoint``
     under the LMs' whole-block policy (``whole_block_policy``: the flash
-    kernel's ``(out, lse)`` are kept, everything else is made again):
+    kernel's folded q, k, v and ``(out, lse)`` are kept, and on
+    ``grouped_expert_mlp``'s path the routing, the dispatched rows and the
+    pre-activations; everything else is made again — so each (tick ×
+    layer) holds those bytes beside its input: ``Block``'s docstring):
     the backward pipeline then recomputes block activations instead of
     holding every (tick × layer) activation live — the memory term that
     otherwise scales with microbatch count under grad-of-scan."""

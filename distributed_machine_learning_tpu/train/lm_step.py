@@ -353,11 +353,11 @@ def _grad_sync_compiler_options(mesh, axis_names) -> dict | None:
     return dict(ASYNC_GRAD_SYNC_OPTIONS)
 
 
-class _StepWithSyncGauges:
+class _StepWithHloGauges:
     """``step(state, tokens, targets)`` that, at its first call under an
-    installed ``Telemetry``, reads the compiled step's own HLO text once
-    and writes the gauges ``grad_sync_bytes`` (bytes a chip all-reduces a
-    step: the gradients, the loss, a model's counts),
+    installed ``Telemetry``, reads the compiled step's own HLO text and its
+    jaxpr once and writes the gauges ``grad_sync_bytes`` (bytes a chip
+    all-reduces a step: the gradients, the loss, a model's counts),
     ``grad_sync_async_bytes`` (those that go through asynchronous
     collectives), ``flash_fwd_calls`` / ``flash_bwd_calls`` (the flash
     kernel calls the compiler kept: equal unless a recomputed block runs a
@@ -366,8 +366,13 @@ class _StepWithSyncGauges:
     ``kda_prepare_bwd_calls`` (the delta rule's preparation kernels, a decay
     a head or a channel: one and one a layer, two and one in a block
     recomputed as a whole; 0 where ``ops/delta_rule.py::state_pass`` said
-    ``"scan"``).  Getting at the text compiles the step a second
-    time (the persistent cache serves it), so nothing is read without a
+    ``"scan"``), ``xla_remat_instructions`` (what XLA's rematerialization
+    pass computes a second time to fit the device: 0 where the step fits as
+    written) and ``remat_kept_bytes{tag=…}`` (the bytes a step's recomputed
+    blocks keep beside their inputs, by tag, from shapes:
+    ``models/transformer.py::kept_bytes``; none without whole-block
+    recomputation).  Getting at the text compiles the step a second time
+    (the persistent cache serves it), so nothing is read without a
     ``Telemetry``: an unobserved run pays one pointer test a step."""
 
     def __init__(self, jitted):
@@ -384,6 +389,9 @@ class _StepWithSyncGauges:
         return self._jitted(state, tokens, targets)
 
     def _publish(self, *args) -> None:
+        from distributed_machine_learning_tpu.models.transformer import (
+            kept_bytes,
+        )
         from distributed_machine_learning_tpu.ops import hlo
 
         from distributed_machine_learning_tpu.telemetry import startup
@@ -391,13 +399,17 @@ class _StepWithSyncGauges:
         # Inside the process's first step: the second lowering has a span
         # and a counter phase of its own there.
         with startup.span("startup.hlo_gauges"):
-            text = self._jitted.lower(*args).compile().as_text()
+            traced = self._jitted.trace(*args)
+            text = traced.lower().compile().as_text()
         registry = self._get_telemetry().registry
         gauges = {
             **hlo.grad_sync_bytes(hlo.all_reduces_from_hlo(text)),
-            **hlo.kernel_calls_from_hlo(text)}
+            **hlo.kernel_calls_from_hlo(text),
+            "xla_remat_instructions": hlo.remat_instructions_from_hlo(text)}
         for name, value in gauges.items():
             registry.gauge(name).set(value)
+        for tag, value in kept_bytes(traced.jaxpr).items():
+            registry.gauge("remat_kept_bytes", tag=tag).set(value)
 
     def __getattr__(self, name):  # lower, trace, ...: the jitted step's own
         return getattr(self._jitted, name)
@@ -496,12 +508,10 @@ def make_lm_train_step(
         in_specs=(P(), batch_spec, batch_spec),
         out_specs=(P(), P(), P()) if with_stats else (P(), P()),
     )
-    if all(mesh.shape[a] == 1 for a in axis_names):
-        return finish(jax.jit(sharded, donate_argnums=(0,)))
     options = _grad_sync_compiler_options(mesh, axis_names)
     jitted = jax.jit(sharded, donate_argnums=(0,),
                      **({"compiler_options": options} if options else {}))
-    return finish(_StepWithSyncGauges(jitted))
+    return finish(_StepWithHloGauges(jitted))
 
 
 def make_lm_eval_step(model):

@@ -1,26 +1,30 @@
 """What whole-block recomputation keeps (``models/transformer.py::
-whole_block_policy``): the flash kernel's ``(out, lse)`` beside the block's
-input, in all four LMs — each layer's forward kernel once in the gradient's
-program, the numbers those of the model that recomputes nothing, and no
-other program touched but for the ``name`` tags."""
+whole_block_policy``): beside the block's input, the flash kernel's inputs
+and ``(out, lse)``, and the expert layer's routing, dispatched rows and
+pre-activations, in all five LMs — each layer's forward kernel once in the
+gradient's program, the numbers those of the model that recomputes nothing,
+and no other program touched but for the ``name`` tags."""
 
 from __future__ import annotations
 
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_machine_learning_tpu.models import hybrid_moe as hm
 from distributed_machine_learning_tpu.models import mla_moe as mm
+from distributed_machine_learning_tpu.models import preroute_moe as pm
 from distributed_machine_learning_tpu.models import transformer as tr
 from distributed_machine_learning_tpu.models import window_moe as wm
-from distributed_machine_learning_tpu.ops.pallas import flash_attention
 from distributed_machine_learning_tpu.train.losses import lm_cross_entropy
 from tests.test_hybrid_moe import TINY as HYBRID
 from tests.test_mla_moe import TINY as MLA
+from tests.test_preroute_moe import TINY as PREROUTE
 from tests.test_window_moe import TINY as WINDOW
 
 VOCAB, L = 97, 128
@@ -40,7 +44,25 @@ MODELS = {
     "WindowMoELM": (lambda: wm.WindowMoELM(
         wm.WindowMoESizes.from_config(WINDOW), attn_impl="flash"),
         ["flash_fwd"] + ["flash_fwd_w16"] * 4),
+    # one global layer without rotation, then three windowed ones
+    "PrerouteMoELM": (lambda: pm.PrerouteMoELM(
+        pm.PrerouteMoESizes.from_config(PREROUTE), attn_impl="flash"),
+        ["flash_fwd"] + ["flash_fwd_w16"] * 3),
 }
+
+
+def untagged(monkeypatch, keep=()):
+    """Every ``checkpoint_name`` call of the program's modules made the
+    identity but for the tags in ``keep``."""
+    def tag(x, name):
+        return checkpoint_name(x, name) if name in keep else x
+
+    for module in list(sys.modules.values()):
+        if (getattr(module, "__name__", "").startswith(
+                "distributed_machine_learning_tpu.")
+                and getattr(module, "checkpoint_name", None)
+                is checkpoint_name):
+            monkeypatch.setattr(module, "checkpoint_name", tag)
 
 
 @pytest.fixture(scope="module")
@@ -53,7 +75,7 @@ def batch():
 def lm(request, batch):
     make, forward = MODELS[request.param]
     model = make()
-    params = model.init(jax.random.PRNGKey(1), batch[0])["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), batch[0])["params"]
     return model, params, sorted(forward)
 
 
@@ -106,25 +128,71 @@ def _residuals(capsys, f, *args):
             if not src.startswith(("from the argument", "from a constant"))]
 
 
-@pytest.mark.parametrize("attn_impl, kept", [
-    # the kernel's folded out [B·H, L, d_v] and float32 lse [B·H, 1, L]
-    ("flash", ["f32[4,1,128]", "f32[4,128,16]"]),
-    ("dense", []),
-])
-def test_a_recomputed_block_keeps_its_inputs_and_the_tagged_pair(
-        capsys, attn_impl, kept):
-    block = tr.remat_whole_block(wm.WindowMoEBlock)(
+#: What the flash kernel's call keeps, folded to [B·H, L, ·]: q, k, v
+#: (K/V unrepeated), out and the float32 lse — at 4 query heads over 2 key
+#: heads of 16 (WINDOW), 7 over 1 (PREROUTE).
+FLASH_4_2 = ["f32[2,128,16]"] * 2 + ["f32[4,1,128]"] + ["f32[4,128,16]"] * 2
+FLASH_7_1 = ["f32[1,128,16]"] * 2 + ["f32[7,1,128]"] + ["f32[7,128,16]"] * 2
+#: What the expert layer keeps over 128 tokens, 3 experts a token (384
+#: buffer rows): the router's logits over 8, each row's token and weight,
+#: each assignment's slot, the groups' sizes; then the dispatched rows and
+#: the two pre-activations [384, F] (of SiLU's, what its reverse reads: σ(g),
+#: not g).
+ROUTED = ["f32[128,8]", "f32[384]", "i32[128,3]", "i32[384]", "i32[8]"]
+#: ... and the chosen experts where a selection bias chose them (the
+#: gather's clamped copy beside them).
+CHOSEN = ["i32[128,3]"] * 2
+#: The sandwich-normed block's attention and feed-forward outputs, and a
+#: gated MLP's two pre-activations: the shared expert's [128, 16], the dense
+#: layer's [1, 128, 48].
+SUBLAYERS = ["f32[1,128,32]"] * 2
+SHARED, DENSE = ["f32[128,16]"] * 2, ["f32[1,128,48]"] * 2
+
+
+def _window_block(attn_impl, dense):
+    return tr.remat_whole_block(wm.WindowMoEBlock)(
         sizes=wm.WindowMoESizes.from_config(WINDOW),
-        layer_type="sliding_attention", dense=False, attn_impl=attn_impl,
-        compute_dtype=jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(0), (1, L, 32))
+        layer_type="sliding_attention", dense=dense, attn_impl=attn_impl,
+        compute_dtype=jnp.float32), 32
+
+
+def _preroute_block(attn_impl, dense):
+    assert not dense  # every SmallThinker layer is sparse
+    sizes = pm.PrerouteMoESizes.from_config(PREROUTE)
+    return tr.remat_whole_block(pm.PrerouteMoEBlock)(
+        sizes=sizes, sliding=True, rotary=True, attn_impl=attn_impl,
+        compute_dtype=jnp.float32), sizes.d_model
+
+
+EXPERTS_32_16 = ROUTED + CHOSEN + ["f32[384,32]"] + ["f32[384,16]"] * 2
+
+
+@pytest.mark.parametrize("make, attn_impl, dense, kept", [
+    (_window_block, "flash", False,
+     FLASH_4_2 + SUBLAYERS + EXPERTS_32_16 + SHARED),
+    (_window_block, "dense", False, SUBLAYERS + EXPERTS_32_16 + SHARED),
+    # a dense MLP: nothing on grouped_expert_mlp's path to keep
+    (_window_block, "flash", True, FLASH_4_2 + SUBLAYERS + DENSE),
+    (_window_block, "dense", True, SUBLAYERS + DENSE),
+    # routed from the block's input by a softmax, no bias; no shared expert;
+    # a norm reads the attention's output, none the experts'; ReLU's reverse
+    # reads the gate's pre-activation itself
+    (_preroute_block, "flash", False, FLASH_7_1 + ["f32[1,128,64]"] + ROUTED
+     + ["f32[384,64]"] + ["f32[384,32]"] * 2),
+], ids=["window-flash-experts", "window-dense-experts", "window-flash-mlp",
+        "window-dense-mlp", "preroute-flash-experts"])
+def test_a_recomputed_block_keeps_its_inputs_and_the_tagged_pair(
+        capsys, make, attn_impl, dense, kept):
+    block, d_model = make(attn_impl, dense)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, L, d_model))
     positions = jnp.arange(L)
-    params = block.init(jax.random.PRNGKey(1), x, positions)["params"]
+    params = jax.jit(block.init)(jax.random.PRNGKey(1), x, positions)["params"]
     rows = _residuals(
         capsys, lambda p, x: block.apply({"params": p}, x, positions).sum(),
         params, x)
-    assert sorted(aval for aval, _ in rows) == kept
+    assert sorted(aval for aval, _ in rows) == sorted(kept)
     assert sum("flash_lse" in src for _, src in rows) == (attn_impl == "flash")
+    assert sum("expert_sizes" in src for _, src in rows) == (not dense)
 
 
 def _program(jaxpr):
@@ -155,6 +223,6 @@ def test_without_block_recomputation_the_tags_are_all_that_changed(
             _loss_and_grads(model, batch))(params).jaxpr)
 
     tagged = program()
-    monkeypatch.setattr(flash_attention, "checkpoint_name", lambda x, _: x)
+    untagged(monkeypatch)
     assert [e for e in tagged if e[0] != "name"] == program()
-    assert sum(e[0] == "name" for e in tagged) >= 2
+    assert sum(e[0] == "name" for e in tagged) >= 5

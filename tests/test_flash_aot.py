@@ -249,7 +249,19 @@ def test_a_compiled_step_publishes_its_flash_calls(v5e, remat, tmp_path):
     finally:
         set_telemetry(previous)
         telemetry.close()
-    gauges = {g["name"]: g["value"]
-              for g in telemetry.registry.snapshot()["gauges"]}
+    snapshot = telemetry.registry.snapshot()["gauges"]
+    gauges = {g["name"]: g["value"] for g in snapshot}
     assert gauges["flash_fwd_calls"] == gauges["flash_bwd_calls"] == layers
     assert gauges["grad_sync_bytes"] > 0
+    assert gauges["xla_remat_instructions"] == 0  # it fits as written
+    # A recomputed block keeps what its flash call reads and makes, folded:
+    # q [B·H, L, D], k and v [B·Hkv, L, D] (a batch row a chip), bf16;
+    # ``out`` and the float32 ``lse`` [B·H, 1, L].
+    kept = {g["labels"]["tag"]: g["value"] for g in snapshot
+            if g["name"] == "remat_kept_bytes"}
+    head = 256 // 2
+    assert kept == ({} if remat != "block" else {
+        "flash_q": layers * 2 * seq * head * 2,
+        "flash_k": layers * seq * head * 2, "flash_v": layers * seq * head * 2,
+        "flash_out": layers * 2 * seq * head * 2,
+        "flash_lse": layers * 2 * seq * 4})

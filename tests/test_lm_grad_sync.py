@@ -20,6 +20,7 @@ from distributed_machine_learning_tpu.ops.hlo import (
     grad_sync_bytes,
     kernel_calls_from_hlo,
     prepare_calls_from_hlo,
+    remat_instructions_from_hlo,
 )
 from distributed_machine_learning_tpu.models import hybrid_moe as hm
 from distributed_machine_learning_tpu.models.transformer import TransformerLM
@@ -205,9 +206,13 @@ def test_off_tpu_and_on_one_device_the_jit_call_is_the_parents(axis_shape,
                     fused_ce_chunks=None, guard=False),
             mesh=mesh, in_specs=(P(), P(*AXES), P(*AXES)),
             out_specs=(P(), P())), donate_argnums=(0,))
-    if axis_shape != (4, 1):
-        # No wrapper either where the pmean is no collective.
+    if axis_shape is None:
+        # No mesh, no wrapper.
         assert type(step) is type(parent)
+    else:
+        # On a mesh the gauges' wrapper, around the very jit call where the
+        # pmean is no collective between TPU devices.
+        assert type(step._jitted) is type(parent)
     assert (step.lower(state, tokens, targets).as_text()
             == parent.lower(state, tokens, targets).as_text())
     _, loss = step(state, tokens, targets)
@@ -241,6 +246,75 @@ def test_gauges_are_written_once_under_an_installed_telemetry(mesh, batch,
     # The gradients and the loss scalar; XLA:CPU has only synchronous ones.
     assert gauges["grad_sync_bytes"] == grad_bytes + 4
     assert gauges["grad_sync_async_bytes"] == 0
+
+
+def test_a_one_device_step_publishes_what_its_blocks_keep(tmp_path):
+    """On one device, as in the benchmark's cells: ``xla_remat_instructions``
+    (0: the program fits as written) and ``remat_kept_bytes`` by tag — here
+    the sandwich-normed window MoE on the dense attention path, recomputed
+    block by block, which keeps its expert layers' routing, rows and
+    pre-activations and its sub-layers' outputs (from shapes, float32).  The
+    model that recomputes nothing keeps nothing to report."""
+    from distributed_machine_learning_tpu.models import window_moe as wm
+    from distributed_machine_learning_tpu.models.transformer import kept_bytes
+    from distributed_machine_learning_tpu.telemetry import (
+        Telemetry,
+        set_telemetry,
+    )
+    from tests.test_window_moe import TINY as WINDOW
+
+    model = wm.WindowMoELM(wm.WindowMoESizes.from_config(WINDOW))
+    mesh = make_mesh(1, AXES, (1, 1))
+    state = jax.eval_shape(
+        lambda: lm_step.init_lm_state(model, config=AdamWConfig()))
+    tokens = jax.ShapeDtypeStruct((1, L), jnp.int32)
+    step = lm_step.make_lm_train_step(model, mesh=mesh)
+    assert kept_bytes(step._jitted.trace(state, tokens, tokens).jaxpr) == {}
+    step = lm_step.make_lm_train_step(
+        model.clone(remat=True, remat_policy="block"), mesh=mesh)
+    telemetry = Telemetry(str(tmp_path), fsync=False)
+    previous = set_telemetry(telemetry)
+    try:
+        step._jitted._publish(state, tokens, tokens)
+    finally:
+        set_telemetry(previous)
+        telemetry.close()
+    gauges = telemetry.registry.snapshot()["gauges"]
+    assert {g["name"]: g["value"] for g in gauges}[
+        "xla_remat_instructions"] == 0
+    kept = {g["labels"]["tag"]: g["value"] for g in gauges
+            if g["name"] == "remat_kept_bytes"}
+    layers, sparse = 5, 4
+    rows, d, f, k = L * 3, 32, 16, 3  # every assignment: all 8 are held
+    assert kept == {
+        "attn_out": layers * L * d * 4, "ffn_out": layers * L * d * 4,
+        "route_logits": sparse * L * 8 * 4,
+        # the chosen experts and the gather's clamped copy of them
+        "route_choice": sparse * 2 * L * k * 4,
+        "expert_token_of_slot": sparse * rows * 4,
+        "expert_slot_of_assignment": sparse * L * k * 4,
+        "expert_sizes": sparse * 8 * 4,
+        "expert_weight_of_slot": sparse * rows * 4,
+        "expert_rows": sparse * rows * d * 4,
+        "expert_up": sparse * rows * f * 4,
+        # SiLU's reverse reads σ(g) of the tagged g
+        "expert_gate": sparse * rows * f * 4,
+        "mlp_gate": sparse * L * f * 4 + L * 48 * 4,
+        "mlp_up": sparse * L * f * 4 + L * 48 * 4}
+
+
+def test_walker_counts_the_instructions_xla_rematerialized():
+    text = _module(
+        "  %fusion.986 = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n"
+        "  %fusion.986.remat = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0)\n"
+        "  %gte.remat.16 = f32[256]{0:T(256)} negate(%p1)\n"
+        "  ROOT %fusion.2026.remat3 = f32[512,256]{1,0:T(8,128)} "
+        "add(%fusion.986, %fusion.986.remat)\n")
+    assert remat_instructions_from_hlo(text) == 3
+    assert remat_instructions_from_hlo(_module(
+        "  ROOT %mul = f32[512,256]{1,0:T(8,128)} multiply(%p0, %p0), "
+        'metadata={op_name="jit(step)/checkpoint/rematted_computation/mul"}\n'
+    )) == 0
 
 
 # ---------------------------------------------------------------- the walker
